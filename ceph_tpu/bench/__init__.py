@@ -5,3 +5,14 @@
 - ``crush_tester`` / crushtool CLI: the ``crushtool --test`` engine
   (ref: src/crush/CrushTester.cc, src/tools/crushtool.cc).
 """
+
+
+def device_stamp() -> dict:
+    """The device a record was taken on, as JAX reports it — every
+    bench record carries this, so a CPU smoke-shape number can never be
+    read as a chip number."""
+    import jax
+    devs = jax.devices()
+    return {"platform": devs[0].platform,
+            "device_kind": devs[0].device_kind,
+            "device_count": len(devs)}

@@ -5,8 +5,7 @@ The `crushtool --test` timing harness scaled to 100M PGs
 src/tools/crushtool.cc). The sweep is ONE device program per measurement
 (Mapper.sweep: fori_loop over PG blocks + on-device scatter-add), so the
 only host<->device traffic is the final (max_devices,) count readback —
-which is also the execution anchor (this platform's block_until_ready
-does not wait for execution; see ceph_tpu/utils/timing.py).
+which is also the execution anchor (see ceph_tpu/utils/timing.py).
 
 Methodology: two sweep sizes, rate taken from the SLOPE so the constant
 dispatch+readback floor cancels — same discipline as the EC benchmark.
@@ -27,7 +26,6 @@ from ceph_tpu.crush import builder
 from ceph_tpu.crush.builder import TYPE_HOST
 from ceph_tpu.crush.mapper import Mapper
 from ceph_tpu.utils.logging import get_logger
-from ceph_tpu.utils.platform import cli_main
 
 log = get_logger("bench")
 
@@ -98,6 +96,15 @@ def choose_args_quantized_map(n_osds: int = 10240):
         args[bid] = ChooseArg(weight_set=[ws])
     m.choose_args[0] = args
     return m
+
+
+# variant name -> (map builder, choose_args key)
+VARIANT_MAPS = {
+    "uniform": (canonical_map, None),
+    "mixed_weight": (mixed_weight_map, None),
+    "choose_args": (choose_args_map, 0),
+    "choose_args_quantized": (choose_args_quantized_map, 0),
+}
 
 
 def _timed_sweep(mapper: Mapper, rule: int, n: int, num_rep: int) -> float:
@@ -213,15 +220,10 @@ def sweep_rate_variants(n_osds: int = 10240, n_pgs: int = 1 << 21,
     round (VERDICT r3 Weak #3). The slow variants sweep fewer PGs (they
     are orders of magnitude slower; the slope method cancels the fixed
     overhead either way)."""
-    builders = {
-        "uniform": (canonical_map, None, n_pgs),
-        "mixed_weight": (mixed_weight_map, None, n_pgs),
-        "choose_args": (choose_args_map, 0, max(1 << 16, n_pgs >> 4)),
-        "choose_args_quantized": (choose_args_quantized_map, 0, n_pgs),
-    }
     out = {}
     for name in variants:
-        build, ca_key, npg = builders[name]
+        build, ca_key = VARIANT_MAPS[name]
+        npg = max(1 << 16, n_pgs >> 4) if name == "choose_args" else n_pgs
         mapper = Mapper(build(n_osds), block=block, choose_args=ca_key)
         r = sweep_rate(n_osds, npg, num_rep, mapper=mapper)
         out[name] = {k: r[k] for k in
@@ -245,7 +247,6 @@ def path_regressions(variants: dict) -> list[str]:
             and "path_expected_vs_actual" in row]
 
 
-@cli_main
 def main(argv=None) -> dict:
     ap = argparse.ArgumentParser(
         prog="crush_sweep", description="batched CRUSH mapping benchmark")
@@ -297,4 +298,6 @@ def main(argv=None) -> dict:
 
 
 if __name__ == "__main__":
+    from ceph_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

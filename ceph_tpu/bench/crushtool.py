@@ -27,7 +27,6 @@ from ceph_tpu.crush.types import (
     ALG_STRAW, ALG_TREE,
     ALG_LIST, ALG_STRAW2, ALG_UNIFORM, ITEM_NONE, WEIGHT_ONE,
 )
-from ceph_tpu.utils.platform import cli_main
 
 ALGS = {"straw2": ALG_STRAW2, "uniform": ALG_UNIFORM, "list": ALG_LIST,
         "straw": ALG_STRAW, "tree": ALG_TREE}
@@ -88,7 +87,6 @@ def build_map(args):
     return m
 
 
-@cli_main
 def main(argv=None) -> dict:
     args = parse_args(argv)
     sources = [s for s in (args.compile, args.infn, args.decompile or None)
@@ -154,4 +152,6 @@ def main(argv=None) -> dict:
 
 
 if __name__ == "__main__":
+    from ceph_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

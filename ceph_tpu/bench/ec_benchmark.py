@@ -45,7 +45,6 @@ from ceph_tpu.ec.interface import ErasureCodeInterface, ErasureCodeProfile
 from ceph_tpu.ec.registry import ErasureCodePluginRegistry
 from ceph_tpu.utils import roofline, timing
 from ceph_tpu.utils.logging import get_logger
-from ceph_tpu.utils.platform import cli_main
 
 log = get_logger("bench")
 
@@ -82,10 +81,8 @@ def parse_args(argv=None) -> argparse.Namespace:
 
 
 def _readback(x) -> None:
-    """Force execution by reading the result back to host. On this
-    platform block_until_ready() acks the dispatch without waiting for
-    execution (measured: ~30 us 'sync' vs ~1 s readback of the same
-    value), so a D2H copy is the only trustworthy barrier."""
+    """Force execution by reading the result back to host: a D2H copy
+    cannot complete before the value exists (see utils/timing.py)."""
     np.asarray(x)
 
 
@@ -364,7 +361,6 @@ class ErasureCodeBench:
         return self.decode()
 
 
-@cli_main
 def main(argv=None) -> dict:
     args = parse_args(argv)
     bench = ErasureCodeBench(args)
@@ -380,4 +376,6 @@ def main(argv=None) -> dict:
 
 
 if __name__ == "__main__":
+    from ceph_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

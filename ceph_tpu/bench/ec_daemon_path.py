@@ -36,6 +36,7 @@ import numpy as np
 
 import jax
 
+from ceph_tpu.bench import device_stamp
 from ceph_tpu.ec.jax_plugin import ErasureCodeJax
 from ceph_tpu.osd.ec_read_aggregator import ECReadAggregator
 
@@ -144,7 +145,7 @@ def ec_daemon_path_section(n_ops: int | None = None,
         "op_bytes": op_bytes,
         "total_bytes": total_bytes,
         "backend": ec.backend,
-        "platform": platform,
+        **device_stamp(),
         "per_op_GiBs": round(_rate(total_bytes, per_op_s), 4),
         "read_agg_GiBs": round(aggregated, 4),
         "resident_GiBs": round(resident, 4),

@@ -1,16 +1,15 @@
 """Device-scaling table: EC encode + CRUSH sweep at 1..N devices.
 
-Run under the virtual CPU mesh (multi-chip TPU hardware is unavailable in
-this environment; the driver validates the same shardings via
-__graft_entry__.dryrun_multichip):
+On a four-chip host it scales over the real mesh (``chip_smoke.py
+--chips 4`` is the quick proof that the sharded paths run there). Under
+the virtual CPU mesh:
 
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
         python -m ceph_tpu.bench.multichip
 
-Scaling here demonstrates the SPMD structure (the EC path has zero
+it only demonstrates the SPMD structure (the EC path has zero
 collectives; the CRUSH sweep's only collective is one (max_devices,)
-psum), not absolute speed — virtual CPU devices share one physical core
-in this sandbox, so ideal speedups appear only on real meshes.
+psum), not speed — virtual CPU devices share the host's cores.
 """
 
 from __future__ import annotations
@@ -21,7 +20,6 @@ import time
 
 import numpy as np
 
-from ceph_tpu.utils.platform import cli_main
 
 
 def ec_rate(mesh, n_devices: int, batch: int, C: int) -> float:
@@ -110,7 +108,6 @@ def crush_rate(mesh, mapper, n_pgs: int) -> float:
     return n_pgs / best
 
 
-@cli_main
 def main(argv=None) -> dict:
     ap = argparse.ArgumentParser(prog="multichip_bench")
     ap.add_argument("--max-devices", type=int, default=0,
@@ -162,4 +159,6 @@ def main(argv=None) -> dict:
 
 
 if __name__ == "__main__":
+    from ceph_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

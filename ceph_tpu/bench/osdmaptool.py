@@ -19,7 +19,6 @@ from ceph_tpu.crush import builder
 from ceph_tpu.crush.types import ITEM_NONE
 from ceph_tpu.osd import OSDMap, PGPool, POOL_TYPE_ERASURE
 from ceph_tpu.sim import ChurnEvent, ChurnSim
-from ceph_tpu.utils.platform import cli_main
 
 
 def create_simple(n_osds: int, pg_num: int, size: int, erasure: bool,
@@ -88,7 +87,6 @@ def parse_args(argv=None) -> argparse.Namespace:
     return p.parse_args(argv)
 
 
-@cli_main
 def main(argv=None) -> int:
     args = parse_args(argv)
     if args.mapfn:
@@ -179,4 +177,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from ceph_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

@@ -155,6 +155,8 @@ def main(argv=None) -> int:
     if not args:
         print(__doc__)
         return 0
+    # a client, not the server: the chip is the serving process's
+    # (one process per chip), so this one stays on the CPU
     import jax
     jax.config.update("jax_platforms", "cpu")
     return asyncio.run(_run(conf, pool, args))

@@ -213,6 +213,9 @@ class ProcCluster:
         return child
 
     async def _exec(self, child: _Child) -> None:
+        # one OS process per daemon, but a chip belongs to ONE process:
+        # the proc backend's children all stay on the CPU (a served
+        # cluster that owns the chip is the inproc backend)
         env = dict(os.environ, JAX_PLATFORMS="cpu")
         child.proc = await asyncio.create_subprocess_exec(
             *child.argv, env=env)
@@ -634,5 +637,5 @@ def main(argv=None) -> None:
 
 if __name__ == "__main__":
     import jax
-    jax.config.update("jax_platforms", "cpu")
+    jax.config.update("jax_platforms", "cpu")     # a child: see _exec
     main()

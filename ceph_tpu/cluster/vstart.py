@@ -671,6 +671,9 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
-    import jax
-    jax.config.update("jax_platforms", "cpu")
+    # the process that hosts the OSDs owns the device: no platform pin
+    # here (the client CLIs and the proc backend's children pin
+    # themselves to the CPU instead)
+    from ceph_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -77,9 +77,9 @@ def crush_ln(xin, xp=np):
         # the fixed-point path needs real 64-bit ints; scope x64 here so
         # callers outside an enable_x64 context do not silently get
         # 32-bit-truncated draws (jax truncates with only a UserWarning)
-        from ceph_tpu.utils.platform import enable_x64 as _enable_x64
+        import jax
 
-        with _enable_x64(True):
+        with jax.enable_x64(True):
             return _crush_ln_impl(xin, xp)
     return _crush_ln_impl(xin, xp)
 

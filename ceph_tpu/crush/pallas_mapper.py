@@ -155,12 +155,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    HAVE_PALLAS = True
-except ImportError:                                   # pragma: no cover
-    HAVE_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ceph_tpu.crush.types import (
     ALG_STRAW2, ITEM_NONE,

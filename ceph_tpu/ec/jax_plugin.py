@@ -47,14 +47,17 @@ class _MatrixKernel:
         lo, hi = tables.nibble_tables(self.coeffs)
         self.lo = jnp.asarray(lo)
         self.hi = jnp.asarray(hi)
-        self.plan = pk.make_plan(bm_np) if pk.HAVE_PALLAS else None
+        self.plan = pk.make_plan(bm_np)
+
+    def _pallas_ok(self, data) -> bool:
+        rows_out, rows_in = self.coeffs.shape
+        return pk.pallas_ok(int(data.shape[-1]), rows_in, rows_out)
 
     def apply(self, data: jax.Array) -> jax.Array:
         """(rows_in, L) uint8 -> (rows_out, L) uint8."""
         if self.backend == "lut":
             return ops.gf_matmul_lut(self.lo, self.hi, data)
-        if self.backend == "pallas" and self.plan is not None \
-                and pk.pallas_ok(int(data.shape[-1])):
+        if self.backend == "pallas" and self._pallas_ok(data):
             return pk.encode_batch_planned(
                 self.plan, data[None],
                 interpret=jax.default_backend() != "tpu")[0]
@@ -62,8 +65,7 @@ class _MatrixKernel:
 
     def apply_batch(self, data: jax.Array) -> jax.Array:
         """(batch, rows_in, C) -> (batch, rows_out, C)."""
-        if self.backend == "pallas" and self.plan is not None \
-                and pk.pallas_ok(int(data.shape[-1])):
+        if self.backend == "pallas" and self._pallas_ok(data):
             return pk.encode_batch_planned(
                 self.plan, data,
                 interpret=jax.default_backend() != "tpu")
@@ -128,8 +130,7 @@ class ErasureCodeJax(ErasureCodeInterface):
             # block-diag rewrite, vs ~60 for the XLA bitmatmul); on CPU
             # it only runs in slow interpret mode, so default to the
             # XLA path there.
-            self.backend = ("pallas" if pk.HAVE_PALLAS
-                            and jax.default_backend() == "tpu"
+            self.backend = ("pallas" if jax.default_backend() == "tpu"
                             else "bitmatmul")
         if self.backend not in ("bitmatmul", "lut", "pallas"):
             raise ValueError(f"unknown backend {self.backend!r}; "
@@ -485,8 +486,8 @@ class StreamingEncodePipeline:
 
     The resident benchmark number assumes the stripes already live in
     HBM; a real ingest path pays host->device per batch. This pipeline
-    overlaps the three legs so a real host measures the PCIe(-or-
-    tunnel)-bound rate instead of the dispatch-serialized one:
+    overlaps the three legs so a real host measures the PCIe-bound
+    rate instead of the dispatch-serialized one:
 
     - **H2D of batch N+1** (``jax.device_put``, asynchronous) is issued
       BEFORE batch N's encode is dispatched, so the transfer engine

@@ -70,23 +70,18 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    HAVE_PALLAS = True
-except ImportError:                                   # pragma: no cover
-    HAVE_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # Minimum lane-tile bytes per grid step (and the alignment callers must
 # provide). encode_batch_planned picks the largest tile in
-# [TILE_L, TILE_MAX] that divides C and keeps the VMEM working set in
-# budget — measured on v5e: 128 KiB tiles beat 32 KiB by ~5% and
-# 512 KiB exceeds the 16 MiB scoped-VMEM limit at k=8.
+# [TILE_L, TILE_MAX] that divides C and whose scoped-VMEM allocation the
+# chip's compiler accepts — measured on v5e: 128 KiB tiles beat 32 KiB
+# by ~5%.
 TILE_L = 1 << 15
 TILE_MAX = 1 << 17
-# k * tile cap keeping the scoped-VMEM allocation under the compiler's
-# 16 MiB limit (k=8 at 128 KiB tiles measured as the edge's safe side).
-_KTILE_CAP = 1 << 20
+# Mosaic's scoped-VMEM limit for one kernel on v5e.
+_VMEM_LIMIT = 16 << 20
 
 
 class EncodePlan(NamedTuple):
@@ -95,15 +90,54 @@ class EncodePlan(NamedTuple):
     packw: jax.Array         # (r*OFF, r*8m) bf16 aligned pack weights
 
 
-def _pick_tile(k: int, C: int) -> int:
+def _scoped_bytes_per_lane(k: int, m: int) -> int:
+    """Upper bound on the scoped VMEM Mosaic allocates per BYTE of lane
+    tile for a (k rows in, m rows out) kernel.
+
+    Measured, not derived: compile ``encode_batch_planned`` for a
+    described v5e and read "Scoped allocation with size X and limit
+    16.00M" at 64/128/256 KiB tiles (k in 1..16, 20, 24, 32; m in
+    1..6, 8; the allocation is linear in the tile). It does NOT scale with k — the
+    ``k * tile <= 1 MiB`` model this replaces let 4+2, 2+2, 2+1 and 8+4
+    through at 128 KiB, which the chip's compiler refuses (interpret
+    mode never allocates, so no test saw it). Four regimes, each the
+    upper envelope of the measurements:
+
+    - 2 <= k <= 4 (the stacked planes fill at most half the 128-deep
+      contraction): 253..276 B/lane, nearly flat in m;
+    - k <= 8 (block-diagonal r=2): ~36.5 B/lane per output row
+      (m=3: 110, m=4: 146, m=8: 280) — the int32 accumulator and its
+      bf16/f32 epilogue copies;
+    - 9 <= k <= 16 (r=1, one 128-deep contraction tile): the same
+      slope plus one more row's worth (m=3: 132, m=4: 163, m=8: 298),
+      flat in k;
+    - k > 16 (a second contraction tile): more per output row and a
+      little per input row (k=20: m=3 150, m=8 370; k=32: m=3 166,
+      m=8 386).
+
+    tests/test_chip_compile.py compiles the shapes the OSD and the
+    benchmarks use against the real compiler, so a libtpu that
+    allocates more fails a test instead of a launch."""
+    if 2 <= k <= 4:
+        return 260 + 3 * m
+    if k <= 8:
+        return 38 * m
+    if k <= 16:
+        return 35 * (m + 1)
+    return 35 * (m + 1) + 10 * m + 2 * (k - 16)
+
+
+def _pick_tile(k: int, m: int, C: int) -> int:
+    """Largest power-of-two tile in [TILE_L, TILE_MAX] dividing C whose
+    modelled allocation fits the limit; 0 when none does (callers keep
+    the XLA bitmatmul)."""
+    per_lane = _scoped_bytes_per_lane(k, m)
     t = TILE_MAX
-    while t > TILE_L:
-        if C % t == 0 and k * t <= _KTILE_CAP:
+    while t >= TILE_L:
+        if C % t == 0 and per_lane * t <= _VMEM_LIMIT:
             return t
         t //= 2
-    # TILE_L is the floor regardless of k: pallas_ok() gates on it and
-    # the pre-cap code ran every k at this tile size
-    return TILE_L if C % TILE_L == 0 else 0
+    return 0
 
 
 def make_plan(bitmatrix: np.ndarray) -> EncodePlan:
@@ -169,16 +203,16 @@ def encode_batch_planned(plan: EncodePlan, data: jax.Array,
                          interpret: bool = False) -> jax.Array:
     """plan x (B, k, C) uint8 -> (B, m, C) uint8 parity.
 
-    C must be a multiple of TILE_L (use pallas_ok; callers fall back to
-    the XLA kernel otherwise)."""
+    (k, m, C) must pass pallas_ok; callers fall back to the XLA kernel
+    otherwise."""
     m8, k8 = plan.bm_bitmajor.shape
     B, k, C = data.shape
     assert k8 == 8 * k, (plan.bm_bitmajor.shape, data.shape)
     m = m8 // 8
     r = plan.bm_op.shape[1] // k8
     off = plan.packw.shape[0] // r
-    tile = _pick_tile(k, C)
-    assert tile, f"C={C} not a multiple of TILE_L={TILE_L}"
+    tile = _pick_tile(k, m, C)
+    assert tile, f"no lane tile for rows {k}->{m}, C={C} (pallas_ok)"
     grid = (B, C // tile)
     params = {}
     if not interpret:
@@ -216,6 +250,8 @@ def gf_matmul_bitplanes_pallas(bitmatrix, data: jax.Array,
     return out[0]
 
 
-def pallas_ok(C: int) -> bool:
-    """Fast-path eligibility for this lane/chunk length."""
-    return HAVE_PALLAS and C % TILE_L == 0 and C > 0
+def pallas_ok(C: int, k: int, m: int) -> bool:
+    """Fast-path eligibility: the lane/chunk length C is tile-aligned
+    and the chip's compiler accepts a tile for a (k rows in, m rows
+    out) matrix."""
+    return C > 0 and _pick_tile(k, m, C) > 0

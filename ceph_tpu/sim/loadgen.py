@@ -281,6 +281,8 @@ async def run_sharded(cluster, pool: str, sessions: int = 1000,
                       read_fraction=read_fraction, think_s=think_s,
                       op_timeout=op_timeout, concurrency=concurrency,
                       seed=seed * 1000 + w + 1)
+        # load generators are clients: the chip is the serving
+        # process's (one process per chip), workers stay on the CPU
         env = dict(os.environ, JAX_PLATFORMS="cpu")
         proc = await asyncio.create_subprocess_exec(
             sys.executable, "-m", "ceph_tpu.sim.loadgen", "--worker",
@@ -406,7 +408,7 @@ if __name__ == "__main__":
     import sys as _sys
 
     import jax as _jax
-    _jax.config.update("jax_platforms", "cpu")
+    _jax.config.update("jax_platforms", "cpu")    # a client: see above
     if "--worker" in _sys.argv:
         asyncio.run(_worker_main())
     else:

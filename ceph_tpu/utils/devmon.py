@@ -14,7 +14,7 @@ blind spots motivated it:
   stall-clamp mgr liveness around exactly this without ever being able
   to SEE the compile that caused it;
 - **unaccounted transfers**: H2D staging and D2H readbacks dominate
-  wall time on tunnel-attached devices, and nothing counted the bytes.
+  wall time when the host link is slow, and nothing counted the bytes.
 
 Two kinds of :class:`DeviceRuntimeMonitor` exist:
 

@@ -1,9 +1,8 @@
 """Physical performance bounds per device — the honesty guard for benchmarks.
 
-Round 1's headline number (9,317 GiB/s) was physically impossible on the
-v5e chip this environment provides; the timing loop measured dispatch, not
-execution (this platform's ``block_until_ready`` returns before the device
-runs). Every benchmark now (a) anchors timing with a device-side reduction
+Round 1's headline number (9,317 GiB/s) was physically impossible on a
+v5e chip; the timing loop measured dispatch, not execution. Every
+benchmark now (a) anchors timing with a device-side reduction
 read back to host, and (b) passes its result through :func:`check`, which
 refuses to report a rate above the device's roofline.
 
@@ -13,7 +12,7 @@ below them is not thereby certified, just possible.
 
 ref: the reference harness (src/test/erasure-code/ceph_erasure_code_benchmark.cc
 ErasureCodeBench::run) has no such guard because wall-clock timing of a
-synchronous C++ loop cannot overshoot; an async remote device can.
+synchronous C++ loop cannot overshoot; an asynchronous device can.
 """
 
 from __future__ import annotations
@@ -29,10 +28,15 @@ class DeviceSpec:
     hbm_bytes: float            # capacity
 
 
-# Known TPU generations (public figures). int8 MACs = OPS/2.
+# Peaks per chip, keyed by ``jax.devices()[0].device_kind``. Source:
+# Google Cloud TPU documentation, the "System architecture" page of
+# each generation (cloud.google.com/tpu/docs/v5e, /v5p, /v4, /v6e):
+# HBM bandwidth, int8 TOPS (MACs = OPS/2; v4 has no int8 rate, its
+# bf16 figure stands in) and HBM capacity. A v5e reports itself as
+# "TPU v5 lite" (confirmed on the chip, PR 22).
 _SPECS = {
-    "TPU v5 lite": DeviceSpec("TPU v5e", 819e9, 394e12 / 2, 16 * 2**30),
-    "TPU v5e": DeviceSpec("TPU v5e", 819e9, 394e12 / 2, 16 * 2**30),
+    "TPU v5 lite": DeviceSpec("TPU v5e", 819e9, 393e12 / 2, 16 * 2**30),
+    "TPU v5e": DeviceSpec("TPU v5e", 819e9, 393e12 / 2, 16 * 2**30),
     "TPU v5": DeviceSpec("TPU v5p", 2765e9, 918e12 / 2, 95 * 2**30),
     "TPU v4": DeviceSpec("TPU v4", 1228e9, 275e12 / 2, 32 * 2**30),
     "TPU v6 lite": DeviceSpec("TPU v6e", 1640e9, 1836e12 / 2, 32 * 2**30),
@@ -40,16 +44,16 @@ _SPECS = {
 
 
 def device_spec(device_kind: str | None = None) -> DeviceSpec | None:
-    """Spec for the current (or named) device; None when unknown (e.g. CPU
-    — no guard is applied there, wall-clock on CPU is synchronous)."""
+    """Spec for the current (or named) device; None when its
+    ``device_kind`` is not in the table. On the CPU that means no guard
+    (wall-clock there is synchronous); a measuring path on a chip
+    treats None as an error (``chip_smoke.py`` does), never as a
+    default."""
     if device_kind is None:
         import jax
 
         device_kind = jax.devices()[0].device_kind
-    for prefix, spec in _SPECS.items():
-        if device_kind.startswith(prefix):
-            return spec
-    return None
+    return _SPECS.get(device_kind)
 
 
 def encode_bound(k: int, m: int, spec: DeviceSpec) -> float:

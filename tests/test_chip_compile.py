@@ -1,0 +1,118 @@
+"""Ask the chip's compiler, without the chip.
+
+The TPU compiler is installed here and compiles for a DESCRIBED v5e
+(nothing runs, nothing is timed). Interpret mode cannot see what these
+see: scoped-VMEM refusals, unaligned slices, ops Mosaic will not lower.
+The kernels of the main path compile here at the shapes the OSD, the
+benchmarks and ``chip_smoke.py`` launch them with, so a change that the
+chip would refuse fails a test instead of a launch.
+
+All chip compiles live in THIS file: only one process may load libtpu,
+the topology is described inside a module fixture (never at import),
+and the xdist worker that gets this file keeps the library until it
+exits. The persistent compile cache is off around them — a compile for
+a described chip cannot be read back without one.
+"""
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from ceph_tpu.gf import pallas_kernels as pk
+
+OBJECT = 4 << 20            # rados bench / ceph_erasure_code_benchmark size
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _ec(k, m):
+    from ceph_tpu.ec.jax_plugin import ErasureCodeJax
+    return ErasureCodeJax(f"k={k} m={m} technique=reed_sol_van "
+                          f"backend=pallas")
+
+
+def _compile_ec(kern, batch, C, sharding):
+    """Compile the fused kernel for ``kern``'s plan at (batch, rows, C)
+    exactly as _MatrixKernel.apply_batch launches it."""
+    rows_out, rows_in = kern.coeffs.shape
+    assert pk.pallas_ok(C, rows_in, rows_out)
+    plan = pk.EncodePlan(*[
+        jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+        for a in kern.plan])
+    data = jax.ShapeDtypeStruct((batch, rows_in, C), jnp.uint8,
+                                sharding=sharding)
+    compiled = pk.encode_batch_planned.lower(plan, data).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("k,m", [(8, 3), (4, 2), (2, 2), (8, 4), (16, 4)])
+def test_ec_encode_compiles_at_4mib_objects(one_chip, k, m):
+    """8+3 is the headline profile, 4+2 tracked config #1, 2+2
+    upstream's default profile; 8+4 and 16+4 sit on the other edges of
+    the tile model. PR 22: the chip's compiler refused 4+2, 2+2 and
+    8+4 under the old ``k * tile <= 1 MiB`` model."""
+    ec = _ec(k, m)
+    _compile_ec(ec._encode_kernel, 16, ec.get_chunk_size(OBJECT), one_chip)
+
+
+def test_ec_decode_two_erasures_compiles(one_chip):
+    ec = _ec(8, 3)
+    erased = (0, 1)
+    avail = tuple(c for c in range(11) if c not in erased)[:8]
+    _compile_ec(ec._decode_kernel(avail, erased), 16,
+                ec.get_chunk_size(OBJECT), one_chip)
+
+
+@pytest.mark.parametrize("k,m,C,tile", [
+    (8, 3, 512 << 10, 128 << 10),
+    (8, 4, 512 << 10, 64 << 10),
+    (4, 2, 1 << 20, 32 << 10),
+    (2, 2, 2 << 20, 32 << 10),
+    (10, 4, 128 << 10, 64 << 10),
+    (8, 3, 4096, 0),                 # the OSD's stripe_unit: never fused
+    (8, 16, 512 << 10, 0),           # nothing fits: stays on XLA
+])
+def test_tile_model(k, m, C, tile):
+    """No compile: the tile the model picks at the shapes above."""
+    assert pk._pick_tile(k, m, C) == tile
+    assert pk.pallas_ok(C, k, m) == bool(tile)
+
+
+@pytest.mark.parametrize("variant", ["uniform", "choose_args"])
+def test_crush_kernel_compiles_at_10k_osds(one_chip, variant):
+    """The fused CRUSH kernel on the canonical 10,240-OSD map, rule 0,
+    numrep 3, under the caller's enable_x64 as Mapper launches it:
+    the uniform plan and the continuous choose_args plan
+    (_choose_level_cont), whose VMEM model only a real compile tests."""
+    from ceph_tpu.bench import crush_sweep
+    from ceph_tpu.crush import pallas_mapper as pm
+    from ceph_tpu.crush.mapper import Mapper
+
+    if variant == "uniform":
+        mapper = Mapper(crush_sweep.canonical_map(10240))
+    else:
+        mapper = Mapper(crush_sweep.choose_args_map(10240), choose_args=0)
+    plan = mapper._kernel_plan(0)
+    assert plan is not None, "build_plan declined the canonical map"
+    lanes, _fold, _groups = pm.kernel_geometry(plan, 3 + pm.SPEC_EXTRA)
+    xs = jax.ShapeDtypeStruct((4 * lanes,), jnp.int32, sharding=one_chip)
+    with jax.enable_x64(True):
+        compiled = pm._run_kernel.lower(plan, xs, 3).compile()
+    assert "tpu_custom_call" in compiled.as_text()

@@ -159,10 +159,10 @@ class TestPallasKernel:
     def test_pallas_ok_gating(self):
         from ceph_tpu.gf import pallas_kernels as pk
 
-        assert pk.pallas_ok(pk.TILE_L)
-        assert pk.pallas_ok(4 * pk.TILE_L)
-        assert not pk.pallas_ok(pk.TILE_L + 1)
-        assert not pk.pallas_ok(0)
+        assert pk.pallas_ok(pk.TILE_L, 8, 3)
+        assert pk.pallas_ok(4 * pk.TILE_L, 8, 3)
+        assert not pk.pallas_ok(pk.TILE_L + 1, 8, 3)
+        assert not pk.pallas_ok(0, 8, 3)
 
 
 class TestPallasPlugin:
