@@ -17,6 +17,7 @@ a 3-replica chooseleaf rule (BASELINE.md tracked config #3).
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import time
 
@@ -267,7 +268,15 @@ def main(argv=None) -> dict:
     ap.add_argument("--profile", default=None, metavar="DIR",
                     help="write a jax.profiler trace of the sweep")
     args = ap.parse_args(argv)
-    from ceph_tpu.utils.profiling import trace
+
+    def trace(log_dir):
+        """jax.profiler's own context manager (a profiler that will
+        not start raises); nothing where no directory was asked for."""
+        if not log_dir:
+            return contextlib.nullcontext()
+        import jax
+        return jax.profiler.trace(log_dir)
+
     if args.checkpoint:
         from ceph_tpu.utils.checkpoint import resumable_sweep
         m = canonical_map(args.num_osds)

@@ -27,15 +27,18 @@ from __future__ import annotations
 import asyncio
 import hmac
 import random
+import time
 import traceback
 import zlib
 from dataclasses import dataclass
 
 from ceph_tpu.msg.auth import Authenticator, AuthError, Keyring
 from ceph_tpu.msg.message import Message
+from ceph_tpu.utils import tracing
 from ceph_tpu.utils.logging import get_logger
 
 log = get_logger("ms")
+_clock = time.perf_counter_ns
 
 BANNER = b"ceph_tpu msgr2.1\n"
 
@@ -145,6 +148,10 @@ class Connection:
         self._tx_epoch = 0
         self._rx_epoch = 0
         self._tx_frames = 0
+        # both ends of the last frame's integrity check (or open), for
+        # the ``msg.recv`` section the reader loop emits once the
+        # decoded message says whose op the frame belonged to
+        self._rx_t0 = self._rx_t1 = 0
 
     def _secure(self) -> bool:
         return self.msgr.mode == MODE_SECURE and self.auth is not None
@@ -153,7 +160,10 @@ class Connection:
     def _trailer(self, seq: int, body: bytes) -> bytes:
         return zlib.crc32(body).to_bytes(4, "little")
 
-    async def _send_frame(self, tag: int, seq: int, body: bytes) -> None:
+    async def _send_frame(self, tag: int, seq: int, body: bytes,
+                          ctx: Message | None = None) -> None:
+        """``ctx``: the message the frame carries, where the caller
+        has it — whose op the ``msg.send`` section belongs to."""
         inj = self.msgr.faults
         if inj is not None:
             act = inj.on_frame(self.msgr.name, self.peer_name)
@@ -165,20 +175,28 @@ class Connection:
         if self.msgr._inject_failure():
             self._abort()
             raise ConnectionError_("injected socket failure (send)")
-        head = tag.to_bytes(1, "little") + seq.to_bytes(8, "little")
-        if self._secure():
-            # AEAD: header authenticated as AAD, body encrypted; no
-            # separate trailer (the GCM tag rides in the ciphertext)
-            ct = self.auth.seal(0 if self.is_client else 1,
-                                self._tx_epoch, tag, seq, head, body)
-            wire = head + ct
-            trailer = b""
-        else:
-            wire = head + body
-            trailer = self._trailer(seq, wire)
+        # msg.send: trailer or seal, and the socket.send the transport
+        # does inline inside write(); drain() may await and stays out
         try:
-            self.writer.write(len(wire).to_bytes(4, "little") + wire +
-                              trailer)
+            with tracing.section("msg.send", ctx, self.msgr.tracer,
+                                 self.msgr.name) as sec:
+                head = tag.to_bytes(1, "little") + \
+                    seq.to_bytes(8, "little")
+                if self._secure():
+                    # AEAD: header authenticated as AAD, body encrypted;
+                    # no separate trailer (the GCM tag rides in the
+                    # ciphertext)
+                    ct = self.auth.seal(0 if self.is_client else 1,
+                                        self._tx_epoch, tag, seq, head,
+                                        body)
+                    wire = head + ct
+                    trailer = b""
+                else:
+                    wire = head + body
+                    trailer = self._trailer(seq, wire)
+                sec.tag("bytes", len(wire))
+                self.writer.write(len(wire).to_bytes(4, "little") +
+                                  wire + trailer)
             await self.writer.drain()
         except (ConnectionError, OSError) as e:
             self._abort()
@@ -197,6 +215,7 @@ class Connection:
         if self.msgr._inject_failure():
             self._abort()
             raise ConnectionError_("injected socket failure (recv)")
+        self._rx_t0 = _clock()
         tag = frame[0]
         seq = int.from_bytes(frame[1:9], "little")
         if self._secure():
@@ -207,10 +226,13 @@ class Connection:
                                       frame[:9], frame[9:])
             except _AE as e:
                 raise ConnectionError_(str(e)) from e
-            return tag, seq, body
-        if not hmac.compare_digest(self._trailer(seq, frame), trailer):
-            raise ConnectionError_("frame integrity check failed")
-        return tag, seq, frame[9:]
+        else:
+            if not hmac.compare_digest(self._trailer(seq, frame),
+                                       trailer):
+                raise ConnectionError_("frame integrity check failed")
+            body = frame[9:]
+        self._rx_t1 = _clock()
+        return tag, seq, body
 
     def _rekey_material(self, new_epoch: int
                         ) -> tuple[bytes, bytes | None]:
@@ -285,14 +307,18 @@ class Connection:
                 self.out_seq += 1
                 seq = self.out_seq
             msg.seq = seq
-            body = msg.encode()
+            with tracing.section("msg.encode", msg, self.msgr.tracer,
+                                 self.msgr.name) as sec:
+                body = msg.encode()
+                sec.tag("type", type(msg).__name__).tag(
+                    "bytes", len(body))
             if not self.policy.lossy:
                 (sess.unacked if sess is not None
                  else self.unacked).append((seq, body))
             try:
                 await self._maybe_rekey()
                 self._tx_frames += 1
-                await self._send_frame(TAG_MSG, seq, body)
+                await self._send_frame(TAG_MSG, seq, body, msg)
             except ConnectionError_:
                 if self.policy.lossy or sess is None:
                     raise
@@ -385,6 +411,10 @@ class Messenger:
         # richer per-peer-pair fault table (sim/faults.FaultInjector):
         # partitions/drops/delays/dup/reorder, installed at runtime
         self.faults = None
+        # the owning daemon's utils.tracing.Tracer (it sets it): where
+        # the msg.* sections of sampled ops are kept; without one they
+        # exist only while a profiler session captures
+        self.tracer = None
         self._rng = random.Random(seed)
         # instance nonce: distinguishes this daemon incarnation so peers
         # reset replay-dedup state after a restart (ref: entity_addr_t
@@ -679,6 +709,7 @@ class Messenger:
         while not conn.closed:
             try:
                 tag, seq, body = await conn._recv_frame()
+                rx_t0, rx_t1 = conn._rx_t0, conn._rx_t1
             except asyncio.CancelledError:
                 return
             except Exception:           # ConnectionError_ or corrupt peer
@@ -688,6 +719,10 @@ class Messenger:
                 return
             if tag == TAG_ACK:
                 conn._handle_ack(seq)
+                if tracing.capturing():   # no message: nobody's op
+                    tracing.emit_section(
+                        "msg.recv", rx_t0, _clock(), None,
+                        self.tracer, self.name, {"type": "ack"})
                 continue
             if tag == TAG_KEEPALIVE:
                 continue
@@ -735,11 +770,20 @@ class Messenger:
                 state = self._peer_in_seq.get(conn.peer_name)
                 if state is not None and state[0] == conn.peer_session:
                     state[1] = seq
+            t_dec = _clock()
             try:
                 msg = Message.decode(body)
             except Exception as e:
                 log.dout(1, f"undecodable message from {conn.peer_name}: {e}")
                 continue
+            if tracing.wanted(msg, self.tracer):
+                # the frame was checked and decoded before the message
+                # could say whose op it is: both sections after the fact
+                tags = {"type": type(msg).__name__, "bytes": len(body)}
+                tracing.emit_section("msg.recv", rx_t0, rx_t1, msg,
+                                     self.tracer, self.name, tags)
+                tracing.emit_section("msg.decode", t_dec, _clock(), msg,
+                                     self.tracer, self.name, tags)
             msg.src = conn.peer_name
             msg.conn = conn
             if self.throttle:

@@ -41,6 +41,7 @@ from ceph_tpu.osd.scheduler import (OpScheduler, QoSProfile,
                                     SchedulerThrottle, _Grant,
                                     size_scaled_cost)
 from ceph_tpu.osd.types import MAX_OID, pg_t
+from ceph_tpu.utils import tracing
 from ceph_tpu.utils.devmon import engine_name as _engine_name
 from ceph_tpu.utils.logging import get_logger
 from ceph_tpu.utils.op_tracker import OpTracker
@@ -142,6 +143,7 @@ class OSD(Dispatcher):
         # phases — shipped monward on the stats piggyback
         from ceph_tpu.utils.tracing import Tracer
         self.tracer = Tracer(name, cfg)
+        self.msgr.tracer = self.tracer    # the msg.* sections' keeper
         # bulk mapping sweeps in the tracked table emit crush_sweep
         # spans (n_pgs/path/n_devices) through the daemon's tracer, so
         # advance-map sweep cost is drill-downable in `trace show`
@@ -220,7 +222,7 @@ class OSD(Dispatcher):
         # from every ECPG on this OSD coalesce into one padded batched
         # kernel launch per flush window (osd_ec_agg knobs, read LIVE)
         from ceph_tpu.osd.ec_aggregator import ECAggregator
-        self.ec_agg = ECAggregator(cfg)
+        self.ec_agg = ECAggregator(cfg, tracer=self.tracer)
         # EC decode/repair aggregator (round 19): the read-side twin —
         # degraded reads and recovery rebuilds from every ECPG coalesce
         # into one padded decode launch per flush window
@@ -229,7 +231,8 @@ class OSD(Dispatcher):
         # bypass QoS cost tags
         from ceph_tpu.osd.ec_read_aggregator import ECReadAggregator
         self.ec_read_agg = ECReadAggregator(cfg,
-                                            scheduler=self.scheduler)
+                                            scheduler=self.scheduler,
+                                            tracer=self.tracer)
         # hot-shard residency (round 19): gathered shard batches pin
         # device-side under osd_ec_resident_bytes, version-keyed so
         # writes invalidate by construction (None when disabled —
@@ -829,12 +832,16 @@ class OSD(Dispatcher):
                 from_osd=self.whoami))
             return True
         if isinstance(msg, MOSDOp):
+            # osd.dispatch: admission up to the scheduler, closed by
+            # hand before the branch that replies or backs off awaits
+            sec = tracing.section("osd.dispatch", msg, self.tracer)
             if self.osdmap is not None and \
                     self.osdmap.is_blocklisted(msg.src):
                 # cluster-level fence (ref: OSD::ms_handle_fast_connect
                 # blocklist check): an evicted/zombie client's ops are
                 # refused with EBLOCKLISTED no matter when it resumes
                 from ceph_tpu.osd.messages import MOSDOpReply
+                sec.finish()
                 await msg.conn.send_message(MOSDOpReply(
                     tid=msg.tid, attempt=getattr(msg, "attempt", 0),
                     result=-108, epoch=self.osdmap.epoch, data=b"",
@@ -849,6 +856,7 @@ class OSD(Dispatcher):
                 # entities stay unrestricted (legacy boot keys), like
                 # the mon-side slice.
                 from ceph_tpu.osd.messages import MOSDOpReply
+                sec.finish()
                 await msg.conn.send_message(MOSDOpReply(
                     tid=msg.tid, attempt=getattr(msg, "attempt", 0),
                     result=-1, epoch=self.osdmap.epoch
@@ -858,6 +866,7 @@ class OSD(Dispatcher):
             if pg is None or not pg.is_primary():
                 # wrong target: client's map is stale; it will resend
                 from ceph_tpu.osd.messages import MOSDOpReply
+                sec.finish()
                 await msg.conn.send_message(MOSDOpReply(
                     tid=msg.tid, attempt=getattr(msg, "attempt", 0),
                     result=-11, epoch=self.osdmap.epoch
@@ -870,6 +879,7 @@ class OSD(Dispatcher):
                 # awaiting — bypass the serialized queue. ONLY pure
                 # ack bundles: a mixed bundle with mutating ops must
                 # keep the per-PG serialization the queue provides.
+                sec.finish()
                 await pg._execute(msg)
                 return True
             if any(c in MUTATING_OPS for c in msg.op_codes) and \
@@ -882,6 +892,7 @@ class OSD(Dispatcher):
                 OVERLOAD_PERF.inc("failsafe_rejections")
                 log.dout(1, f"osd.{self.whoami} failsafe ENOSPC "
                             f"for {msg.oid}")
+                sec.finish()
                 await msg.conn.send_message(MOSDOpReply(
                     tid=msg.tid, attempt=getattr(msg, "attempt", 0),
                     result=-28, epoch=self.osdmap.epoch
@@ -895,6 +906,7 @@ class OSD(Dispatcher):
                 # This is the data-safety invariant's "parked" half;
                 # ops admitted before readiness land in the log and
                 # fold into the parent ("land in the merged parent").
+                sec.finish()
                 await pg.send_backoff(msg)
                 return True
             queue_cap = int(
@@ -916,6 +928,7 @@ class OSD(Dispatcher):
                 # queue_cap payloads in memory): backoff instead of
                 # queueing unboundedly — the client parks and resends
                 # after our UNBLOCK (ref: the PG Backoff machinery)
+                sec.finish()
                 await pg.send_backoff(msg)
                 return True
             # admission: ops queue at the scheduler (dmClock tags per
@@ -923,7 +936,8 @@ class OSD(Dispatcher):
             # than dispatch (ref: mClockScheduler::enqueue)
             op_span = self.tracer.from_msg(
                 "osd_op", msg, tags={"osd": self.whoami,
-                                     "oid": msg.oid})
+                                     "oid": msg.oid,
+                                     "pgid": str(pg.pgid)})
             if op_span is not None:
                 # the op's primary-side span opens at admission; its
                 # "queue" child covers throttle + pg-queue wait and is
@@ -934,6 +948,7 @@ class OSD(Dispatcher):
                 msg, key=("client", entity, msg.pool),
                 profile=self._client_profile(entity, pg.pool),
                 cost=self._op_cost(msg))
+            sec.finish()
             return True
         if isinstance(msg, MOSDRepOp):
             pg = self._pg_for(msg.pgid, create=True)

@@ -40,6 +40,7 @@ import time
 
 import numpy as np
 
+from ceph_tpu.utils import tracing
 from ceph_tpu.utils.logging import get_logger
 from ceph_tpu.utils.perf_counters import PerfCountersBuilder
 
@@ -87,13 +88,14 @@ def _agg_perf():
 
 
 class _Entry:
-    __slots__ = ("data", "with_crc", "fut", "t0")
+    __slots__ = ("data", "with_crc", "fut", "t0", "span")
 
-    def __init__(self, data, with_crc, fut, t0):
+    def __init__(self, data, with_crc, fut, t0, span=None):
         self.data = data
         self.with_crc = with_crc
         self.fut = fut
         self.t0 = t0
+        self.span = span        # the op's ec.agg_wait interval
 
 
 class _Group:
@@ -112,8 +114,9 @@ class _Group:
 class ECAggregator:
     """One per OSD daemon; every ECPG encode routes through it."""
 
-    def __init__(self, config: dict | None = None):
+    def __init__(self, config: dict | None = None, tracer=None):
         self.config = config if config is not None else {}
+        self.tracer = tracer    # the owning daemon's, for the sections
         self.perf = _agg_perf()
         self._groups: dict[tuple, _Group] = {}
         self.stopped = False
@@ -137,12 +140,15 @@ class ECAggregator:
         return int(self.config.get("osd_ec_fallback_retries", 1))
 
     # -- submit ------------------------------------------------------------
-    async def encode(self, ec, data, with_crc: bool = False):
+    async def encode(self, ec, data, with_crc: bool = False,
+                     span=None):
         """Encode a (B, k, C) uint8 stripe batch; returns
         ``(parity np(B, m, C), row_crcs np(B, k+m) | None)``.
         ``row_crcs`` is None when ``with_crc`` is False or the plugin
         has no fused path (callers fall back to zlib via
-        ec.crc.hcrc_attr)."""
+        ec.crc.hcrc_attr). ``span``: the op's span, where it has one:
+        its ``ec.agg_wait`` child runs from here to the op's result
+        (what ``batch_wait`` sums)."""
         data = np.ascontiguousarray(data, dtype=np.uint8)
         if not self.enabled() or self.stopped:
             # the measured per-op baseline: one UNPADDED launch per
@@ -153,7 +159,7 @@ class ECAggregator:
             # orthogonal to coalescing)
             self.perf.inc("bypass")
             try:
-                return self._run(ec, data, with_crc, pad=False)
+                return self._run(ec, data, with_crc, pad=False, ctx=span)
             except Exception as e:
                 return self._degrade_one(ec, data, with_crc, e)
         key = (str(ec.profile), int(data.shape[1]), int(data.shape[2]))
@@ -162,7 +168,9 @@ class ECAggregator:
             g = self._groups[key] = _Group(ec)
         loop = asyncio.get_event_loop()
         fut = loop.create_future()
-        g.entries.append(_Entry(data, with_crc, fut, loop.time()))
+        g.entries.append(_Entry(
+            data, with_crc, fut, loop.time(),
+            span.child("ec.agg_wait") if span is not None else None))
         g.stripes += data.shape[0]
         if g.stripes >= self.max_stripes():
             self._flush(key, g, "full")
@@ -210,15 +218,22 @@ class ECAggregator:
         entries = g.entries
         if not entries:
             return
-        datas = [e.data for e in entries]
-        big = datas[0] if len(datas) == 1 else \
-            np.concatenate(datas, axis=0)
+        # the launch serves every op of the batch; its sections hang
+        # off the first one's wait
+        ctx = entries[0].span
+        with tracing.section("ec.pack", ctx, self.tracer) as sec:
+            datas = [e.data for e in entries]
+            big = datas[0] if len(datas) == 1 else \
+                np.concatenate(datas, axis=0)
+            sec.tag("ops", len(entries)).tag("stripes",
+                                             int(big.shape[0]))
         want_crc = any(e.with_crc for e in entries)
         loop = asyncio.get_event_loop()
         try:
-            parity, crcs = self._run(g.ec, big, want_crc)
+            parity, crcs = self._run(g.ec, big, want_crc, ctx=ctx)
         except Exception as e:
             self._degrade(g.ec, entries, e)
+            self._end_waits(entries, trigger)
             return
         off = 0
         now = loop.time()
@@ -231,6 +246,7 @@ class ECAggregator:
                 ent.fut.set_result(res)
             self.perf.avg_add("batch_wait", now - ent.t0)
             off += b
+        self._end_waits(entries, trigger)
         self.perf.inc("batches")
         self.perf.inc("stripes", int(big.shape[0]))
         self.perf.inc("ops", len(entries))
@@ -238,6 +254,12 @@ class ECAggregator:
         self.perf.avg_add("batch_occupancy", float(big.shape[0]))
         log.dout(10, f"ec_agg flush {trigger}: {len(entries)} ops, "
                      f"{big.shape[0]} stripes")
+
+    @staticmethod
+    def _end_waits(entries, trigger: str) -> None:
+        for ent in entries:
+            if ent.span is not None:
+                ent.span.tag("trigger", trigger).finish()
 
     # -- degrade ladder (round 16) -----------------------------------------
     def _degrade(self, ec, entries, err: Exception) -> None:
@@ -298,30 +320,50 @@ class ECAggregator:
         """Next power of two: bounds the jit cache to O(log) shapes."""
         return 1 << (int(b) - 1).bit_length() if b > 1 else 1
 
-    def _run(self, ec, data, want_crc: bool, pad: bool = True):
+    def _run(self, ec, data, want_crc: bool, pad: bool = True,
+             ctx=None):
         """One device launch over a (possibly padded) batch. The fused
         checksum+encode jit carries its own quarantine: after it
         raises, flushes drop to plain encode + host crc (callers'
         zlib path) until an exponential-backoff deadline
         (osd_ec_fallback_quarantine_base/_max) passes, then the fused
-        path is probed again by simply serving the next crc flush."""
+        path is probed again by simply serving the next crc flush.
+        ``ctx``: the span the ``ec.*`` sections hang off."""
         b = data.shape[0]
         padded = self._pad(b) if pad else b
         if padded != b:
-            pad = np.zeros((padded - b,) + data.shape[1:],
-                           dtype=np.uint8)
-            data = np.concatenate([data, pad], axis=0)
+            with tracing.section("ec.pack", ctx, self.tracer) as sec:
+                pad = np.zeros((padded - b,) + data.shape[1:],
+                               dtype=np.uint8)
+                data = np.concatenate([data, pad], axis=0)
+                sec.tag("padded", padded - b)
         if want_crc and time.monotonic() >= self._crc_q_until:
             try:
-                parity, crcs = ec.encode_batch_with_crc(data)
-                parity = np.asarray(parity)[:b]
-                crcs = None if crcs is None else np.asarray(crcs)[:b]
+                # ec.launch: H2D and the enqueue; ec.device_wait: the
+                # blocking read-back (the device finishes, then D2H)
+                with tracing.section("ec.launch", ctx,
+                                     self.tracer) as sec:
+                    sec.tag("engine", "encode_crc").tag("stripes",
+                                                        padded)
+                    parity, crcs = ec.encode_batch_with_crc(data)
+                with tracing.section("ec.device_wait", ctx,
+                                     self.tracer) as sec:
+                    parity = np.asarray(parity)[:b]
+                    crcs = None if crcs is None \
+                        else np.asarray(crcs)[:b]
+                    sec.tag("bytes", int(parity.nbytes))
             except Exception as e:
                 self._crc_fail(e)
             else:
                 self._crc_failures = 0
                 return parity, crcs
-        return np.asarray(ec.encode_batch(data))[:b], None
+        with tracing.section("ec.launch", ctx, self.tracer) as sec:
+            sec.tag("engine", "encode").tag("stripes", padded)
+            parity = ec.encode_batch(data)
+        with tracing.section("ec.device_wait", ctx, self.tracer) as sec:
+            parity = np.asarray(parity)[:b]
+            sec.tag("bytes", int(parity.nbytes))
+        return parity, None
 
     def _crc_fail(self, e: Exception) -> None:
         self.perf.inc("crc_fallbacks")

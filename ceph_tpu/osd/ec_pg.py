@@ -23,6 +23,7 @@ every shard).
 from __future__ import annotations
 
 import asyncio
+import time
 
 import numpy as np
 
@@ -40,6 +41,7 @@ from ceph_tpu.osd.messages import (
 )
 from ceph_tpu.osd.pg import PG, PGMETA
 from ceph_tpu.osd.pg_log import OP_DELETE, OP_MODIFY, LogEntry, eversion
+from ceph_tpu.utils import tracing
 from ceph_tpu.utils.logging import get_logger
 
 log = get_logger("osd")
@@ -158,13 +160,15 @@ class ECPG(PG):
         except ValueError:
             return -1
 
-    def _local_shard_state(self, oid: str):
-        """(exists, shard bytes, version, logical size)."""
-        try:
-            data = self.osd.store.read(self.cid, oid)
-            attrs = self.osd.store.getattrs(self.cid, oid)
-        except StoreError:
-            return False, b"", eversion(), 0
+    def _local_shard_state(self, oid: str, ctx=None):
+        """(exists, shard bytes, version, logical size). ``ctx``: the
+        span or message of the op that reads, where there is one."""
+        with tracing.section("store.read", ctx, self.osd.tracer):
+            try:
+                data = self.osd.store.read(self.cid, oid)
+                attrs = self.osd.store.getattrs(self.cid, oid)
+            except StoreError:
+                return False, b"", eversion(), 0
         return True, data, _vparse(attrs.get("_v")), \
             int.from_bytes(attrs.get("_size", b"\0" * 8), "little")
 
@@ -185,26 +189,28 @@ class ECPG(PG):
     def _pos_attr(pos: int) -> bytes:
         return int(pos).to_bytes(4, "little", signed=True)
 
-    def _obj_version(self, oid: str) -> eversion:
-        return self._local_shard_state(oid)[2]
+    def _obj_version(self, oid: str, ctx=None) -> eversion:
+        return self._local_shard_state(oid, ctx)[2]
 
-    def _obj_size(self, oid: str) -> int:
-        exists, _, _, size = self._local_shard_state(oid)
+    def _obj_size(self, oid: str, ctx=None) -> int:
+        exists, _, _, size = self._local_shard_state(oid, ctx)
         if not exists:
             raise StoreError(f"no object {oid}")
         return size
 
     # -- chunk gathering (the ReadPipeline) --------------------------------
     async def _subread(self, osd_id: int, oid: str, chunk_off: int,
-                       chunk_len: int):
+                       chunk_len: int, span=None):
         tid = self.osd.next_tid()
         fut = asyncio.get_event_loop().create_future()
         self._subread_waiters[tid] = fut
         try:
-            await self.osd.send_osd(osd_id, MOSDECSubOpRead(
+            msg = MOSDECSubOpRead(
                 tid=tid, epoch=self.epoch, pgid=self.cid, oid=oid,
                 chunk_off=chunk_off, chunk_len=chunk_len,
-                from_osd=self.osd.whoami))
+                from_osd=self.osd.whoami)
+            msg.set_trace(span)
+            await self.osd.send_osd(osd_id, msg)
             return await asyncio.wait_for(fut, timeout=5.0)
         except (asyncio.TimeoutError, ConnectionError, OSError):
             return None
@@ -250,13 +256,24 @@ class ECPG(PG):
         off, ln = first * C, count * C
         cache = getattr(self.osd, "ec_resident", None)
         ckey = None
+        # a rebuild runs beside the PG's client op, not under it
+        op_span = None if repair else self._active_span
+        tracer = self.osd.tracer
         if cache is not None and not exclude_osds:
             ckey = (str(self.cid), oid, int(first), int(count),
                     _vblob(version))
-            hit = cache.get(ckey)
-            if hit is not None:
-                return np.asarray(hit)
+            with tracing.section("ec.cache_lookup", op_span,
+                                 tracer) as sec:
+                hit = cache.get(ckey)
+                sec.tag("hit", hit is not None)
+                if hit is not None:
+                    return np.asarray(hit)        # the D2H read-back
         avail: dict[int, np.ndarray] = {}
+        # the sub-reads go out one after another: one interval over the
+        # whole round, the sub-reads' frames and the shard OSDs'
+        # osd.ec_sub_read sections its children
+        wait_span = op_span.child("osd.ec_subread_wait") \
+            if op_span is not None else None
         for slot, osd_id in enumerate(self.acting):
             # stop once decodable: all data positions in hand, or any
             # k positions once every data SLOT has been tried (MDS
@@ -268,32 +285,38 @@ class ECPG(PG):
                     not self.osd.osd_is_up(osd_id):
                 continue
             if osd_id == self.osd.whoami:
-                exists, data, ver, _size = self._local_shard_state(oid)
+                exists, data, ver, _size = self._local_shard_state(
+                    oid, wait_span)
                 if not exists or ver != version:
                     continue
                 pos = self._stored_pos(oid, default=slot)
-                chunk = np.zeros(ln, dtype=np.uint8)
                 piece = data[off:off + ln]
-                chunk[:len(piece)] = np.frombuffer(piece, dtype=np.uint8)
             else:
-                reply = await self._subread(osd_id, oid, off, ln)
+                reply = await self._subread(osd_id, oid, off, ln,
+                                            wait_span)
                 if reply is None or not reply.exists:
                     continue
                 if eversion(reply.version_epoch,
                             reply.version_v) != version:
                     continue
                 pos = reply.shard_pos if reply.shard_pos >= 0 else slot
-                chunk = np.zeros(ln, dtype=np.uint8)
                 piece = reply.data[:ln]
+            with tracing.section("osd.ec_assemble", wait_span, tracer):
+                chunk = np.zeros(ln, dtype=np.uint8)
                 chunk[:len(piece)] = np.frombuffer(piece, dtype=np.uint8)
             if pos < 0 or pos >= self.k + self.m or pos in avail:
                 continue
             avail[pos] = chunk.reshape(count, C)
+        if wait_span is not None:
+            wait_span.tag("shards", sorted(avail)).finish()
         want = set(range(self.k))
         if want <= set(avail):
-            out = np.stack([avail[c] for c in range(self.k)], axis=1)
+            with tracing.section("osd.ec_assemble", op_span, tracer):
+                out = np.stack([avail[c] for c in range(self.k)],
+                               axis=1)
             if ckey is not None:
-                cache.put(ckey, out)
+                with tracing.section("ec.cache_fill", op_span, tracer):
+                    cache.put(ckey, out)
             return out
         # degraded: decode missing data chunks from what we have —
         # routed through the OSD's cross-op read aggregator, which
@@ -309,18 +332,21 @@ class ECPG(PG):
                 f"{oid}: {len(avail)} fresh shards < k={self.k} "
                 f"(have {sorted(avail)})")
         use = sorted(need)
-        stacked = np.stack([avail[c] for c in use], axis=1)
+        with tracing.section("osd.ec_assemble", op_span, tracer):
+            stacked = np.stack([avail[c] for c in use], axis=1)
         missing = sorted(want - set(avail))
         decoded = await self._agg_decode(missing, use, stacked,
                                          repair=repair)
-        out = np.zeros((count, self.k, C), dtype=np.uint8)
-        for c in range(self.k):
-            if c in avail:
-                out[:, c] = avail[c]
-            else:
-                out[:, c] = np.asarray(decoded[:, missing.index(c)])
+        with tracing.section("osd.ec_assemble", op_span, tracer):
+            out = np.zeros((count, self.k, C), dtype=np.uint8)
+            for c in range(self.k):
+                if c in avail:
+                    out[:, c] = avail[c]
+                else:
+                    out[:, c] = np.asarray(decoded[:, missing.index(c)])
         if ckey is not None:
-            cache.put(ckey, out)
+            with tracing.section("ec.cache_fill", op_span, tracer):
+                cache.put(ckey, out)
         return out
 
     # -- client op execution ----------------------------------------------
@@ -456,26 +482,36 @@ class ECPG(PG):
 
     async def _read_range(self, oid: str, off: int,
                           length: int) -> bytes:
-        size = self._obj_size(oid)          # raises if absent
+        op_span = self._active_span
+        size = self._obj_size(oid, op_span)     # raises if absent
         end = size if not length else min(off + length, size)
         if off >= end:
             return b""
-        version = self._obj_version(oid)
+        version = self._obj_version(oid, op_span)
         first, count = self.sinfo.stripe_range(off, end - off)
         stripes = await self._gather(oid, first, count, version)
-        flat = stripes.reshape(-1).tobytes()
-        W = self.sinfo.stripe_width
-        lo = off - first * W
-        return flat[lo:lo + (end - off)]
+        with tracing.section("osd.ec_assemble", op_span,
+                             self.osd.tracer) as sec:
+            flat = stripes.reshape(-1).tobytes()
+            W = self.sinfo.stripe_width
+            lo = off - first * W
+            sec.tag("bytes", end - off)
+            return flat[lo:lo + (end - off)]
 
     # -- the RMW + sub-op write pipeline -----------------------------------
     async def _submit_ec_write(self, oid, edits, write_full, new_size,
                                deleted, attrs_delta, omap_delta,
                                omap_rm=()) -> int:
+        op_span = self._active_span
+        tracer = self.osd.tracer
+        # osd.ec_prepare: the synchronous stretches of this function,
+        # each closed by hand before the await that ends it
+        sec = tracing.section("osd.ec_prepare", op_span, tracer)
         live = self.live_acting()
         if len(live) < self.pool.min_size:
             return -11
-        exists, _, old_version, old_size = self._local_shard_state(oid)
+        exists, _, old_version, old_size = self._local_shard_state(
+            oid, op_span)
         old = None
         if not deleted and write_full is None:
             size = old_size if exists else 0
@@ -495,12 +531,14 @@ class ECPG(PG):
             # k fresh shards mid-recovery) must EAGAIN with no side
             # effects, not log an entry it then cannot apply
             if exists:
+                sec.finish()
                 try:
                     old = await self._gather(oid, first, count,
                                              old_version)
                 except UnreadableNow as e:
                     log.dout(5, f"pg {self.pgid} rmw parks: {e}")
                     return -11
+                sec = tracing.section("osd.ec_prepare", op_span, tracer)
             else:
                 old = np.zeros((count, self.k, self.sinfo.chunk_size),
                                dtype=np.uint8)
@@ -511,6 +549,7 @@ class ECPG(PG):
         self.pg_log.trim(keep=self._trim_keep())
         self._meta_txn_store()
         if deleted:
+            sec.finish()
             return await self._fan_out_delete(oid, entry)
         if write_full is not None:
             logical = write_full
@@ -543,8 +582,10 @@ class ECPG(PG):
         C = self.sinfo.chunk_size
         data_chunks = buf.reshape(count, self.k, C)
         whole = write_full is not None
-        parity, row_crcs = await self._agg_encode(data_chunks,
-                                                  with_crc=whole)
+        sec.tag("stripes", count).finish()
+        parity, row_crcs = await self._agg_encode(
+            data_chunks, with_crc=whole, span=op_span)
+        sec = tracing.section("osd.ec_prepare", op_span, tracer)
         attrs_delta = dict(attrs_delta)
         attrs_delta["_v"] = _vblob(version)
         attrs_delta["_size"] = size.to_bytes(8, "little")
@@ -587,6 +628,7 @@ class ECPG(PG):
                 truncate_stripes=trunc_stripes, size=size,
                 remove=False, attrs=attrs, omap=omap_delta,
                 omap_rm=list(omap_rm), log_entry=entry_blob)
+        sec.tag("stripes", count).finish()
         committed = await self._fan_out_subops(tid, per_osd)
         if committed < self.k:
             # fewer than k durable shards: the object would be
@@ -616,6 +658,7 @@ class ECPG(PG):
                               ) -> int:
         """Apply locally + send to peers + await acks. Returns how many
         shards actually committed (local apply counts as one)."""
+        t_fan = time.monotonic()
         committed = 0
         pending: set[int] = set()
         waiter = asyncio.get_event_loop().create_future()
@@ -627,19 +670,18 @@ class ECPG(PG):
         sub_span = op_span.child(
             "ec_subop_wait",
             tags={"shards": sorted(per_osd)}) if op_span else None
-        for osd_id, msg in per_osd.items():
-            if osd_id == self.osd.whoami:
-                store_span = op_span.child(
-                    "objectstore_commit",
-                    tags={"osd": self.osd.whoami}) if op_span else None
-                if self._apply_sub_write(msg, local=True) == 0:
-                    committed += 1
-                if store_span is not None:
-                    store_span.finish()
-            else:
-                pending.add(osd_id)
-                msg.set_trace(sub_span)
-                remote.append((osd_id, msg))
+        with tracing.section("osd.ec_fanout", op_span,
+                             self.osd.tracer) as sec:
+            sec.tag("shards", len(per_osd))
+            for osd_id, msg in per_osd.items():
+                if osd_id == self.osd.whoami:
+                    if self._apply_sub_write(msg, local=True,
+                                             ctx=op_span) == 0:
+                        committed += 1
+                else:
+                    pending.add(osd_id)
+                    msg.set_trace(sub_span)
+                    remote.append((osd_id, msg))
         failed: set[int] = set()
         self._subop_waiters[tid] = (pending, waiter, failed)
         sent = set()
@@ -662,6 +704,11 @@ class ECPG(PG):
         # apply — it must not count toward the >=k durability check, or
         # the client could be acked with fewer than k live shards.
         committed += len((sent - remaining) - failed)
+        if committed == len(per_osd):
+            # the `ceph osd perf` commit leg for an EC pool: fan-out to
+            # the last of the k+m commits (ref: os_commit_latency)
+            self.osd.perf.avg_add("commit_latency",
+                                  time.monotonic() - t_fan)
         return committed
 
     def _meta_txn_store(self) -> None:
@@ -669,7 +716,21 @@ class ECPG(PG):
 
     # -- sub-op handling (shard side) --------------------------------------
     def _apply_sub_write(self, m: MOSDECSubOpWrite,
-                         local: bool = False) -> int:
+                         local: bool = False, ctx=None) -> int:
+        """Apply one shard's sub-write to this OSD's store: the
+        ``objectstore_commit`` section, under ``ctx`` (the op's span on
+        the primary, the sub-write's on a shard OSD)."""
+        t0 = time.monotonic()
+        with tracing.section("objectstore_commit", ctx,
+                             self.osd.tracer) as sec:
+            sec.tag("osd", self.osd.whoami)
+            result = self._apply_sub_write_txn(m, local)
+        # the `ceph osd perf` apply leg (ref: os_apply_latency)
+        self.osd.perf.avg_add("apply_latency", time.monotonic() - t0)
+        return result
+
+    def _apply_sub_write_txn(self, m: MOSDECSubOpWrite,
+                             local: bool) -> int:
         # hot-shard residency: this object's cached generations are
         # already unreachable (version-keyed), reclaim their bytes now
         cache = getattr(self.osd, "ec_resident", None)
@@ -708,22 +769,20 @@ class ECPG(PG):
         span = self.osd.tracer.from_msg(
             "ec_sub_write", m, tags={"osd": self.osd.whoami,
                                      "oid": m.oid})
-        store_span = span.child(
-            "objectstore_commit",
-            tags={"osd": self.osd.whoami}) if span else None
-        result = self._apply_sub_write(m)
-        if store_span is not None:
-            store_span.finish()
+        result = self._apply_sub_write(m, ctx=span or m)
         if span is not None:
             if result != 0:
                 span.tag("result", result)
             span.finish()
 
+        ack = MOSDECSubOpWriteReply(
+            tid=m.tid, result=result, pgid=self.cid,
+            from_osd=self.osd.whoami)
+        ack.set_trace(span)               # its frames hang off the apply
+
         async def _ack():
             try:
-                await m.conn.send_message(MOSDECSubOpWriteReply(
-                    tid=m.tid, result=result, pgid=self.cid,
-                    from_osd=self.osd.whoami))
+                await m.conn.send_message(ack)
             except Exception:
                 pass
         asyncio.ensure_future(_ack())
@@ -740,18 +799,23 @@ class ECPG(PG):
             fut.set_result(True)
 
     def handle_ec_sub_read(self, m: MOSDECSubOpRead) -> None:
-        exists, data, ver, size = self._local_shard_state(m.oid)
-        piece = data[m.chunk_off:m.chunk_off + m.chunk_len] if exists \
-            else b""
-        pos = self._stored_pos(m.oid) if exists else -1
+        with tracing.section("osd.ec_sub_read", m,
+                             self.osd.tracer) as sec:
+            exists, data, ver, size = self._local_shard_state(m.oid, m)
+            piece = data[m.chunk_off:m.chunk_off + m.chunk_len] \
+                if exists else b""
+            pos = self._stored_pos(m.oid) if exists else -1
+            reply = MOSDECSubOpReadReply(
+                tid=m.tid, pgid=self.cid, oid=m.oid, exists=exists,
+                data=piece, version_epoch=ver.epoch,
+                version_v=ver.v, size=size,
+                from_osd=self.osd.whoami, shard_pos=pos)
+            reply.set_trace(sec)          # its frames hang off the read
+            sec.tag("osd", self.osd.whoami).tag("bytes", len(piece))
 
         async def _reply():
             try:
-                await m.conn.send_message(MOSDECSubOpReadReply(
-                    tid=m.tid, pgid=self.cid, oid=m.oid, exists=exists,
-                    data=piece, version_epoch=ver.epoch,
-                    version_v=ver.v, size=size,
-                    from_osd=self.osd.whoami, shard_pos=pos))
+                await m.conn.send_message(reply)
             except Exception:
                 pass
         asyncio.ensure_future(_reply())
@@ -816,16 +880,18 @@ class ECPG(PG):
                 best = (ver, size)
         return best
 
-    async def _agg_encode(self, data_chunks, with_crc: bool = False):
+    async def _agg_encode(self, data_chunks, with_crc: bool = False,
+                          span=None):
         """Every ECPG encode routes through the OSD's cross-op
         aggregator (osd/ec_aggregator.py); the per-op launch survives
         behind ``osd_ec_agg=off`` inside it. Bare harnesses without a
         daemon aggregator take a direct (still fused) call. Returns
-        ``(parity np(B, m, C), row_crcs np(B, k+m) | None)``."""
+        ``(parity np(B, m, C), row_crcs np(B, k+m) | None)``.
+        ``span``: the client op's span where the encode serves one."""
         agg = getattr(self.osd, "ec_agg", None)
         if agg is not None:
             return await agg.encode(self.ec, data_chunks,
-                                    with_crc=with_crc)
+                                    with_crc=with_crc, span=span)
         if with_crc:
             parity, crcs = self.ec.encode_batch_with_crc(data_chunks)
             return np.asarray(parity), \
@@ -846,7 +912,8 @@ class ECPG(PG):
         if agg is not None:
             return await agg.decode(
                 self.ec, want, avail, chunks,
-                charge_bytes=int(chunks.nbytes) if repair else 0)
+                charge_bytes=int(chunks.nbytes) if repair else 0,
+                span=None if repair else self._active_span)
         return np.asarray(self.ec.decode_batch(want, avail, chunks))
 
     async def _rebuild_shard(self, oid: str, shard: int, ver: eversion,

@@ -49,6 +49,7 @@ import time
 
 import numpy as np
 
+from ceph_tpu.utils import tracing
 from ceph_tpu.utils.logging import get_logger
 from ceph_tpu.utils.perf_counters import PerfCountersBuilder
 
@@ -99,12 +100,13 @@ def _read_agg_perf():
 
 
 class _Entry:
-    __slots__ = ("chunks", "fut", "t0")
+    __slots__ = ("chunks", "fut", "t0", "span")
 
-    def __init__(self, chunks, fut, t0):
+    def __init__(self, chunks, fut, t0, span=None):
         self.chunks = chunks
         self.fut = fut
         self.t0 = t0
+        self.span = span        # the op's ec.agg_wait interval
 
 
 class _Group:
@@ -125,9 +127,11 @@ class _Group:
 class ECReadAggregator:
     """One per OSD daemon; every ECPG decode routes through it."""
 
-    def __init__(self, config: dict | None = None, scheduler=None):
+    def __init__(self, config: dict | None = None, scheduler=None,
+                 tracer=None):
         self.config = config if config is not None else {}
         self.scheduler = scheduler
+        self.tracer = tracer    # the owning daemon's, for the sections
         self.perf = _read_agg_perf()
         self._groups: dict[tuple, _Group] = {}
         self.stopped = False
@@ -154,7 +158,7 @@ class ECReadAggregator:
 
     # -- submit ------------------------------------------------------------
     async def decode(self, ec, want, avail, chunks,
-                     charge_bytes: int = 0):
+                     charge_bytes: int = 0, span=None):
         """Decode a (B, len(avail), C) uint8 batch into the ``want``
         chunk rows; returns np (B, len(want), C).
 
@@ -163,7 +167,9 @@ class ECReadAggregator:
         bytes/osd_qos_cost_per_io_bytes is paid before the op queues,
         the same divisor client writes pay at admission. Client
         degraded reads pass 0 — their cost tag was already charged by
-        the daemon's admission path."""
+        the daemon's admission path. ``span``: the op's span, where it
+        has one: its ``ec.agg_wait`` child runs from the enqueue to the
+        op's result (what ``batch_wait`` sums)."""
         chunks = np.ascontiguousarray(chunks, dtype=np.uint8)
         want = tuple(want)
         avail = tuple(avail)
@@ -180,7 +186,8 @@ class ECReadAggregator:
             # flatter the aggregator's speedup
             self.perf.inc("bypass")
             try:
-                return self._run(ec, want, avail, chunks, pad=False)
+                return self._run(ec, want, avail, chunks, pad=False,
+                                 ctx=span)
             except Exception as e:
                 return self._degrade_one(ec, want, avail, chunks, e)
         key = (str(ec.profile), avail, want, int(chunks.shape[2]))
@@ -189,7 +196,9 @@ class ECReadAggregator:
             g = self._groups[key] = _Group(ec, want, avail)
         loop = asyncio.get_event_loop()
         fut = loop.create_future()
-        g.entries.append(_Entry(chunks, fut, loop.time()))
+        g.entries.append(_Entry(
+            chunks, fut, loop.time(),
+            span.child("ec.agg_wait") if span is not None else None))
         g.stripes += chunks.shape[0]
         if g.stripes >= self.max_stripes():
             self._flush(key, g, "full")
@@ -237,14 +246,21 @@ class ECReadAggregator:
         entries = g.entries
         if not entries:
             return
-        datas = [e.chunks for e in entries]
-        big = datas[0] if len(datas) == 1 else \
-            np.concatenate(datas, axis=0)
+        # the launch serves every op of the batch; its sections hang
+        # off the first one's wait
+        ctx = entries[0].span
+        with tracing.section("ec.pack", ctx, self.tracer) as sec:
+            datas = [e.chunks for e in entries]
+            big = datas[0] if len(datas) == 1 else \
+                np.concatenate(datas, axis=0)
+            sec.tag("ops", len(entries)).tag("stripes",
+                                             int(big.shape[0]))
         loop = asyncio.get_event_loop()
         try:
-            out = self._run(g.ec, g.want, g.avail, big)
+            out = self._run(g.ec, g.want, g.avail, big, ctx=ctx)
         except Exception as e:
             self._degrade(g, entries, e)
+            self._end_waits(entries, trigger)
             return
         off = 0
         now = loop.time()
@@ -254,6 +270,7 @@ class ECReadAggregator:
                 ent.fut.set_result(out[off:off + b])
             self.perf.avg_add("batch_wait", now - ent.t0)
             off += b
+        self._end_waits(entries, trigger)
         self.perf.inc("batches")
         self.perf.inc("stripes", int(big.shape[0]))
         self.perf.inc("ops", len(entries))
@@ -261,6 +278,12 @@ class ECReadAggregator:
         self.perf.avg_add("batch_occupancy", float(big.shape[0]))
         log.dout(10, f"ec_read_agg flush {trigger}: {len(entries)} "
                      f"ops, {big.shape[0]} stripes")
+
+    @staticmethod
+    def _end_waits(entries, trigger: str) -> None:
+        for ent in entries:
+            if ent.span is not None:
+                ent.span.tag("trigger", trigger).finish()
 
     # -- degrade ladder ----------------------------------------------------
     def _degrade(self, g: _Group, entries, err: Exception) -> None:
@@ -340,10 +363,12 @@ class ECReadAggregator:
         """Next power of two: bounds the jit cache to O(log) shapes."""
         return 1 << (int(b) - 1).bit_length() if b > 1 else 1
 
-    def _run(self, ec, want, avail, chunks, pad: bool = True):
+    def _run(self, ec, want, avail, chunks, pad: bool = True,
+             ctx=None):
         """One device launch over a (possibly padded) batch; while the
         device decode is quarantined, serves the reference decoder
-        instead (bit-exact, so callers can't tell beyond latency)."""
+        instead (bit-exact, so callers can't tell beyond latency).
+        ``ctx``: the span the ``ec.*`` sections hang off."""
         if time.monotonic() < self._dev_q_until:
             self.perf.inc("quarantined_ops")
             return np.asarray(
@@ -352,10 +377,19 @@ class ECReadAggregator:
         b = chunks.shape[0]
         padded = self._pad(b) if pad else b
         if padded != b:
-            z = np.zeros((padded - b,) + chunks.shape[1:],
-                         dtype=np.uint8)
-            chunks = np.concatenate([chunks, z], axis=0)
-        out = np.asarray(ec.decode_batch(want, avail, chunks))[:b]
+            with tracing.section("ec.pack", ctx, self.tracer) as sec:
+                z = np.zeros((padded - b,) + chunks.shape[1:],
+                             dtype=np.uint8)
+                chunks = np.concatenate([chunks, z], axis=0)
+                sec.tag("padded", padded - b)
+        # ec.launch: H2D and the enqueue; ec.device_wait: the blocking
+        # read-back (the device finishes, then D2H)
+        with tracing.section("ec.launch", ctx, self.tracer) as sec:
+            sec.tag("engine", "decode").tag("stripes", padded)
+            out = ec.decode_batch(want, avail, chunks)
+        with tracing.section("ec.device_wait", ctx, self.tracer) as sec:
+            out = np.asarray(out)[:b]
+            sec.tag("bytes", int(out.nbytes))
         self._dev_failures = 0
         return out
 

@@ -51,6 +51,7 @@ from ceph_tpu.osd.pg_log import OP_DELETE, OP_MODIFY, LogEntry, PGLog, \
     eversion
 from ceph_tpu.osd.recovery import PERF as RECOVERY_PERF
 from ceph_tpu.osd.types import MAX_OID, MIN_OID, pg_t
+from ceph_tpu.utils import tracing
 from ceph_tpu.utils.logging import get_logger
 
 
@@ -59,7 +60,7 @@ def _finish_store_span(span, store) -> None:
     per-phase sub-spans (the kv/WAL split: WALStore reports
     apply/wal_kv_commit, BlueStore block_write/kv_commit/
     deferred_write) recorded during the synchronous commit."""
-    if span is None:
+    if not span:                          # None, or the section is off
         return
     for phase, dt in getattr(store, "last_txn_phases", {}).items():
         span.annotate(phase, dt)
@@ -1719,11 +1720,17 @@ class PG:
                      extra: dict) -> None:
         if m.conn is None:
             return
-        try:
-            await m.conn.send_message(MOSDOpReply(
+        op_span = getattr(m, "_span", None)
+        with tracing.section("osd.reply", op_span,
+                             self.osd.tracer) as sec:
+            reply = MOSDOpReply(
                 tid=m.tid, attempt=getattr(m, "attempt", 0),
                 result=result, epoch=self.epoch, data=data,
-                extra=json.dumps(extra) if extra else ""))
+                extra=json.dumps(extra) if extra else "")
+            # the reply's frames and the client's decode hang off it
+            reply.set_trace(sec or op_span)
+        try:
+            await m.conn.send_message(reply)
         except Exception:
             pass                          # client resends via objecter
 
@@ -2041,9 +2048,9 @@ class PG:
             self._repop_waiters[tid] = [set(replicas), waiter, reqid,
                                         False]
         op_span = self._active_span
-        store_span = op_span.child(
-            "objectstore_commit",
-            tags={"osd": self.osd.whoami}) if op_span else None
+        store_span = tracing.section(
+            "objectstore_commit", op_span,
+            self.osd.tracer).tag("osd", self.osd.whoami)
         import time as _time
         _t0 = _time.monotonic()
         try:
@@ -2138,9 +2145,9 @@ class PG:
                                     "pgid": self.cid})
         entry = LogEntry.decode(m.log_entry)
         t = Transaction.decode(m.txn)
-        store_span = span.child(
-            "objectstore_commit",
-            tags={"osd": self.osd.whoami}) if span else None
+        store_span = tracing.section(
+            "objectstore_commit", span or m,
+            self.osd.tracer).tag("osd", self.osd.whoami)
         import time as _time
         _t0 = _time.monotonic()
         try:

@@ -40,6 +40,7 @@ from ceph_tpu.osd.messages import (
 )
 from ceph_tpu.osd.osdmap import FLAG_FULL, FLAG_PAUSERD, FLAG_PAUSEWR
 from ceph_tpu.osd.types import ObjectLocator
+from ceph_tpu.utils import tracing
 from ceph_tpu.utils.logging import get_logger
 from ceph_tpu.utils.op_tracker import OpTracker
 
@@ -66,6 +67,7 @@ class Objecter(Dispatcher):
         # context rides every MOSDOp hop of the op
         from ceph_tpu.utils.tracing import Tracer
         self.tracer = Tracer("client", config)
+        self.msgr.tracer = self.tracer    # the msg.* sections' keeper
         self._trace_flush_at = 0.0
         self._trace_flush_later: object | None = None
         # default per-op deadline and resend cap (ref: objecter's
@@ -101,10 +103,11 @@ class Objecter(Dispatcher):
 
     async def ms_dispatch(self, msg) -> bool:
         if isinstance(msg, MOSDOpReply):
-            fut = self._waiters.pop(
-                (msg.tid, getattr(msg, "attempt", 0)), None)
-            if fut and not fut.done():
-                fut.set_result(msg)
+            with tracing.section("client.reply", msg, self.tracer):
+                fut = self._waiters.pop(
+                    (msg.tid, getattr(msg, "attempt", 0)), None)
+                if fut and not fut.done():
+                    fut.set_result(msg)
             return True
         if isinstance(msg, MOSDMapPingReply):
             fut = self._map_ping_waiters.pop(msg.tid, None)
@@ -298,6 +301,9 @@ class Objecter(Dispatcher):
                 raise ObjectOperationError(
                     -110, f"op on {oid} failed after {attempt} attempts")
             osdmap = await self.monc.wait_for_osdmap()
+            # client.submit: target calc and building the MOSDOp, up to
+            # the send; closed by hand before a branch that parks awaits
+            sec = tracing.section("client.submit", span, self.tracer)
             gate = self._flag_gate(osdmap, pool_id, has_write)
             if gate is not None:
                 reason, errno = gate
@@ -308,6 +314,7 @@ class Objecter(Dispatcher):
                 # park on the wait-queue: the incremental that clears
                 # the flag (or raises the quota) resumes the op
                 tracked.mark_event(f"parked ({reason})")
+                sec.finish()
                 await self._wait_for_new_map(osdmap, deadline)
                 continue
             if seed is not None:
@@ -318,6 +325,7 @@ class Objecter(Dispatcher):
                                                      oid)
             if primary < 0 or primary not in osdmap.osd_addrs:
                 tracked.mark_event("no primary; waiting for map")
+                sec.finish()
                 await self._refresh_map(osdmap)
                 continue
             backoff = self._match_backoff(pool_id, pg_seed, oid)
@@ -327,6 +335,7 @@ class Objecter(Dispatcher):
                 # self-heal window expires (UNBLOCK lost / OSD died)
                 tracked.mark_event(
                     f"parked (backoff from osd.{backoff[2]})")
+                sec.finish()
                 await self._wait_backoff(backoff, pool_id, pg_seed,
                                          primary, deadline)
                 continue
@@ -342,6 +351,7 @@ class Objecter(Dispatcher):
                                      attempt=attempt, snapc=snapc,
                                      snap_id=snap_id, flags=flags)
                 op_msg.set_trace(span)
+                sec.tag("bytes", sum(len(o[4]) for o in ops)).finish()
                 await self.msgr.send_message(
                     op_msg, EntityAddr(host, port), f"osd.{primary}")
                 reply = await asyncio.wait_for(
@@ -380,8 +390,9 @@ class Objecter(Dispatcher):
                 tracked.mark_event("ENOSPC from failsafe; map stale")
                 await self._wait_for_new_map(osdmap, deadline)
                 continue
-            tracked.mark_event("reply received")
-            extra = json.loads(reply.extra) if reply.extra else {}
+            with tracing.section("client.reply", span, self.tracer):
+                tracked.mark_event("reply received")
+                extra = json.loads(reply.extra) if reply.extra else {}
             return reply.result, reply.data, extra
 
     async def _wait_backoff(self, ent: list, pool_id: int, seed: int,

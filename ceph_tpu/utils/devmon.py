@@ -22,11 +22,19 @@ Two kinds of :class:`DeviceRuntimeMonitor` exist:
   ``device_runtime``, registered in the process collection): the
   compile/transfer side. Process-level code — ``crush.mapper``,
   ``crush.sharded_sweep``, ``ec.jax_plugin`` — reports here, because
-  the jit caches it observes are process-wide. A daemon's Tracer can
-  be attached (:meth:`attach_tracer`) so each first-compile emits a
-  deterministic ``jit_compile`` span (never sampled away — compiles
-  are rare, operator-critical events) that ships monward on the
-  daemon's existing report piggyback and lands in ``trace ls/show``.
+  the jit caches it observes are process-wide. It also listens to
+  ``jax.monitoring`` (registered once, with the singleton): every
+  backend compile is counted (``xla_compiles``, ``xla_compile_seconds``,
+  ``xla_cache_hits`` for those the persistent cache served) and kept in
+  a bounded list (:func:`compile_events`) beside the ``jit_call`` it
+  happened under. ``jit_compiles`` stays what it was — first calls per
+  (function object, shape), re-traces that hit JAX's caches among them.
+  A daemon's Tracer can be attached (:meth:`attach_tracer`) so each
+  ``jit_call`` that really compiled emits one ``jit_compile`` span
+  (never sampled away — compiles are rare, operator-critical events;
+  its duration is the backend's, its ``cached`` tag says whether the
+  persistent cache served it) that ships monward on the daemon's
+  existing report piggyback and lands in ``trace ls/show``.
 - **per-daemon instances** (``register=False``, counter family
   ``devmon``, reaching ``/metrics`` only through the daemon's
   MMgrReport session — the round-13 ``osd_ec_agg`` discipline): the
@@ -70,6 +78,57 @@ _WARM_MAX = 4096
 # sim.faults' device kinds (jit_fail / jit_stall / bad_result) fire.
 # Installed process-wide by Cluster.install_faults; None in production.
 _fault_injector = None
+
+# -- the compile listener ------------------------------------------------------
+# jax.monitoring events: the backend compile (fun_name, seconds — a
+# retrieval from the persistent cache reports here too, with the
+# retrieval's time) and the marker that precedes it on a cache hit
+_EV_COMPILE = "/jax/core/compile/backend_compile_duration"
+_EV_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_EVENTS_MAX = 4096
+# (perf_counter_ns at the compile's end, fun_name, seconds, cached,
+# program): program is the jit_call in progress on that thread, else
+# "other"
+_events: list[tuple] = []
+_tls = threading.local()
+
+
+def compile_events() -> list[tuple]:
+    """Every backend compile this process has seen since the singleton
+    was made (the oldest go once the list is full)."""
+    return _events
+
+
+def _on_event(event: str, **_kw) -> None:
+    if event == _EV_CACHE_HIT:
+        _tls.hit = True
+
+
+def _on_duration(event: str, seconds: float, **kw) -> None:
+    if event != _EV_COMPILE or _singleton is None:
+        return
+    cached = bool(getattr(_tls, "hit", False))
+    _tls.hit = False
+    calls = getattr(_tls, "calls", None)
+    seconds = float(seconds)
+    ev = (time.perf_counter_ns(), str(kw.get("fun_name", "?")), seconds,
+          cached, calls[-1][0] if calls else "other")
+    if calls:
+        calls[-1][1].append(ev)
+    if len(_events) >= _EVENTS_MAX:
+        del _events[:_EVENTS_MAX // 4]
+    _events.append(ev)
+    perf = _singleton.perf
+    perf.inc("xla_compiles")
+    perf.tinc("xla_compile_seconds", seconds)
+    if cached:
+        perf.inc("xla_cache_hits")
+
+
+def _listen() -> None:
+    from jax import monitoring
+    monitoring.register_event_listener(_on_event)
+    monitoring.register_event_duration_secs_listener(_on_duration)
 
 
 def set_fault_injector(inj) -> None:
@@ -141,6 +200,15 @@ class DeviceRuntimeMonitor:
             .add_time("jit_compile_seconds",
                       "wall seconds spent in compile-triggering first "
                       "calls")
+            .add_u64_counter("xla_compiles",
+                             "backend compiles jax reported (process "
+                             "monitor only; persistent-cache retrievals "
+                             "included)")
+            .add_time("xla_compile_seconds",
+                      "seconds inside those backend compiles")
+            .add_u64_counter("xla_cache_hits",
+                             "backend compiles the persistent compile "
+                             "cache served")
             .add_u64_counter("launches_pallas",
                              "map/sweep launches served by the fused "
                              "Pallas kernel (interpret included)")
@@ -212,10 +280,12 @@ class DeviceRuntimeMonitor:
 
     # -- wiring ------------------------------------------------------------
     def attach_tracer(self, tracer) -> None:
-        """Attach the owning daemon's Tracer: every first compile
-        emits one deterministic ``jit_compile`` span through it (in
-        multi-daemon test processes the last attach wins — one span
-        per compile either way, never zero, never double)."""
+        """Attach the owning daemon's Tracer: every ``jit_call`` under
+        which the backend compiled emits one ``jit_compile`` span
+        through it (in multi-daemon test processes the last attach
+        wins — one span per compiling call either way, never zero,
+        never double). The span has to ship monward on SOME daemon's
+        report, so a tracer it is."""
         self.tracer = tracer
 
     # -- compile accounting ------------------------------------------------
@@ -252,27 +322,35 @@ class DeviceRuntimeMonitor:
                 if len(self._warm) >= _WARM_MAX:
                     self._warm.pop(next(iter(self._warm)))
                 self._warm[k] = None
-        if warm:
+        # the compile listener files what the backend compiles under
+        # this call (warm by our book or not: the book can be wrong)
+        calls = getattr(_tls, "calls", None)
+        if calls is None:
+            calls = _tls.calls = []
+        mine = (fn_name, [])
+        calls.append(mine)
+        t0 = 0.0 if warm else time.perf_counter()
+        try:
             out = fn(*args)
-        else:
-            t0 = time.perf_counter()
-            try:
-                out = fn(*args)
-            except BaseException:
+        except BaseException:
+            if not warm:
                 with self._lock:
                     self._warm.pop(k, None)
-                raise
-            self.record_compile(fn_name, key,
-                                time.perf_counter() - t0)
+            raise
+        finally:
+            calls.pop()
+        if not warm:
+            self.record_compile(fn_name, key, time.perf_counter() - t0)
+        if mine[1]:
+            self._emit_compile_span(fn_name, key, mine[1])
         if corrupt:
             self.perf.inc("faults_injected")
             out = _corrupt_result(out)
         return out
 
     def record_compile(self, fn_name: str, key, seconds: float) -> None:
-        """One observed compile: counter + time sum + per-function
-        table + (when a tracer is attached) a deterministic
-        ``jit_compile`` span whose wall covers the first call."""
+        """One observed FIRST CALL: counter + time sum + per-function
+        table. Its wall is the call's, whatever JAX's caches did."""
         seconds = max(float(seconds), 0.0)
         self.perf.inc("jit_compiles")
         self.perf.tinc("jit_compile_seconds", seconds)
@@ -283,19 +361,22 @@ class DeviceRuntimeMonitor:
             ent["seconds"] = round(ent["seconds"] + seconds, 6)
             ent["last_key"] = str(key)[:120]
             ent["last_seconds"] = round(seconds, 6)
+
+    def _emit_compile_span(self, fn_name: str, key, events) -> None:
+        """One ``jit_compile`` span for a call under which the backend
+        compiled: the trace id is minted directly (head sampling must
+        not drop compile evidence), the duration is the backend's own
+        seconds added up, and it ends where the last compile ended."""
         tracer = self.tracer
-        if tracer is not None:
-            # a real Span, but assembled post-hoc: the trace id is
-            # minted directly (head sampling must not drop compile
-            # evidence) and the start is back-dated so the span's
-            # wall IS the measured first-call stall
-            from ceph_tpu.utils.tracing import Span, new_trace_id
-            s = Span(tracer, "jit_compile", new_trace_id(),
-                     tags={"fn": fn_name, "key": str(key)[:120]})
-            s.start -= seconds
-            s.duration = seconds
-            s.finished = True
-            tracer.record(s)
+        if tracer is None:
+            return
+        from ceph_tpu.utils.tracing import Span, new_trace_id
+        seconds = sum(ev[2] for ev in events)
+        s = Span(tracer, "jit_compile", new_trace_id(),
+                 tags={"fn": fn_name, "key": str(key)[:120],
+                       "cached": all(ev[3] for ev in events),
+                       "xla": [ev[1] for ev in events][:8]})
+        s.close_at(events[-1][0] - int(seconds * 1e9), events[-1][0])
 
     # -- kernel-path health ------------------------------------------------
     def expected_engine(self, plan_path: str | None) -> str:
@@ -468,4 +549,5 @@ def devmon() -> DeviceRuntimeMonitor:
     global _singleton
     if _singleton is None:
         _singleton = DeviceRuntimeMonitor()
+        _listen()
     return _singleton

@@ -8,6 +8,25 @@ logical op share a ``trace_id`` and link through ``parent_span_id``, and
 the context crosses message boundaries as two u64s appended to every
 wire ``Message`` (zero = untraced).
 
+Two kinds of span, one type:
+
+- an **interval** is a stage in an op's life that may hold ``await``s
+  (``queue``, ``ec_subop_wait``, ``ec.agg_wait``): intervals of
+  different ops overlap freely, so an interval's length is a WAIT, not
+  anybody's CPU time;
+- a **section** (:func:`section`, a context manager) is synchronous
+  work on the recording thread with no ``await`` inside. Sections nest
+  and never overlap otherwise, so the innermost open section at any
+  instant is what the thread is doing, self time (a section less the
+  sections inside it) is well defined, and the sum over all sections
+  is at most the thread's CPU time.
+
+One clock: both ends of every span are ``time.perf_counter_ns()``
+stamps (``t0_ns``/``t1_ns``); the wall ``start`` that ``dump()`` and
+``TraceIndex`` show is derived from one process-wide offset taken at
+import. ``jax.profiler`` host events are on the same clock, which is
+how a benchmark lays spans over the device's busy intervals.
+
 Sampling model:
 
 - **head-based**: ``trace_sampling_rate`` decides at the op's root
@@ -18,10 +37,26 @@ Sampling model:
   crosses ``trace_slow_keep_s`` it is assigned a trace id post-hoc and
   kept in the slow buffer — SLOW_OPS warnings stay drill-downable even
   at sampling 0. ``trace_slow_keep_s <= 0`` disables even this local
-  timing (the truly-off path the bench pins).
+  timing (the truly-off path the bench pins);
+- **capture**: while a ``jax.profiler`` session runs in the process
+  (:func:`capturing`), every ``client_op`` root is sampled as if the
+  rate were 1 — under a trace id with :data:`CAPTURE_ONLY` set, so no
+  daemon ships it to the operator's buffers — and every finished span
+  and section is appended as a tuple to one bounded process-wide list
+  (:func:`captured`). Sections are captured whether or not their op was
+  sampled, so ops already in flight when the session starts leave no
+  holes. No option turns this on: take a device trace, and the
+  program's spans of those seconds are there, on its clock. With no
+  session and sampling 0 a section costs that one check and allocates
+  nothing.
 
-Completed spans land in a bounded per-daemon buffer (asok
-``dump_tracing``) and a bounded ship queue the daemon's existing
+Names: the spans older than the sections keep theirs (``client_op``,
+``osd_op``, ``queue``, ``execute``, ``ec_subop_wait``, ``ec_sub_write``,
+``objectstore_commit``, ...); new ones are ``<layer>.<what>`` with the
+layer (``client``, ``msg``, ``osd``, ``ec``, ``store``) as the prefix.
+
+Completed spans of sampled ops land in a bounded per-daemon buffer
+(asok ``dump_tracing``) and a bounded ship queue the daemon's existing
 reporting loop drains monward (MPGStats / MDSBeacon piggyback,
 MTraceReport for clients); the mon pools them and the mgr
 TracingModule reassembles cross-daemon traces by trace_id
@@ -30,48 +65,180 @@ TracingModule reassembles cross-daemon traces by trace_id
 
 from __future__ import annotations
 
+import itertools
 import json
 import random
+import sys
+import threading
 import time
 from collections import OrderedDict, deque
 from typing import Any
 
+_clock = time.perf_counter_ns
+# wall seconds at perf_counter_ns() == 0: the one offset every span's
+# ``start`` is derived from (cross-daemon alignment stays on the wall)
+_WALL_OFFSET = time.time() - _clock() / 1e9
+
+INTERVAL = "interval"
+SECTION = "section"
+
+# set in a trace id minted because a profiler session was running, not
+# because the operator's sampling chose the op: every daemon the context
+# reaches can tell, and keeps such spans out of its buffers
+CAPTURE_ONLY = 1 << 62
+
 
 def new_trace_id() -> int:
-    """Nonzero 63-bit id (0 is the 'untraced' sentinel on the wire)."""
-    return random.getrandbits(63) | 1
+    """Nonzero 62-bit id (0 is the 'untraced' sentinel on the wire,
+    bit 62 is :data:`CAPTURE_ONLY`)."""
+    return random.getrandbits(62) | 1
+
+
+# span ids: odd numbers counted up from a random 62-bit start, unique
+# within the process and as good as random across processes, at a
+# quarter of getrandbits' cost (a captured op makes hundreds of spans)
+_span_ids = itertools.count(new_trace_id(), 2)
+_ID_MASK = (1 << 62) - 1
+_flatten = itertools.chain.from_iterable
+
+
+def wall_of(stamp_ns: int) -> float:
+    """Wall-clock seconds of a ``perf_counter_ns`` stamp."""
+    return _WALL_OFFSET + stamp_ns / 1e9
+
+
+# -- capture -----------------------------------------------------------------
+
+# what one captured record holds, in order; the span's tags follow as
+# key, value, key, value (one flat tuple of atoms a record: the
+# collector stops tracking it, and a session keeps tens of thousands)
+FIELDS = ("kind", "name", "service", "thread", "t0_ns", "t1_ns",
+          "trace_id", "span_id", "parent_span_id")
+CAPTURE_MAX = 1 << 19       # records kept per session; the rest count
+
+_probe = None               # jax.profiler.TraceAnnotation.is_enabled
+_cap_on = False
+_cap_records: list[tuple] = []
+_cap_dropped = 0
+# thread id -> [cpu first, clock first, cpu last, clock last] (ns): the
+# thread's CPU time beside the clock at its first section and (stamped
+# at most once a millisecond: the call is a system call) at its last
+_cap_threads: dict[int, list[int]] = {}
+_CPU_STAMP_NS = 1_000_000
+
+
+def _find_probe():
+    jax = sys.modules.get("jax")
+    if jax is None:                       # never imported for our sake
+        return None
+    try:
+        return jax.profiler.TraceAnnotation.is_enabled
+    except AttributeError:                # jax still importing, or old
+        return None
+
+
+def capturing() -> bool:
+    """Is a ``jax.profiler`` session running in this process? Looked
+    up where the work happens; a new session starts a new capture."""
+    global _probe, _cap_on, _cap_records, _cap_dropped, _cap_threads
+    probe = _probe
+    if probe is None:
+        probe = _probe = _find_probe()
+        if probe is None:
+            return False
+    on = probe()
+    if on is not _cap_on:
+        if on:
+            _cap_records, _cap_dropped, _cap_threads = [], 0, {}
+        _cap_on = on
+    return on
+
+
+def _capture(kind, name, service, thread, t0_ns, t1_ns, trace_id,
+             span_id, parent_span_id, tags) -> None:
+    global _cap_dropped
+    if len(_cap_records) >= CAPTURE_MAX:
+        _cap_dropped += 1
+        return
+    rec = (kind, name, service, thread, t0_ns, t1_ns, trace_id, span_id,
+           parent_span_id)
+    if tags:
+        rec += tuple(_flatten(tags.items()))
+    _cap_records.append(rec)
+    if thread:                            # a section, on its own thread
+        ent = _cap_threads.get(thread)
+        if ent is None:
+            cpu, now = time.thread_time_ns(), _clock()
+            _cap_threads[thread] = [cpu, now, cpu, now]
+        elif t1_ns - ent[3] > _CPU_STAMP_NS:
+            ent[2], ent[3] = time.thread_time_ns(), _clock()
+
+
+def captured() -> list[tuple]:
+    """The records of the newest profiler session, each a tuple in the
+    order of :data:`FIELDS` with the tags after them. Kept until the
+    next session starts."""
+    return _cap_records
+
+
+def record_dict(rec: tuple) -> dict:
+    """One captured record as a dict, ``tags`` among its keys."""
+    n = len(FIELDS)
+    d = dict(zip(FIELDS, rec))
+    d["tags"] = dict(zip(rec[n::2], rec[n + 1::2]))
+    return d
+
+
+def capture_info() -> dict:
+    """``dropped`` (records beyond :data:`CAPTURE_MAX`) and, for every
+    thread that recorded a section, its ``time.thread_time_ns()`` and
+    ``time.perf_counter_ns()`` at the first and at the last one (the
+    last to within a millisecond)."""
+    return {"dropped": _cap_dropped, "records": len(_cap_records),
+            "threads": {th: {"cpu_ns": (e[0], e[2]),
+                             "clock_ns": (e[1], e[3])}
+                        for th, e in _cap_threads.items()}}
 
 
 class Span:
     """One timed phase inside one daemon (ref: a jspan/blkin trace
-    point pair). ``trace_id == 0`` marks a local-only root still
-    awaiting the tail-retention decision."""
+    point pair), an interval or a section (``kind``). ``trace_id == 0``
+    marks a local-only root still awaiting the tail-retention decision,
+    or a section captured for an op nobody sampled."""
 
     __slots__ = ("tracer", "trace_id", "span_id", "parent_span_id",
-                 "name", "service", "start", "_t0", "duration", "tags",
-                 "finished")
+                 "name", "service", "kind", "thread", "t0_ns", "t1_ns",
+                 "duration", "tags", "finished")
 
     def __init__(self, tracer: "Tracer | None", name: str,
                  trace_id: int, parent_span_id: int = 0,
-                 tags: dict | None = None):
+                 tags: dict | None = None, kind: str = INTERVAL,
+                 service: str = ""):
         self.tracer = tracer
         self.trace_id = trace_id
-        self.span_id = new_trace_id()
+        self.span_id = next(_span_ids) & _ID_MASK
         self.parent_span_id = parent_span_id
         self.name = name
-        self.service = tracer.service if tracer is not None else ""
-        self.start = time.time()          # wall: cross-daemon alignment
-        self._t0 = time.monotonic()       # monotonic: durations
+        self.service = tracer.service if tracer is not None else service
+        self.kind = kind
+        self.thread = threading.get_ident() if kind == SECTION else 0
+        self.t1_ns: int | None = None
         self.duration: float | None = None
         self.tags: dict = dict(tags) if tags else {}
         self.finished = False
+        self.t0_ns = _clock()
+
+    @property
+    def start(self) -> float:
+        """Wall seconds of ``t0_ns`` (cross-daemon alignment)."""
+        return wall_of(self.t0_ns)
 
     def tag(self, key: str, value: Any) -> "Span":
         self.tags[key] = value
         return self
 
     def child(self, name: str, tags: dict | None = None) -> "Span":
-        """A child span in the SAME daemon (same trace, linked)."""
+        """A child interval in the SAME daemon (same trace, linked)."""
         return Span(self.tracer, name, self.trace_id,
                     parent_span_id=self.span_id, tags=tags)
 
@@ -82,20 +249,32 @@ class Span:
         phases and the caller attaches them post-hoc — a live child
         span would double-count the enclosing wall)."""
         s = self.child(name, tags=tags)
-        s.finished = True
-        s.duration = max(float(duration), 0.0)
         # start back-dated so the child nests inside this span's wall
-        s.start = self.start
-        if s.tracer is not None:
-            s.tracer.record(s)
+        s.close_at(self.t0_ns,
+                   self.t0_ns + int(max(float(duration), 0.0) * 1e9))
+
+    def close_at(self, t0_ns: int, t1_ns: int) -> None:
+        """Finish with both stamps given (a phase measured before its
+        span could be made: the context was not known yet)."""
+        self.finished = True
+        self.t0_ns, self.t1_ns = t0_ns, t1_ns
+        self.duration = (t1_ns - t0_ns) / 1e9
+        _finished(self)
 
     def finish(self) -> None:
         if self.finished:
             return
         self.finished = True
-        self.duration = time.monotonic() - self._t0
-        if self.tracer is not None:
-            self.tracer.record(self)
+        self.t1_ns = t1 = _clock()
+        self.duration = (t1 - self.t0_ns) / 1e9
+        _finished(self)
+
+    # a section is used as ``with tracing.section(...) as s:``
+    def __enter__(self) -> "Span":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.finish()
 
     def dump(self) -> dict:
         return {
@@ -104,12 +283,112 @@ class Span:
             "parent_span_id": self.parent_span_id,
             "name": self.name,
             "service": self.service,
+            "kind": self.kind,
             "start": self.start,
             "duration": round(
                 self.duration if self.duration is not None
-                else time.monotonic() - self._t0, 9),
+                else (_clock() - self.t0_ns) / 1e9, 9),
             "tags": self.tags,
         }
+
+
+def _finished(span: Span) -> None:
+    if capturing():
+        _capture(span.kind, span.name, span.service, span.thread,
+                 span.t0_ns, span.t1_ns, span.trace_id, span.span_id,
+                 span.parent_span_id, span.tags)
+    tid = span.trace_id
+    if span.tracer is not None and (
+            (tid and not tid & CAPTURE_ONLY) or
+            not (span.parent_span_id or span.thread)):
+        # the operator's op, or a root that tail retention may keep
+        span.tracer.record(span)
+
+
+class _Off:
+    """What :func:`section` returns when nobody is looking: one shared
+    object, nothing allocated, every method a no-op."""
+
+    __slots__ = ()
+    trace_id = span_id = 0
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return None
+
+    def __bool__(self):
+        return False
+
+    def tag(self, key, value):
+        return self
+
+    def finish(self):
+        return None
+
+
+_OFF = _Off()
+
+
+def _context(ctx, tracer):
+    """(trace id, parent span id, tracer) of ``ctx``: a Span (the new
+    span becomes its child), a wire Message (it becomes a child of the
+    sender's span) or None."""
+    if ctx is None:
+        return 0, 0, tracer
+    if isinstance(ctx, Span):
+        return ctx.trace_id, ctx.span_id, \
+            tracer if tracer is not None else ctx.tracer
+    return ctx.trace_id, ctx.parent_span_id, tracer
+
+
+def wanted(ctx=None, tracer=None) -> bool:
+    """Would a section under ``ctx`` be recorded anywhere? True when
+    the op was sampled for the operator and there is a tracer to keep
+    it, or while a profiler session runs. This is the off path: the
+    one check, nothing allocated."""
+    tid = ctx.trace_id if ctx is not None else 0
+    return bool(tid and not tid & CAPTURE_ONLY
+                and (tracer is not None or isinstance(ctx, Span))) \
+        or capturing()
+
+
+def section(name: str, ctx=None, tracer: "Tracer | None" = None,
+            service: str = ""):
+    """Open a section: ``with tracing.section("msg.encode", msg,
+    tracer) as s: ...; s.tag("bytes", n)``. No ``await`` inside (close
+    it with ``s.finish()`` before a branch that awaits). ``ctx`` is the
+    parent Span, the wire Message that carries the context, or None;
+    ``service`` names the recorder where there is no tracer."""
+    # wanted(), spelt out: this is the call every site makes
+    tid = ctx.trace_id if ctx is not None else 0
+    if not ((tid and not tid & CAPTURE_ONLY
+             and (tracer is not None or isinstance(ctx, Span)))
+            or capturing()):
+        return _OFF
+    tid, pid, tracer = _context(ctx, tracer)
+    return Span(tracer, name, tid, pid, None, SECTION, service)
+
+
+def emit_section(name: str, t0_ns: int, t1_ns: int, ctx=None,
+                 tracer: "Tracer | None" = None, service: str = "",
+                 tags: dict | None = None) -> None:
+    """A section measured before its context was known (a frame is
+    checked and decoded before the message says whose op it is). The
+    caller asks :func:`wanted` first."""
+    tid, pid, tracer = _context(ctx, tracer)
+    if not tid or tid & CAPTURE_ONLY or tracer is None:
+        # nobody's buffers want it, only the capture: no Span is made
+        # (a session sees four of these for every message)
+        if capturing():
+            _capture(SECTION, name,
+                     tracer.service if tracer is not None else service,
+                     threading.get_ident(), t0_ns, t1_ns, tid,
+                     next(_span_ids) & _ID_MASK, pid, tags)
+        return
+    s = Span(tracer, name, tid, pid, tags, SECTION, service)
+    s.close_at(t0_ns, t1_ns)
 
 
 class Tracer:
@@ -155,10 +434,15 @@ class Tracer:
         """Root span for a NEW logical op. Head-sampled roots get a
         propagating trace id; unsampled roots are local-only (tail
         retention candidates); None when tracing is fully off
-        (sampling 0 AND tail tracking disabled)."""
+        (sampling 0 AND tail tracking disabled). While a profiler
+        session runs every root is followed, under a capture-only
+        id."""
         rate = self.sampling_rate()
         if rate > 0.0 and random.random() < rate:
             return Span(self, name, new_trace_id(), tags=tags)
+        if capturing():
+            return Span(self, name, new_trace_id() | CAPTURE_ONLY,
+                        tags=tags)
         if self.slow_keep_s() > 0.0:
             return Span(self, name, 0, tags=tags)
         return None
@@ -176,13 +460,17 @@ class Tracer:
 
     # -- recording ---------------------------------------------------------
     def record(self, span: Span) -> None:
+        tid = span.trace_id
+        local = tid == 0 or bool(tid & CAPTURE_ONLY)
+        if local and (span.parent_span_id or span.kind == SECTION):
+            return                        # nobody sampled it: capture's
         slow = span.duration is not None and \
             0.0 < self.slow_keep_s() <= span.duration
-        if span.trace_id == 0:
+        if local:
             if not slow:
                 return                    # unsampled and fast: drop
             # tail retention: promote the local-only root so the mgr
-            # can index it (children were never created — by design)
+            # can index it (children were never kept — by design)
             span.trace_id = new_trace_id()
             span.tags["tail_sampled"] = True
         if slow:
@@ -257,6 +545,7 @@ class TraceIndex:
                 "parent_span_id": int(span.get("parent_span_id", 0)),
                 "name": str(span.get("name", "?")),
                 "service": str(span.get("service", "?")),
+                "kind": str(span.get("kind", INTERVAL)),
                 "start": float(span.get("start", 0.0)),
                 "duration": float(span.get("duration", 0.0)),
                 "tags": tags if isinstance(tags, dict) else {},
@@ -343,6 +632,7 @@ class TraceIndex:
                 "span_id": sid,
                 "name": s.get("name"),
                 "service": s.get("service"),
+                "kind": s.get("kind", INTERVAL),
                 "offset": round(s["start"] - t0, 6),
                 "duration": round(s.get("duration", 0.0), 6),
                 "tags": s.get("tags", {}),
@@ -351,7 +641,8 @@ class TraceIndex:
                 "children": [node(c, depth + 1) for c in kids]
                 if depth < self.MAX_TREE_DEPTH else ([{
                     "span_id": 0, "name": f"({len(kids)} elided)",
-                    "service": "", "offset": 0.0, "duration": 0.0,
+                    "service": "", "kind": INTERVAL, "offset": 0.0,
+                    "duration": 0.0,
                     "tags": {}, "children": [],
                 }] if kids else []),
             }

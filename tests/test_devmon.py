@@ -690,3 +690,61 @@ def test_device_storm_cluster():
         finally:
             await c.stop()
     run(go())
+
+
+# -- the compile listener: compiles, not first calls (round 26) --------------
+
+def test_xla_compile_listener_counts_compiles_not_first_calls():
+    """Two ``jax.jit`` objects of one function and shape are two first
+    calls by devmon's book; what the backend compiled is what the
+    ``jax.monitoring`` listener saw, filed under the ``jit_call`` in
+    progress, and the ``jit_compile`` span carries its seconds."""
+    import jax
+    import jax.numpy as jnp
+    from ceph_tpu.utils.devmon import compile_events
+
+    dm = devmon()
+    tracer = Tracer("devmon-xla", {"trace_slow_keep_s": 0.0})
+    old_tracer = dm.tracer
+    dm.attach_tracer(tracer)
+    try:
+        def f(x):
+            return (x * 3 + 1).sum()
+
+        x = jnp.arange(1237, dtype=jnp.int32)       # a shape of its own
+        jax.block_until_ready(x)
+        a, b = jax.jit(f), jax.jit(f)
+        before = dm.perf.dump()
+        n_events = len(compile_events())
+        for fn in (a, b):
+            assert int(dm.jit_call("ec_probe", (id(fn), x.shape),
+                                   fn, x)) == int(f(np.arange(1237)))
+        # warm by both books
+        dm.jit_call("ec_probe", (id(a), x.shape), a, x)
+        after = dm.perf.dump()
+        assert after["jit_compiles"] - before["jit_compiles"] == 2
+        mine = [ev for ev in compile_events()[n_events:]
+                if ev[4] == "ec_probe"]
+        true_count = len(mine)
+        assert 1 <= true_count <= 2
+        assert after["xla_compiles"] - before["xla_compiles"] \
+            >= true_count
+        for at_ns, fun, seconds, cached, program in mine:
+            assert "f" in fun and seconds > 0.0 and not cached
+            assert at_ns <= time.perf_counter_ns()
+        assert after["xla_compile_seconds"] > \
+            before["xla_compile_seconds"]
+        # one jit_compile span per call that compiled, true duration
+        spans = [s for s in tracer.dump()["spans"]
+                 if s["name"] == "jit_compile"]
+        assert len(spans) == true_count
+        assert spans[0]["tags"]["fn"] == "ec_probe"
+        assert spans[0]["tags"]["cached"] is False
+        assert spans[0]["duration"] == pytest.approx(mine[0][2],
+                                                     abs=1e-6)
+        # a compile under no jit_call is counted, under "other"
+        n_events = len(compile_events())
+        jax.block_until_ready(jax.jit(lambda v: v - 7)(x))
+        assert [ev[4] for ev in compile_events()[n_events:]] == ["other"]
+    finally:
+        dm.attach_tracer(old_tracer)

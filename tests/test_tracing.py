@@ -25,6 +25,7 @@ import pytest
 from ceph_tpu.cluster.vstart import Cluster
 from ceph_tpu.mgr.modules import TracingModule
 from ceph_tpu.sim import faults as F
+from ceph_tpu.utils import tracing
 from ceph_tpu.utils.tracing import Tracer, TraceIndex
 
 
@@ -296,8 +297,11 @@ def test_slow_op_retained_below_sampling_rate():
             deadline = asyncio.get_event_loop().time() + 10
             tail = []
             while not tail:
+                # (a cold process's first crush_sweep compiles and is
+                # tail-kept too: it is not the op this test delays)
                 tail = [s for _, s in lead.trace_spans
-                        if s.get("tags", {}).get("tail_sampled")]
+                        if s.get("tags", {}).get("tail_sampled")
+                        and s["name"] == "client_op"]
                 if not tail:
                     assert asyncio.get_event_loop().time() < \
                         deadline, list(lead.trace_spans)
@@ -416,3 +420,296 @@ def test_op_tracker_monotonic_and_config_knobs():
     for i in range(5):
         t2.create(f"op{i}").finish()
     assert len(t2.history) == 3
+
+
+# -- one clock, two kinds, capture (round 26) -------------------------------
+
+def test_span_stamps_share_one_clock_and_dump_keeps_its_shape():
+    t = Tracer("client", {"trace_sampling_rate": 1.0})
+    before = time.perf_counter_ns()
+    wall = time.time()
+    root = t.start_root("client_op", tags={"oid": "o"})
+    time.sleep(0.01)
+    root.finish()
+    after = time.perf_counter_ns()
+    # both ends are perf_counter_ns stamps, the duration their difference
+    assert before <= root.t0_ns < root.t1_ns <= after
+    assert root.duration == (root.t1_ns - root.t0_ns) / 1e9 >= 0.01
+    # the wall start is derived from them through one offset
+    assert abs(root.start - wall) < 0.05
+    assert root.start == tracing.wall_of(root.t0_ns)
+    d = root.dump()
+    assert set(d) == {"trace_id", "span_id", "parent_span_id", "name",
+                      "service", "kind", "start", "duration", "tags"}
+    assert d["kind"] == "interval" and d["service"] == "client"
+    assert isinstance(d["start"], float) and d["duration"] >= 0.01
+    # a back-dated sub-phase nests inside its parent on the same clock
+    root.annotate("kv_commit", 0.004)
+    kv = t.dump()["spans"][-1]
+    assert kv["name"] == "kv_commit" and kv["start"] == d["start"]
+    assert kv["duration"] == 0.004
+    # the index keeps the kind and shows it
+    idx = TraceIndex()
+    idx.add(d)
+    assert idx.show(root.trace_id)["tree"][0]["kind"] == "interval"
+
+
+def test_section_nesting_gives_the_outer_its_self_time():
+    t = Tracer("osd.0", {"trace_sampling_rate": 1.0})
+    root = t.start_root("client_op")
+    with tracing.section("osd.ec_fanout", root) as outer:
+        time.sleep(0.004)
+        with tracing.section("objectstore_commit", root) as inner:
+            inner.tag("osd", 0)
+            time.sleep(0.006)
+        time.sleep(0.002)
+    assert outer.kind == inner.kind == "section"
+    assert outer.thread == inner.thread != 0
+    assert outer.trace_id == root.trace_id
+    assert outer.parent_span_id == inner.parent_span_id == root.span_id
+    # the inner one lies inside the outer one on the one clock
+    assert outer.t0_ns <= inner.t0_ns < inner.t1_ns <= outer.t1_ns
+    self_s = outer.duration - inner.duration
+    assert 0.006 <= self_s < outer.duration and inner.duration >= 0.006
+    # both reach the operator's buffer, kind shown
+    kinds = {s["name"]: s["kind"] for s in t.dump()["spans"]}
+    assert kinds == {"objectstore_commit": "section",
+                     "osd.ec_fanout": "section"}
+    # nobody looking: one shared object, nothing recorded
+    off = Tracer("osd.1", {})
+    a = tracing.section("msg.send", None, off)
+    b = tracing.section("msg.recv", None, off)
+    assert a is b and not a
+    with a as s:
+        s.tag("bytes", 1).finish()
+    assert off.dump()["spans"] == []
+
+
+def _captured() -> list[dict]:
+    """The capture's records as dicts (tags too)."""
+    return [tracing.record_dict(r) for r in tracing.captured()]
+
+
+def test_capture_follows_the_profiler_session(tmp_path):
+    import jax
+    assert not tracing.capturing()
+    n_before = len(tracing.captured())
+    off = Tracer("client", {"trace_slow_keep_s": 0.0})
+    with tracing.section("client.submit", None, off):
+        pass
+    assert off.start_root("client_op") is None
+    assert len(tracing.captured()) == n_before     # the off path
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        assert tracing.capturing()
+        assert tracing.captured() == []            # a new session
+        root = off.start_root("client_op")         # sampled as if rate 1
+        assert root is not None
+        assert root.trace_id & tracing.CAPTURE_ONLY
+        with tracing.section("client.submit", root) as sec:
+            sec.tag("bytes", 7)
+        with tracing.section("msg.recv", None, None, "osd.3"):
+            pass                                   # nobody's op: kept too
+        root.finish()
+    finally:
+        jax.profiler.stop_trace()
+    assert not tracing.capturing()
+    recs = _captured()
+    assert [(r["kind"], r["name"]) for r in recs] == [
+        ("section", "client.submit"), ("section", "msg.recv"),
+        ("interval", "client_op")]
+    assert recs[0]["tags"] == {"bytes": 7}
+    assert recs[0]["parent_span_id"] == recs[2]["span_id"]
+    assert recs[1]["service"] == "osd.3" and recs[1]["trace_id"] == 0
+    assert recs[2]["t0_ns"] <= recs[0]["t0_ns"] <= recs[0]["t1_ns"] \
+        <= recs[2]["t1_ns"]
+    info = tracing.capture_info()
+    assert info["dropped"] == 0 and info["records"] == 3
+    (th,) = info["threads"].values()
+    assert th["cpu_ns"][0] <= th["cpu_ns"][1]
+    assert th["clock_ns"][0] <= th["clock_ns"][1]
+    # capture-only traces never reach the operator's buffers
+    assert off.dump()["spans"] == [] and off.ship_pending() == 0
+    # after the session the list stays, and nothing more is appended
+    with tracing.section("client.submit", None, off):
+        pass
+    assert len(tracing.captured()) == 3
+
+
+# every span of the served EC path's table (ISSUE 26), old names and new
+EC_WRITE_SPANS = {
+    "client_op", "client.submit", "client.reply", "msg.encode",
+    "msg.send", "msg.recv", "msg.decode", "osd_op", "queue", "execute",
+    "osd.dispatch", "osd.ec_prepare", "osd.ec_fanout", "ec_subop_wait",
+    "ec_sub_write", "ec.agg_wait", "ec.pack", "ec.launch",
+    "ec.device_wait", "objectstore_commit", "osd.reply"}
+EC_READ_SPANS = {
+    "client_op", "client.submit", "client.reply", "msg.encode",
+    "msg.send", "msg.recv", "msg.decode", "osd_op", "queue", "execute",
+    "osd.dispatch", "osd.ec_subread_wait", "osd.ec_sub_read",
+    "osd.ec_assemble", "ec.cache_lookup", "ec.agg_wait", "ec.pack",
+    "ec.launch", "ec.device_wait", "store.read", "osd.reply"}
+
+
+async def _ec_cluster(config, **kw):
+    c = await Cluster(n_mons=1, n_osds=6,
+                      config=dict({"osd_ec_resident_bytes": 8 << 20},
+                                  **config), **kw).start()
+    ret, rs, _ = await c.client.mon_command(
+        {"prefix": "osd erasure-code-profile set", "name": "p32",
+         "profile": ["k=3", "m=2", "plugin=jax",
+                     "technique=reed_sol_van", "stripe_unit=4096"]})
+    assert ret == 0, rs
+    ret, rs, _ = await c.client.mon_command(
+        {"prefix": "osd pool create", "pool": "ec", "pg_num": 4,
+         "pool_type": "erasure", "erasure_code_profile": "p32"})
+    assert ret == 0, rs
+    await c.wait_for_clean(timeout=120)
+    return c, await c.client.open_ioctx("ec")
+
+
+async def _degrade(c, oid: str):
+    """Kill the OSD that holds the object's first data shard; reads of
+    it decode from then on."""
+    ret, _, out = await c.client.mon_command(
+        {"prefix": "osd map", "pool": "ec", "object": oid})
+    assert ret == 0
+    victim = json.loads(out)["acting"][1]     # a data shard, not the
+    await c.kill_osd(victim)                  # primary's own
+    await c.wait_for_osd_down(victim, timeout=60)
+
+
+def test_ec_write_and_degraded_read_capture_every_span(tmp_path):
+    """One EC write_full and one degraded read under a profiler session
+    yield every span of the table, each with its parent in the same
+    trace, and every section inside the client_op root's interval."""
+    import jax
+    payload = bytes(range(256)) * 192            # 48 KiB: 4 stripes
+
+    async def go():
+        c, io = await _ec_cluster({})
+        try:
+            await io.write_full("warm", payload)      # connections up
+            await io.write_full("victim", payload)
+            await _degrade(c, "victim")
+            assert await io.read("victim") == payload  # programs warm
+            for o in c.osds:                     # the next read gathers
+                if o.ec_resident is not None:
+                    o.ec_resident.clear()
+            jax.profiler.start_trace(str(tmp_path))
+            try:
+                await io.write_full("traced", payload)
+                assert await io.read("victim") == payload
+            finally:
+                jax.profiler.stop_trace()
+        finally:
+            await c.stop()
+    run(go())
+    recs = _captured()
+    assert tracing.capture_info()["dropped"] == 0
+    roots = [r for r in recs if r["name"] == "client_op"]
+    assert [r["tags"]["op_class"] for r in roots] == ["write", "read"]
+    for root, want in zip(roots, (EC_WRITE_SPANS, EC_READ_SPANS)):
+        assert root["trace_id"] & tracing.CAPTURE_ONLY
+        mine = [r for r in recs if r["trace_id"] == root["trace_id"]]
+        assert want <= {r["name"] for r in mine}, \
+            want - {r["name"] for r in mine}
+        ids = {r["span_id"] for r in mine}
+        for r in mine:
+            if r is not root:
+                assert r["parent_span_id"] in ids, r
+            if r["kind"] == "section":
+                assert root["t0_ns"] <= r["t0_ns"] <= r["t1_ns"] \
+                    <= root["t1_ns"], (r["name"], root["name"])
+        # what the old names were is what they are
+        kind = {r["name"]: r["kind"] for r in mine}
+        assert kind["queue"] == kind["execute"] == "interval"
+        assert kind["ec.agg_wait"] == "interval"
+        assert kind["msg.send"] == kind["ec.launch"] == "section"
+    wkind = {r["name"]: r["kind"] for r in recs
+             if r["trace_id"] == roots[0]["trace_id"]}
+    assert wkind["objectstore_commit"] == "section"
+    assert wkind["ec_subop_wait"] == wkind["ec_sub_write"] == "interval"
+
+
+def test_ec_spans_reach_trace_show_on_the_operators_path():
+    """No profiler session, trace_sampling_rate 1.0: the same spans
+    reach the mgr's index (``ceph trace show``)."""
+    payload = bytes(range(256)) * 192
+
+    async def go():
+        c, io = await _ec_cluster(
+            {"trace_sampling_rate": 1.0, "mgr_tracing_interval": 0.25},
+            mgr_modules=[TracingModule])
+        try:
+            await io.write_full("warm", payload)
+            await io.write_full("victim", payload)
+            await _degrade(c, "victim")
+            for o in c.osds:
+                if o.ec_resident is not None:
+                    o.ec_resident.clear()
+            assert not tracing.capturing()
+            n_cap = len(tracing.captured())
+            await io.write_full("traced", payload)
+            assert await io.read("victim") == payload
+            assert len(tracing.captured()) == n_cap
+            mod = c.mgr.modules[0]
+            deadline = asyncio.get_event_loop().time() + 30
+            found = {}
+            while len(found) < 2:
+                for row in mod.trace_ls(limit=50):
+                    show = mod.trace_show(row["trace_id"])
+                    if row["root"] != "client_op" or not show["tree"]:
+                        continue
+                    tags = show["tree"][0]["tags"]
+                    flat: list[dict] = []
+                    _flatten(show["tree"][0], flat)
+                    names = {n["name"] for n in flat}
+                    if tags.get("oid") == "traced" and \
+                            EC_WRITE_SPANS <= names:
+                        found["write"] = flat
+                    if tags.get("oid") == "victim" and \
+                            tags.get("op_class") == "read" and \
+                            EC_READ_SPANS <= names:
+                        found["read"] = flat
+                if len(found) < 2:
+                    assert asyncio.get_event_loop().time() < deadline, (
+                        sorted(found), mod.trace_ls(limit=50))
+                    await asyncio.sleep(0.2)
+            kinds = {n["name"]: n["kind"] for n in found["write"]}
+            assert kinds["osd.ec_prepare"] == "section"
+            assert kinds["ec_subop_wait"] == "interval"
+        finally:
+            await c.stop()
+    run(go())
+
+
+def test_ec_pool_feeds_commit_and_apply_latency():
+    """`ceph osd perf` on an EC cluster: every acting OSD's
+    apply_latency counts its sub-writes, the primaries' commit_latency
+    counts the writes."""
+    async def go():
+        c, io = await _ec_cluster({})
+        try:
+            n = 12
+            for i in range(n):
+                await io.write_full(f"obj-{i}", bytes([i]) * 20000)
+            lat = {o.whoami: o.perf.dump() for o in c.osds}
+            acting = set()
+            for o in c.osds:
+                for pg in o.pgs.values():
+                    if getattr(pg, "ec", None) is not None:
+                        acting.update(a for a in pg.acting if a >= 0)
+            assert acting
+            for osd in acting:
+                assert lat[osd]["apply_latency"]["avgcount"] > 0, osd
+                assert lat[osd]["apply_latency"]["sum"] > 0.0
+            commits = sum(d["commit_latency"]["avgcount"]
+                          for d in lat.values())
+            assert commits == n
+            applies = sum(d["apply_latency"]["avgcount"]
+                          for d in lat.values())
+            assert applies == n * 5                     # k + m each
+        finally:
+            await c.stop()
+    run(go())
