@@ -3,7 +3,7 @@
 The `crushtool --test` timing harness scaled to 100M PGs
 (ref: src/crush/CrushTester.cc CrushTester::test with --show-statistics;
 src/tools/crushtool.cc). The sweep is ONE device program per measurement
-(Mapper.sweep: fori_loop over PG blocks + on-device scatter-add), so the
+(Mapper.sweep: a loop over PG blocks + on-device counts), so the
 only host<->device traffic is the final (max_devices,) count readback —
 which is also the execution anchor (see ceph_tpu/utils/timing.py).
 

@@ -19,8 +19,11 @@ sweep runs SPMD with
 - **zero collectives on the hot path**: mapping is per-PG-independent,
   so the ONLY communication in the aggregated sweep is one
   ``(max_devices,)`` ``psum`` of the per-device placement counts at
-  the very end (and ``sharded_map_pgs`` has none at all — its output
-  stays sharded on the batch axis until the caller reads it back).
+  the very end, each shard having counted its own placements as the
+  single-device step does (``mapper._count_placements``, a one-hot
+  matmul: the scatter-add it replaced took nine tenths of a v5e's
+  time); ``sharded_map_pgs`` has none at all — its output stays
+  sharded on the batch axis until the caller reads it back.
 
 Both entry points serve whichever engine the single-device path would
 use — the fused Pallas kernel body (with its masked XLA fallback for
@@ -179,9 +182,12 @@ def sharded_map_pgs(mesh, mapper, ruleno: int, xs,
 def _compiled_sharded_sweep(fn_body, firstn, nd, mesh, block, local_n,
                             result_max):
     """shard_map'd aggregated sweep step: per-shard iota + local
-    scatter-add counts, ONE psum pair at the end — the whole
+    counts through the single-device step's ``_count_placements`` (no
+    scatter: the colliding scatter-add that stood here took 752 of a
+    v5e's 825 ms a sweep), ONE psum pair at the end — the whole
     communication cost of scaling CRUSH."""
     axis = _mesh_axis(mesh)
+    from ceph_tpu.crush.mapper import _count_placements
     from ceph_tpu.crush.types import ITEM_NONE
 
     def local(arrs, start_x, n_total):
@@ -203,7 +209,8 @@ def _compiled_sharded_sweep(fn_body, firstn, nd, mesh, block, local_n,
             w = fn_body(arrs, xs)                # (block, rmax)
             live = (w != ITEM_NONE) & inb[:, None]
             flat = jnp.where(live, w, nd)
-            counts = counts.at[flat.reshape(-1)].add(jnp.int64(1))
+            counts = counts + _count_placements(
+                flat, nd + 1).astype(jnp.int64)
             if firstn:
                 short = (live.sum(axis=1) < result_max) & inb
                 bad = bad + short.sum(dtype=jnp.int64)
@@ -222,7 +229,8 @@ def sharded_sweep(mesh, mapper, ruleno: int, start_x: int, n: int,
     """Aggregated CRUSH sweep of [start_x, start_x + n) with the PG
     range sharded over the mesh — the multi-chip Mapper.sweep.
 
-    Any ``n`` is accepted (tail lanes mask out of the accumulation).
+    Any ``n`` is accepted (tail lanes mask out of the accumulation);
+    the range wraps modulo 2^32 as ``Mapper.sweep``'s does.
     Returns (counts (max_devices,), bad) replicated on every device,
     equal to the single-device sweep's."""
     if getattr(mapper, "_scalar_reason", None):
@@ -244,7 +252,8 @@ def sharded_sweep(mesh, mapper, ruleno: int, start_x: int, n: int,
             "crush_sharded_sweep",
             mapper._jit_key(ruleno, result_max, used_kernel,
                             ("sharded", local_n, block, nd)),
-            fn, mapper.arrays, jnp.uint32(start_x), jnp.int64(n))
+            fn, mapper.arrays, jnp.uint32(start_x % (1 << 32)),
+            jnp.int64(n))
     mapper.last_map_path = \
         mapper.mapping_path(ruleno, result_max) + "+sharded"
     return out

@@ -75,7 +75,7 @@ class CrushTester:
         """Aggregated sweep over [min_x, max_x].
 
         Without keep_mappings this is ONE device program (Mapper.sweep):
-        per-device counts accumulate via on-device scatter-add and only
+        per-device counts accumulate on the device and only
         the (max_devices,) count vector is read back — round 1 shipped
         every (N, rep) mapping block to the host and bincounted there.
 

@@ -116,3 +116,23 @@ def test_crush_kernel_compiles_at_10k_osds(one_chip, variant):
     with jax.enable_x64(True):
         compiled = pm._run_kernel.lower(plan, xs, 3).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_count_placements_compiles_at_kernel_block(one_chip):
+    """The sweep step's histogram at the kernel path's block (2^21
+    lanes x 3, the 10,240-OSD map's 10,241 bins), fed as the kernel
+    hands its result over (``leaves.T``): no scatter, the one-hots made
+    inside the matmul's fusion, and no row-major copy of the (block, 3)
+    ids (that pads 3 to 128 lanes: 1 GiB of temporaries)."""
+    from ceph_tpu.crush.mapper import _count_placements
+
+    def count(leaves):
+        return _count_placements(leaves.T, 10_241)
+
+    leaves = jax.ShapeDtypeStruct((3, 1 << 21), jnp.int32,
+                                  sharding=one_chip)
+    with jax.enable_x64(True):
+        compiled = jax.jit(count).lower(leaves).compile()
+    text = compiled.as_text()
+    assert "convolution(" in text and "scatter(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20

@@ -97,6 +97,28 @@ class TestBitExact:
         assert (np.asarray(c) == np.asarray(c1)).all()
         assert int(b) == int(b1)
 
+    @pytest.mark.parametrize("sharded", [False, True])
+    def test_sweep_range_wraps_at_2_pow_32(self, mesh, hier, sharded):
+        """x is crush_do_rule's 32-bit input: a sweep whose start has
+        run past 2^32 (a benchmark window stepping its start by 2^23 a
+        sweep gets there in 40 s once a sweep takes 85 ms) maps the low
+        word, and one that crosses 2^32 goes on from 0, as the
+        benchmark's reference counts them."""
+        m, rid, mp, want = hier
+
+        def sweep(start, n):
+            c, b = (sharded_sweep(mesh, mp, rid, start, n, 3) if sharded
+                    else mp.sweep(rid, start, n, 3))
+            return np.asarray(c), int(b)
+
+        want_c = np.bincount(want[:757].reshape(-1),
+                             minlength=m.max_devices)
+        c, b = sweep((1 << 32) + (1 << 33), 757)
+        assert (c == want_c).all() and b == 0
+        head, _ = sweep((1 << 32) - 300, 300)
+        c, b = sweep((1 << 32) - 300, 300 + 757)
+        assert (c == head + want_c).all() and b == 0
+
     def test_randomized_sweep(self, mesh, hier, rng):
         """Randomized PG ids (not a contiguous range) through the
         sharded full-mapping path vs the single-device engine."""
