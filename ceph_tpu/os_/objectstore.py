@@ -109,6 +109,11 @@ class Transaction:
     def empty(self) -> bool:
         return not self.ops
 
+    def data_bytes(self) -> int:
+        """Payload bytes its writes carry (attrs, omap and the
+        encoding's own bytes left out)."""
+        return sum(len(op[4]) for op in self.ops if op[0] == OP_WRITE)
+
     def append(self, other: "Transaction") -> "Transaction":
         self.ops.extend(other.ops)
         return self

@@ -184,6 +184,10 @@ class OSD(Dispatcher):
             .add_time_avg("apply_latency",
                           "replica-side objectstore txn apply "
                           "seconds (time-avg)")
+            .add_u64_counter("rep_ops",
+                             "replicated writes fanned out as primary")
+            .add_u64_counter("rep_fanout_bytes",
+                             "payload bytes sent to replicas")
             .create_perf_counters())
         # daemon -> mgr report session (round 12, ref: MgrClient):
         # the mgrmap subscription finds the active mgr; the reporter
@@ -1251,16 +1255,20 @@ class OSD(Dispatcher):
                     if addr is None:
                         continue
                     self._hb_last_rx.setdefault(o, now)
+                    # stamped when THIS ping goes out, not when the
+                    # round began: the sends before it (and whatever
+                    # the loop did between them) are not this peer's
+                    sent = asyncio.get_event_loop().time()
                     try:
                         await asyncio.wait_for(
                             self.hb_msgr.send_message(MOSDPing(
                                 op=PING, from_osd=self.whoami,
                                 epoch=self.osdmap.epoch,
-                                stamp=now), addr, f"osd.{o}"),
+                                stamp=sent), addr, f"osd.{o}"),
                             timeout=1.0)
                         # only the OLDEST outstanding ping is kept: its
                         # age is the peer's unanswered-for window
-                        self._hb_ping_pending.setdefault(o, now)
+                        self._hb_ping_pending.setdefault(o, sent)
                     except Exception:
                         pass
                     if now - self._hb_last_rx[o] > self.hb_grace and \

@@ -2018,6 +2018,10 @@ class PG:
                 below = [x <= lb for x in txn_oids]
                 if any(below) and not all(below):
                     return -11, False, None             # -EAGAIN
+        op_span = self._active_span
+        # the prepare section: log entries, the transaction's blob and
+        # one MOSDRepOp per replica (the osd.ec_prepare analog)
+        sec = tracing.section("osd.rep_prepare", op_span, self.osd.tracer)
         self.last_user_version += 1
         version = eversion(self.epoch, self.last_user_version)
         entry = self.pg_log.add(
@@ -2047,7 +2051,13 @@ class PG:
             waiter = asyncio.get_event_loop().create_future()
             self._repop_waiters[tid] = [set(replicas), waiter, reqid,
                                         False]
-        op_span = self._active_span
+        log_blob = entry.encode()
+        extra_blobs = [e.encode() for e in extra_entries]
+        repops = [(o, MOSDRepOp(
+            tid=tid, epoch=self.epoch, pgid=self.cid, txn=txn_blob,
+            log_entry=log_blob, extra_log=extra_blobs))
+            for o in replicas]
+        sec.tag("replicas", len(replicas)).finish()
         store_span = tracing.section(
             "objectstore_commit", op_span,
             self.osd.tracer).tag("osd", self.osd.whoami)
@@ -2065,17 +2075,26 @@ class PG:
             # time as a reported time-avg (ref: os_commit_latency)
             self.osd.perf.avg_add("commit_latency",
                                   _time.monotonic() - _t0)
+        # from the fan-out to the last replica's commit reply (the
+        # ec_subop_wait analog); each replica's apply is its child
         repop_span = op_span.child(
-            "repop_wait",
+            "rep_subop_wait",
             tags={"replicas": sorted(replicas)}) \
             if op_span and replicas else None
         send_failed = False
-        for o in replicas:
-            rep = MOSDRepOp(
-                tid=tid, epoch=self.epoch, pgid=self.cid,
-                txn=txn_blob, log_entry=entry.encode(),
-                extra_log=[e.encode() for e in extra_entries])
-            rep.set_trace(repop_span)
+        if replicas:
+            # what the OSD does to fan out before the first send; the
+            # sends hold awaits, so their time is the messenger's own
+            # sections (msg.encode, msg.send) under each MOSDRepOp
+            with tracing.section("osd.rep_fanout", op_span,
+                                 self.osd.tracer) as sec:
+                sec.tag("replicas", len(replicas))
+                for _o, rep in repops:
+                    rep.set_trace(repop_span)
+                self.osd.perf.inc("rep_ops")
+                self.osd.perf.inc("rep_fanout_bytes",
+                                  t.data_bytes() * len(replicas))
+        for o, rep in repops:
             try:
                 await self.osd.send_osd(o, rep)
             except (ConnectionError, OSError, asyncio.TimeoutError,
@@ -2143,6 +2162,27 @@ class PG:
         span = self.osd.tracer.from_msg(
             "repop_apply", m, tags={"osd": self.osd.whoami,
                                     "pgid": self.cid})
+        # osd.rep_apply: the replica's own work (decoding the entry and
+        # the transaction, the log) around its store commit, which is
+        # the section inside it
+        with tracing.section("osd.rep_apply", span or m, self.osd.tracer):
+            if not self._apply_rep_op(m, span):
+                return
+
+        async def _ack():
+            try:
+                # reply on the incoming connection: the replica may not
+                # have seen the map naming the primary yet
+                await m.conn.send_message(MOSDRepOpReply(
+                    tid=m.tid, result=0, pgid=self.cid,
+                    from_osd=self.osd.whoami))
+            except Exception:
+                pass      # primary's repop timeout covers the loss
+        asyncio.ensure_future(_ack())
+
+    def _apply_rep_op(self, m: MOSDRepOp, span) -> bool:
+        """Commit the replica's transaction and append its log entries;
+        False where the store refused it (no ack is sent then)."""
         entry = LogEntry.decode(m.log_entry)
         t = Transaction.decode(m.txn)
         store_span = tracing.section(
@@ -2156,7 +2196,7 @@ class PG:
             log.error(f"pg {self.pgid} repop apply failed: {e}")
             if span is not None:
                 span.tag("error", str(e)).finish()
-            return
+            return False
         finally:
             _finish_store_span(store_span, self.osd.store)
             # the `ceph osd perf` apply leg (ref: os_apply_latency)
@@ -2173,17 +2213,7 @@ class PG:
         self.pg_log.trim(keep=self._trim_keep())
         self.last_user_version = max(self.last_user_version,
                                      entry.version.v)
-
-        async def _ack():
-            try:
-                # reply on the incoming connection: the replica may not
-                # have seen the map naming the primary yet
-                await m.conn.send_message(MOSDRepOpReply(
-                    tid=m.tid, result=0, pgid=self.cid,
-                    from_osd=self.osd.whoami))
-            except Exception:
-                pass      # primary's repop timeout covers the loss
-        asyncio.ensure_future(_ack())
+        return True
 
     def handle_rep_reply(self, m: MOSDRepOpReply) -> None:
         ent = self._repop_waiters.get(m.tid)

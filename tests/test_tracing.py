@@ -203,10 +203,10 @@ def test_replicated_write_trace_reassembly(tmp_path):
             (local_commit,) = _find(execute["children"],
                                     "objectstore_commit")
             assert local_commit["service"] == primary_svc
-            (repop_wait,) = _find(execute["children"], "repop_wait")
+            (rep_subop_wait,) = _find(execute["children"], "rep_subop_wait")
             # >= 2 replica apply spans from DISTINCT non-primary osds,
             # each with its own objectstore commit
-            applies = _find(repop_wait["children"], "repop_apply")
+            applies = _find(rep_subop_wait["children"], "repop_apply")
             svcs = {a["service"] for a in applies}
             assert len(applies) >= 2 and len(svcs) >= 2, applies
             assert primary_svc not in svcs
@@ -226,7 +226,7 @@ def test_replicated_write_trace_reassembly(tmp_path):
                 "client latency unaccounted for: "
                 f"{observed} vs phases {phase_sum}")
             for a in applies:
-                assert a["duration"] <= repop_wait["duration"] + 0.010
+                assert a["duration"] <= rep_subop_wait["duration"] + 0.010
             assert trace["phases"]["objectstore_commit"] >= 0.0
 
             # -- `ceph trace ls/show` (the mon-side CLI view) ---------
