@@ -20,9 +20,10 @@ The decomposition (all facts pinned by tests/test_ec_agg.py):
   the MXU right next to the encode matmul — the fused pass emits a
   uint32 row-CRC per shard row of the batch (data AND parity rows).
 - Rows concatenate through the fixed 32x32 "append C zero bytes"
-  operator ``M_C``: ``raw(A || B) = M_C(raw(A)) ^ raw(B)``. The
-  per-shard fold over a write's ``count`` rows is O(count) 32-bit host
-  ops on the device-produced row CRCs (vectorized across shards) — the
+  operator ``M_C``: ``raw(A || B) = M_C(raw(A)) ^ raw(B)``, and
+  ``M_2C = M_C o M_C``. The fold over a write's ``count`` rows is
+  pairwise: log2(count) levels of a few 32-bit host ops on the
+  device-produced row CRCs, vectorized across rows and shards — the
   O(bytes) work stays on device, in the encode program.
 
 Everything here is host-side plan construction (numpy + zlib), cached
@@ -136,14 +137,28 @@ def device_row_crcs(rows: np.ndarray) -> np.ndarray:
     return np.asarray(out)[:R]
 
 
-@functools.lru_cache(maxsize=8)
-def _shift_columns(chunk_size: int) -> np.ndarray:
-    """(32,) uint32-valued columns of M_C, the 'append C zero bytes'
-    operator on raw CRC states: column j = M_C applied to basis 2^j."""
-    cols = np.array([1 << j for j in range(32)], dtype=np.uint64)
-    for _ in range(int(chunk_size)):
-        cols = _zero_byte_update(cols)
-    return cols
+def _apply_cols(cols: np.ndarray, state) -> np.ndarray:
+    """Apply a 32x32 GF(2) operator (given as its 32 basis-column
+    images) to every state of an array (or to one int)."""
+    s = np.asarray(state, dtype=np.uint64)
+    j = np.arange(32, dtype=np.uint64)
+    bits = ((s[..., None] >> j) & np.uint64(1)).astype(bool)
+    return np.bitwise_xor.reduce(
+        np.where(bits, cols, np.uint64(0)), axis=-1)
+
+
+@functools.lru_cache(maxsize=128)
+def _shift_columns(length: int) -> np.ndarray:
+    """(32,) uint32-valued columns of M_length, the 'append ``length``
+    zero bytes' operator on raw CRC states: column j = M_length applied
+    to basis 2^j. Square-and-multiply (ref: crc32_combine): an even
+    length is the square of its half, an odd one a zero byte more."""
+    if length == 0:
+        return np.array([1 << j for j in range(32)], dtype=np.uint64)
+    if length % 2:
+        return _zero_byte_update(_shift_columns(length - 1))
+    half = _shift_columns(length // 2)
+    return _apply_cols(half, half)
 
 
 def combine_row_crcs(row_crcs: np.ndarray, chunk_size: int) -> np.ndarray:
@@ -151,49 +166,37 @@ def combine_row_crcs(row_crcs: np.ndarray, chunk_size: int) -> np.ndarray:
 
     ``row_crcs``: (..., count) uint32 — count C-byte rows per shard, in
     concatenation order. Returns (...) uint64-valued raw CRC of each
-    shard's count*C bytes. O(count) vectorized 32-bit host ops — the
-    O(bytes) part already ran on device."""
+    shard's count*C bytes. A pairwise fold, log2(count) levels of a few
+    vectorized 32-bit host ops each (``raw(A || B) = M_len(B)(raw(A)) ^
+    raw(B)``, neighbours of equal length at every level) — the O(bytes)
+    part already ran on device."""
     rc = np.asarray(row_crcs, dtype=np.uint64)
-    cols = _shift_columns(chunk_size)
-    state = np.zeros(rc.shape[:-1], dtype=np.uint64)
-    j = np.arange(32, dtype=np.uint64)
-    for i in range(rc.shape[-1]):
-        bits = ((state[..., None] >> j) & np.uint64(1)).astype(bool)
-        state = np.bitwise_xor.reduce(
-            np.where(bits, cols, np.uint64(0)), axis=-1) ^ rc[..., i]
-    return state
-
-
-def _apply_cols(cols: np.ndarray, state: int) -> int:
-    """Apply a 32x32 GF(2) operator (given as its 32 basis-column
-    images) to one state."""
-    j = np.arange(32, dtype=np.uint64)
-    bits = ((np.uint64(state) >> j) & np.uint64(1)).astype(bool)
-    return int(np.bitwise_xor.reduce(
-        np.where(bits, cols, np.uint64(0))))
+    count = rc.shape[-1]
+    if count == 0:
+        return np.zeros(rc.shape[:-1], dtype=np.uint64)
+    lead = (1 << (count - 1).bit_length()) - count
+    if lead:
+        # leading zero rows leave a raw CRC as it is (state 0 stays 0)
+        rc = np.concatenate(
+            [np.zeros(rc.shape[:-1] + (lead,), dtype=np.uint64), rc],
+            axis=-1)
+    block = int(chunk_size)         # bytes a state stands for
+    while rc.shape[-1] > 1:
+        rc = _apply_cols(_shift_columns(block), rc[..., 0::2]) \
+            ^ rc[..., 1::2]
+        block *= 2
+    return rc[..., 0]
 
 
 @functools.lru_cache(maxsize=64)
 def _zero_crc(length: int) -> int:
     """zlib.crc32 of `length` zero bytes — the affine (init/final-xor)
-    part of the checksum, a function of the length alone. Computed in
-    O(log length) by square-and-multiply over the append-one-zero-byte
-    operator (ref: crc32_combine) — materializing a length-sized zero
+    part of the checksum, a function of the length alone. O(log length)
+    through :func:`_shift_columns` — materializing a length-sized zero
     buffer here would re-introduce the O(bytes) host work the fused
     path exists to offload."""
-    state = _M32            # the pre-inverted init register
-    cols = _zero_byte_update(
-        np.array([1 << j for j in range(32)], dtype=np.uint64))
-    n = int(length)
-    while n:
-        if n & 1:
-            state = _apply_cols(cols, state)
-        n >>= 1
-        if n:
-            # square the operator: image of basis j under cols∘cols
-            cols = np.array([_apply_cols(cols, int(c)) for c in cols],
-                            dtype=np.uint64)
-    return state ^ _M32
+    # the pre-inverted init register, run through `length` zero bytes
+    return int(_apply_cols(_shift_columns(int(length)), _M32)) ^ _M32
 
 
 def shard_crc32(row_crcs: np.ndarray, chunk_size: int) -> np.ndarray:
@@ -206,19 +209,31 @@ def shard_crc32(row_crcs: np.ndarray, chunk_size: int) -> np.ndarray:
     return lin ^ np.uint64(_zero_crc(rc.shape[-1] * int(chunk_size)))
 
 
-def hcrc_attr(shard_bytes: bytes, row_crcs=None,
-              chunk_size: int | None = None) -> bytes:
-    """The ONE producer of the ``_hcrc`` shard attribute (4 bytes LE).
+def hcrc_attrs(shards, row_crcs=None,
+               chunk_size: int | None = None) -> list[bytes]:
+    """The ONE producer of the ``_hcrc`` shard attribute (4 bytes LE),
+    for all of a write's shards at once.
 
     Consumes the fused kernel's per-row CRC output when the caller has
-    one (``row_crcs``: (count,) uint32 for this shard, ``chunk_size``
-    required), and falls back to host-side ``zlib.crc32`` otherwise —
-    both producers are pinned byte-for-byte equal by test."""
+    one (``row_crcs``: (len(shards), count) uint32, a row per shard,
+    ``chunk_size`` required: one fold for all of them), and falls back
+    to host-side ``zlib.crc32`` of each shard's bytes otherwise — both
+    producers are pinned byte-for-byte equal by test."""
     if row_crcs is not None:
         if not chunk_size:
             raise ValueError(
                 "row_crcs needs the chunk size to combine")
-        v = int(shard_crc32(np.asarray(row_crcs), chunk_size))
+        vals = shard_crc32(np.asarray(row_crcs), chunk_size)
     else:
-        v = zlib.crc32(shard_bytes)
-    return int(v).to_bytes(4, "little")
+        vals = [zlib.crc32(s) for s in shards]
+    return [int(v).to_bytes(4, "little") for v in vals]
+
+
+def hcrc_attr(shard_bytes: bytes, row_crcs=None,
+              chunk_size: int | None = None) -> bytes:
+    """One shard's ``_hcrc``: :func:`hcrc_attrs` of a single shard
+    (``row_crcs``: (count,) uint32 for this shard)."""
+    return hcrc_attrs(
+        [shard_bytes],
+        None if row_crcs is None else np.asarray(row_crcs)[None, :],
+        chunk_size)[0]

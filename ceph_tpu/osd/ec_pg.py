@@ -592,6 +592,8 @@ class ECPG(PG):
         # fan the per-shard sub-ops out (ref: ECBackend sub writes)
         tid = self.osd.next_tid()
         entry_blob = entry.encode()
+        payloads, hcrcs, hcrc_from = self._shard_payloads(
+            data_chunks, parity, row_crcs, whole)
         per_osd: dict[int, MOSDECSubOpWrite] = {}
         for pos, osd_id in enumerate(self.acting):
             if osd_id < 0 or not self.osd.osd_is_up(osd_id):
@@ -600,35 +602,19 @@ class ECPG(PG):
                 continue    # backfill target above its watermark: the
                 #             scan rebuilds this shard; a sub-op now
                 #             would materialize a partial object
-            shard = data_chunks[:, pos, :] if pos < self.k else \
-                parity[:, pos - self.k, :]
-            shard_bytes = shard.tobytes()
             attrs = dict(attrs_delta)
             # position stamp: these bytes encode THIS acting position
             # — readers/rebuilders trust the stamp over the holder's
             # (shuffle-prone) slot
             attrs["_pos"] = self._pos_attr(pos)
-            # per-shard write-time checksum (ref: ECBackend hinfo):
-            # valid only when this write covers the WHOLE object (a
-            # partial overwrite can't know the full-shard crc without
-            # reading the rest, so it invalidates it — exactly the
-            # reference's append-only hinfo discipline). Scrub repair
-            # uses it to LOCATE a corrupt shard, which the code alone
-            # cannot do at m=1. The value comes from the fused
-            # checksum+encode pass when it ran (hcrc_attr combines the
-            # device row CRCs; zlib fallback otherwise — pinned equal).
-            attrs["_hcrc"] = ec_crc.hcrc_attr(
-                shard_bytes,
-                row_crcs=row_crcs[:, pos]
-                if row_crcs is not None else None,
-                chunk_size=C) if whole else b""
+            attrs["_hcrc"] = hcrcs[pos]
             per_osd[osd_id] = MOSDECSubOpWrite(
                 tid=tid, epoch=self.epoch, pgid=self.cid, oid=oid,
-                first_stripe=first, data=shard_bytes,
+                first_stripe=first, data=payloads[pos],
                 truncate_stripes=trunc_stripes, size=size,
                 remove=False, attrs=attrs, omap=omap_delta,
                 omap_rm=list(omap_rm), log_entry=entry_blob)
-        sec.tag("stripes", count).finish()
+        sec.tag("stripes", count).tag("hcrc", hcrc_from).finish()
         committed = await self._fan_out_subops(tid, per_osd)
         if committed < self.k:
             # fewer than k durable shards: the object would be
@@ -638,6 +624,37 @@ class ECPG(PG):
                       f"{committed} shards committed (< k={self.k})")
             return -5                                 # -EIO
         return 0
+
+    @staticmethod
+    def _shard_payloads(data_chunks, parity, row_crcs, whole: bool
+                        ) -> tuple[list[bytes], list[bytes], str]:
+        """All k+m sub-write payloads and ``_hcrc`` stamps of one
+        write, by acting position, in one pass: ``data_chunks``
+        (count, k, C) and ``parity`` (count, m, C) are made lane-major
+        (one block transpose-copy each), so a position's payload is a
+        contiguous row and its ``bytes`` a memcpy.
+
+        The per-shard write-time checksum (ref: ECBackend hinfo) is
+        valid only when this write covers the WHOLE object (a partial
+        overwrite can't know the full-shard crc without reading the
+        rest, so it invalidates it with ``b""`` — exactly the
+        reference's append-only hinfo discipline). Scrub repair uses it
+        to LOCATE a corrupt shard, which the code alone cannot do at
+        m=1. The values come from the fused checksum+encode pass when
+        it ran (``row_crcs`` (count, k+m): hcrc_attrs folds the device
+        row CRCs of all shards at once; zlib of each payload otherwise
+        — pinned equal). Returns ``(payloads, hcrcs, where the stamps
+        came from: device_rows | zlib | none)``."""
+        payloads = [lane.tobytes() for block in (data_chunks, parity)
+                    for lane in np.ascontiguousarray(
+                        block.transpose(1, 0, 2))]
+        if not whole:
+            return payloads, [b""] * len(payloads), "none"
+        if row_crcs is None:
+            return payloads, ec_crc.hcrc_attrs(payloads), "zlib"
+        return payloads, ec_crc.hcrc_attrs(
+            payloads, row_crcs=row_crcs.T,
+            chunk_size=data_chunks.shape[2]), "device_rows"
 
     async def _fan_out_delete(self, oid: str, entry: LogEntry) -> int:
         tid = self.osd.next_tid()
@@ -934,12 +951,14 @@ class ECPG(PG):
                                          exclude_osds=exclude_osds,
                                          repair=True)
         if shard < self.k:
-            shard_bytes = data_chunks[:, shard, :].tobytes()
+            shard_bytes = np.ascontiguousarray(
+                data_chunks[:, shard, :]).tobytes()
             hcrc = ec_crc.hcrc_attr(shard_bytes)
         else:
             parity, row_crcs = await self._agg_encode(data_chunks,
                                                       with_crc=True)
-            shard_bytes = parity[:, shard - self.k, :].tobytes()
+            shard_bytes = np.ascontiguousarray(
+                parity[:, shard - self.k, :]).tobytes()
             hcrc = ec_crc.hcrc_attr(
                 shard_bytes,
                 row_crcs=row_crcs[:, shard]

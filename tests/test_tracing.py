@@ -630,6 +630,12 @@ def test_ec_write_and_degraded_read_capture_every_span(tmp_path):
              if r["trace_id"] == roots[0]["trace_id"]}
     assert wkind["objectstore_commit"] == "section"
     assert wkind["ec_subop_wait"] == wkind["ec_sub_write"] == "interval"
+    # the write's two ec_prepare stretches: the closing one says where
+    # the k+m _hcrc stamps came from (the fused pass's folded row CRCs)
+    prep = [r["tags"] for r in recs if r["name"] == "osd.ec_prepare"
+            and r["trace_id"] == roots[0]["trace_id"]]
+    assert prep == [{"stripes": 4},
+                    {"stripes": 4, "hcrc": "device_rows"}]
 
 
 def test_ec_spans_reach_trace_show_on_the_operators_path():
