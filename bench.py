@@ -89,7 +89,7 @@ def ec_streaming_metric(resident_gibs: float | None) -> dict:
 
 def ec_daemon_path_metric() -> dict:
     """Round-19 read-side data path: concurrent degraded-read decodes
-    through the ``osd/ec_read_aggregator`` (coalesced padded batched
+    through ``osd/ec_aggregator.ECReadAggregator`` (coalesced padded batched
     decode launches vs the per-op ``osd_ec_read_agg=off`` baseline),
     against the resident decode kernel rate. The claim the section
     pins: the aggregated daemon-path rate lands within 2x of the

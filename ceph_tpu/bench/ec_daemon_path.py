@@ -1,8 +1,8 @@
 """The ``ec_daemon_path`` bench section: the READ-side data path.
 
 Round 19's tentpole moved the OSD's decode/repair traffic behind
-``osd/ec_read_aggregator.ECReadAggregator`` — the read-side twin of the
-round-13 encode aggregator. This section measures the same op mix
+``osd/ec_aggregator.ECReadAggregator`` — the decode direction of the
+OSD's windowed batcher. This section measures the same op mix
 (n_ops concurrent "degraded reads", each a (stripes_per_op, k, C)
 survivor-chunk batch decoding one lost data chunk) through three legs:
 
@@ -38,7 +38,7 @@ import jax
 
 from ceph_tpu.bench import device_stamp
 from ceph_tpu.ec.jax_plugin import ErasureCodeJax
-from ceph_tpu.osd.ec_read_aggregator import ECReadAggregator
+from ceph_tpu.osd.ec_aggregator import ECReadAggregator
 
 
 def _default_shape() -> tuple[int, int, int]:

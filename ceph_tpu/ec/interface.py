@@ -195,7 +195,7 @@ class ErasureCodeInterface(ABC):
         """(B, len(avail), C) -> (B, len(want), C) via a HOST-ONLY
         path — no jit, no device, bit-exact with ``decode_batch`` by
         construction. The last rung of the OSD read aggregator's
-        degrade ladder (osd/ec_read_aggregator): when the device
+        degrade ladder (osd/ec_aggregator): when the device
         decode keeps failing, a degraded read is served from here
         rather than erroring. Base: the per-stripe loop (still
         host-only when ``decode_chunks`` is — device plugins MUST

@@ -222,18 +222,16 @@ class OSD(Dispatcher):
         # rounds all dequeue through it (osd_op_queue=fifo reverts to
         # the pre-scheduler FIFO admission loop)
         self.scheduler = OpScheduler(cfg)
-        # EC encode aggregator (round 13): concurrent stripe encodes
-        # from every ECPG on this OSD coalesce into one padded batched
-        # kernel launch per flush window (osd_ec_agg knobs, read LIVE)
-        from ceph_tpu.osd.ec_aggregator import ECAggregator
+        # EC aggregators (rounds 13 and 19): one windowed batcher, two
+        # directions. Concurrent stripe encodes, and degraded-read and
+        # recovery decodes, from every ECPG on this OSD coalesce into
+        # one padded batched kernel launch per flush window
+        # (osd_ec_agg* / osd_ec_read_agg* knobs, read LIVE); repair
+        # decodes charge the scheduler's `recovery` class so a
+        # degraded-read storm can't bypass QoS cost tags
+        from ceph_tpu.osd.ec_aggregator import ECAggregator, \
+            ECReadAggregator
         self.ec_agg = ECAggregator(cfg, tracer=self.tracer)
-        # EC decode/repair aggregator (round 19): the read-side twin —
-        # degraded reads and recovery rebuilds from every ECPG coalesce
-        # into one padded decode launch per flush window
-        # (osd_ec_read_agg knobs, read LIVE); repair decodes charge the
-        # scheduler's `recovery` class so a degraded-read storm can't
-        # bypass QoS cost tags
-        from ceph_tpu.osd.ec_read_aggregator import ECReadAggregator
         self.ec_read_agg = ECReadAggregator(cfg,
                                             scheduler=self.scheduler,
                                             tracer=self.tracer)

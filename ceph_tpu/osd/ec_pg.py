@@ -899,39 +899,26 @@ class ECPG(PG):
 
     async def _agg_encode(self, data_chunks, with_crc: bool = False,
                           span=None):
-        """Every ECPG encode routes through the OSD's cross-op
+        """Every ECPG encode routes through the OSD's cross-op encode
         aggregator (osd/ec_aggregator.py); the per-op launch survives
-        behind ``osd_ec_agg=off`` inside it. Bare harnesses without a
-        daemon aggregator take a direct (still fused) call. Returns
+        behind ``osd_ec_agg=off`` inside it. Returns
         ``(parity np(B, m, C), row_crcs np(B, k+m) | None)``.
         ``span``: the client op's span where the encode serves one."""
-        agg = getattr(self.osd, "ec_agg", None)
-        if agg is not None:
-            return await agg.encode(self.ec, data_chunks,
-                                    with_crc=with_crc, span=span)
-        if with_crc:
-            parity, crcs = self.ec.encode_batch_with_crc(data_chunks)
-            return np.asarray(parity), \
-                (None if crcs is None else np.asarray(crcs))
-        return np.asarray(self.ec.encode_batch(data_chunks)), None
+        return await self.osd.ec_agg.encode(
+            self.ec, data_chunks, with_crc=with_crc, span=span)
 
     async def _agg_decode(self, want, avail, chunks,
                           repair: bool = False):
         """Every ECPG decode routes through the OSD's cross-op read
-        aggregator (osd/ec_read_aggregator.py); the per-op launch
-        survives behind ``osd_ec_read_agg=off`` inside it. Bare
-        harnesses without a daemon aggregator take a direct call.
-        ``repair`` decodes charge a recovery-class size-scaled QoS
-        grant inside the aggregator — client degraded reads pass
-        False (their cost tag was paid at admission). Returns
-        np (B, len(want), C)."""
-        agg = getattr(self.osd, "ec_read_agg", None)
-        if agg is not None:
-            return await agg.decode(
-                self.ec, want, avail, chunks,
-                charge_bytes=int(chunks.nbytes) if repair else 0,
-                span=None if repair else self._active_span)
-        return np.asarray(self.ec.decode_batch(want, avail, chunks))
+        aggregator (osd/ec_aggregator.py); the per-op launch survives
+        behind ``osd_ec_read_agg=off`` inside it. ``repair`` decodes
+        charge a recovery-class size-scaled QoS grant inside the
+        aggregator — client degraded reads pass False (their cost tag
+        was paid at admission). Returns np (B, len(want), C)."""
+        return await self.osd.ec_read_agg.decode(
+            self.ec, want, avail, chunks,
+            charge_bytes=int(chunks.nbytes) if repair else 0,
+            span=None if repair else self._active_span)
 
     async def _rebuild_shard(self, oid: str, shard: int, ver: eversion,
                              size: int, apply_local: bool = False,

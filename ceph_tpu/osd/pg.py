@@ -1974,7 +1974,7 @@ class PG:
             # a re-peer + recovery has made the log durable on the new
             # acting set (_promote_pending_eagain). Recording 0 here
             # immediately (round 3) let a dup be acked with fewer than
-            # min_size durable copies (ADVICE.md round 3, medium).
+            # min_size durable copies (round 3's review, medium).
             self._reqid_results[reqid] = (result, extra)
         if len(self._reqid_results) > 2000:      # bounded (log-trim analog)
             kept_eagain = 0

@@ -181,8 +181,8 @@ OPTIONS: dict[str, Option] = {o.name: o for o in [
            "stripes that force an immediate aggregator flush (the "
            "batch-size ceiling; also bounds the padded launch's "
            "memory)", min=1),
-    # EC read/repair aggregator (round 19; the decode twin of the
-    # round-13 encode aggregator, osd/ec_read_aggregator.py). Read
+    # EC read/repair aggregator (round 19; the decode direction of
+    # the same batcher, ECReadAggregator in osd/ec_aggregator.py). Read
     # LIVE per decode, so osd_ec_read_agg=false flips a running OSD
     # to the measured per-op decode baseline.
     Option("osd_ec_read_agg", bool, True,
@@ -536,13 +536,6 @@ OPTIONS: dict[str, Option] = {o.name: o for o in [
            "when true, `ceph config set` rejects names that are not "
            "registered Options instead of storing them as raw "
            "strings"),
-    # TPU execution knobs (no Ceph analog).
-    Option("tpu_ec_backend", str, "auto",
-           "GF kernel: bitmatmul (MXU) | lut (VPU) | auto",
-           enum_allowed=("bitmatmul", "lut", "auto")),
-    Option("tpu_block_bytes", int, 1 << 20,
-           "per-step chunk-bytes tile for streaming encodes", min=4096),
-    Option("tpu_mesh_axes", str, "batch", "mesh axis names, comma-separated"),
     Option("debug_default_level", int, 0, "default log gate level"),
 ]}
 

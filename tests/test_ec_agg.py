@@ -13,19 +13,23 @@ only (the live-cluster acceptance rides tests/test_ec_cluster.py):
 - **fused encode+CRC** — one device program returns the SAME parity as
   the plain kernel plus per-row CRCs that fold to ``zlib.crc32`` of
   every shard (data AND parity positions);
-- **aggregator** — concurrent ops coalesce into fewer launches with
-  lane-for-lane identical results, every flush trigger fires
-  (full/window/idle, a lone op never held past the window), the
-  ``osd_ec_agg=off`` baseline bypasses, padding is pow2-bounded, and
-  drain cancels cleanly;
+- **aggregator, both directions** — the policy exists once
+  (``osd/ec_aggregator._WindowedBatcher``), so its cases run once per
+  direction (the ``way`` fixture: ``ECAggregator`` encoding,
+  ``ECReadAggregator`` decoding): concurrent ops coalesce into fewer
+  launches with lane-for-lane identical results, every flush trigger
+  fires (full/window/idle, a lone op never held past the window, a
+  cancelled flusher flushes as ``window``), the ``=off`` baseline
+  bypasses UNPADDED, padding is pow2-bounded, drain cancels cleanly,
+  and a failed batched flush rejects ONLY its own poisoned waiter;
+  what belongs to the decode side alone is in tests/test_ec_read_agg.py;
 - **pipeline** — StreamingEncodePipeline's outputs equal per-batch
   encodes, in order;
-- **degrade ladder (round 16)** — a failed batched flush
-  disaggregates and rejects ONLY its own poisoned waiter, per-op
-  device retries are bounded, the host reference encoder serves
-  bit-exactly as the last rung, the fused checksum+encode jit
-  quarantines on backoff after failures, and the streaming pipeline
-  falls back to the unpipelined path without losing a batch.
+- **degrade ladder (round 16)** — per-op device retries are bounded,
+  the host reference encoder serves bit-exactly as the last rung, the
+  fused checksum+encode jit quarantines on backoff after failures, and
+  the streaming pipeline falls back to the unpipelined path without
+  losing a batch.
 
 One module-scoped plugin instance: every test shares its jit cache
 (tier-1 runs near the wall-clock cap — compiles are the budget).
@@ -41,10 +45,12 @@ import pytest
 from ceph_tpu.ec import crc as ec_crc
 from ceph_tpu.ec.interface import ErasureCodeInterface
 from ceph_tpu.ec.jax_plugin import ErasureCodeJax, StreamingEncodePipeline
-from ceph_tpu.osd.ec_aggregator import ECAggregator
+from ceph_tpu.osd.ec_aggregator import ECAggregator, ECReadAggregator
 
 K, M, C = 3, 2, 64
 N = K + M
+WANT = (0,)             # data chunk 0 lost
+AVAIL = (1, 2, 3)       # survivors: data 1..2 + parity 0
 
 
 @pytest.fixture(scope="module")
@@ -161,29 +167,119 @@ def test_base_interface_fused_is_optional():
     run(go())
 
 
-# -- the aggregator --------------------------------------------------------
+# -- the aggregator, once per direction ------------------------------------
 
-def test_aggregator_coalesces_bit_exact(ec):
-    """Concurrent ops (non-pow2 sizes, mixed with_crc) coalesce into
-    FEWER launches than ops, and every op's slice equals its own
-    per-op encode lane for lane — the bit-exactness contract."""
-    rng = _rng(6)
-    ops = [rng.integers(0, 256, (b, K, C), dtype=np.uint8)
-           for b in (1, 3, 2, 5, 1, 3, 2)]
+class _Encode:
+    """The encode direction, as the shared cases drive it."""
+
+    cls, opt = ECAggregator, "osd_ec_agg"
+
+    @staticmethod
+    def ops(ec, rng, sizes):
+        return [rng.integers(0, 256, (b, K, C), dtype=np.uint8)
+                for b in sizes]
+
+    @staticmethod
+    def submit(agg, ec, x, with_crc=False):
+        return agg.encode(ec, x, with_crc=with_crc)
+
+    @staticmethod
+    def run(agg, ec, x, **kw):
+        return agg._run(ec, x, True, **kw)
+
+    @staticmethod
+    def direct(ec, x):
+        return np.asarray(ec.encode_batch(x))
+
+    @staticmethod
+    def rows(out):
+        return np.asarray(out[0])
+
+
+class _Decode:
+    """The decode direction: survivor chunks in, the lost chunk out."""
+
+    cls, opt = ECReadAggregator, "osd_ec_read_agg"
+
+    @staticmethod
+    def ops(ec, rng, sizes):
+        out = []
+        for data in _Encode.ops(ec, rng, sizes):
+            word = np.concatenate(
+                [data, np.asarray(ec.encode_batch(data))], axis=1)
+            out.append(np.stack([word[:, i, :] for i in AVAIL], axis=1))
+        return out
+
+    @staticmethod
+    def submit(agg, ec, x, with_crc=False):
+        return agg.decode(ec, WANT, AVAIL, x)
+
+    @staticmethod
+    def run(agg, ec, x, **kw):
+        return agg._run(ec, WANT, AVAIL, x, **kw)
+
+    @staticmethod
+    def direct(ec, x):
+        return np.asarray(ec.decode_batch(WANT, AVAIL, x))
+
+    @staticmethod
+    def rows(out):
+        return np.asarray(out)
+
+
+@pytest.fixture(params=[_Encode, _Decode], ids=["encode", "decode"])
+def way(request):
+    return request.param
+
+
+def _cfg(way, **knobs):
+    """``{<prefix>: True, <prefix>_<knob>: value, ...}``"""
+    return {way.opt: True,
+            **{f"{way.opt}_{k}": v for k, v in knobs.items()}}
+
+
+class _Spy:
+    """Records the batch size of every device launch."""
+
+    profile = "spy"
+
+    def __init__(self, ec):
+        self._ec = ec
+        self.launched = []
+
+    def encode_batch(self, data):
+        self.launched.append(data.shape[0])
+        return self._ec.encode_batch(data)
+
+    def encode_batch_with_crc(self, data):
+        self.launched.append(data.shape[0])
+        return self._ec.encode_batch_with_crc(data)
+
+    def decode_batch(self, want, avail, chunks):
+        self.launched.append(chunks.shape[0])
+        return self._ec.decode_batch(want, avail, chunks)
+
+
+def test_aggregator_coalesces_bit_exact(ec, way):
+    """Concurrent ops (non-pow2 sizes, mixed with_crc where encoding)
+    coalesce into FEWER launches than ops, and every op's slice equals
+    its own per-op result lane for lane — the bit-exactness contract."""
+    ops = way.ops(ec, _rng(6), (1, 3, 2, 5, 1, 3, 2))
 
     async def go():
-        agg = ECAggregator({"osd_ec_agg": True,
-                            "osd_ec_agg_window_us": 2000.0})
+        agg = way.cls(_cfg(way, window_us=2000.0))
         outs = await asyncio.gather(*[
-            agg.encode(ec, d, with_crc=(i % 2 == 0))
+            way.submit(agg, ec, d, with_crc=(i % 2 == 0))
             for i, d in enumerate(ops)])
         d = agg.dump()
         assert 1 <= d["batches"] < len(ops)
         assert d["ops"] == len(ops)
         assert d["stripes"] == sum(o.shape[0] for o in ops)
-        for i, (dat, (p, c)) in enumerate(zip(ops, outs)):
-            assert (np.asarray(p) ==
-                    np.asarray(ec.encode_batch(dat))).all(), i
+        for i, (dat, out) in enumerate(zip(ops, outs)):
+            assert (way.rows(out) == way.direct(ec, dat)).all(), i
+            if way is not _Encode:
+                continue
+            p, c = out
             if i % 2 == 0:
                 word = np.concatenate(
                     [dat, np.asarray(p)], axis=1)
@@ -197,19 +293,15 @@ def test_aggregator_coalesces_bit_exact(ec):
     run(go())
 
 
-def test_aggregator_full_trigger(ec):
-    """``osd_ec_agg_max_stripes`` forces an immediate flush — the
+def test_aggregator_full_trigger(ec, way):
+    """``<prefix>_max_stripes`` forces an immediate flush — the
     batch-size ceiling fires before any window elapses."""
-    rng = _rng(7)
+    ops = way.ops(ec, _rng(7), (2, 2, 2, 2))
 
     async def go():
-        agg = ECAggregator({"osd_ec_agg": True,
-                            "osd_ec_agg_window_us": 1e6,
-                            "osd_ec_agg_max_stripes": 4})
-        ops = [rng.integers(0, 256, (2, K, C), dtype=np.uint8)
-               for _ in range(4)]
+        agg = way.cls(_cfg(way, window_us=1e6, max_stripes=4))
         t0 = asyncio.get_event_loop().time()
-        await asyncio.gather(*[agg.encode(ec, d) for d in ops])
+        await asyncio.gather(*[way.submit(agg, ec, d) for d in ops])
         took = asyncio.get_event_loop().time() - t0
         d = agg.dump()
         assert d["flushes"]["full"] >= 1
@@ -217,119 +309,122 @@ def test_aggregator_full_trigger(ec):
     run(go())
 
 
-def test_aggregator_lone_op_never_held_past_window(ec):
+def test_aggregator_lone_op_never_held_past_window(ec, way):
     """A lone op flushes EARLY on queue idleness — and in any case
     inside the window (here 10s, so a window-bound wait would hang
     the assertion far past the observed bound)."""
-    rng = _rng(8)
+    d, = way.ops(ec, _rng(8), (1,))
 
     async def go():
-        agg = ECAggregator({"osd_ec_agg": True,
-                            "osd_ec_agg_window_us": 10e6})
-        d = rng.integers(0, 256, (1, K, C), dtype=np.uint8)
+        agg = way.cls(_cfg(way, window_us=10e6))
         t0 = asyncio.get_event_loop().time()
-        p, _ = await agg.encode(ec, d)
+        out = await way.submit(agg, ec, d)
         took = asyncio.get_event_loop().time() - t0
-        assert (p == np.asarray(ec.encode_batch(d))).all()
+        assert (way.rows(out) == way.direct(ec, d)).all()
         assert took < 9.0, "lone op pinned to the window"
         assert agg.dump()["flushes"]["idle"] == 1
     run(go())
 
 
-def test_aggregator_window_trigger(ec):
+def test_aggregator_window_trigger(ec, way):
     """An expired window flushes whatever accumulated (window ~0:
     the first flusher wake is already past the deadline)."""
-    rng = _rng(9)
+    ops = way.ops(ec, _rng(9), (1, 1))
 
     async def go():
-        agg = ECAggregator({"osd_ec_agg": True,
-                            "osd_ec_agg_window_us": 0.0})
-        ops = [rng.integers(0, 256, (1, K, C), dtype=np.uint8)
-               for _ in range(2)]
-        await asyncio.gather(*[agg.encode(ec, d) for d in ops])
+        agg = way.cls(_cfg(way, window_us=0.0))
+        await asyncio.gather(*[way.submit(agg, ec, d) for d in ops])
         assert agg.dump()["flushes"]["window"] >= 1
     run(go())
 
 
-def test_aggregator_off_is_per_op_baseline(ec):
-    """``osd_ec_agg=off`` (read LIVE) serves every encode per-op:
-    no batches, a bypass count, identical results — the measured
-    baseline the bench compares against."""
-    rng = _rng(10)
-    ops = [rng.integers(0, 256, (2, K, C), dtype=np.uint8)
-           for _ in range(3)]
+def test_aggregator_cancelled_flusher_flushes_as_window(ec, way):
+    """A flusher task cancelled from outside once it runs (its owner
+    going down, a task group unwinding) must not strand its waiters:
+    it flushes its group as ``window`` on the way out."""
+    d, = way.ops(ec, _rng(21), (3,))
 
     async def go():
-        cfg = {"osd_ec_agg": False}
-        agg = ECAggregator(cfg)
+        agg = way.cls(_cfg(way, window_us=10e6))
+        waiter = asyncio.ensure_future(way.submit(agg, ec, d))
+        for _ in range(3):              # entry lands, flusher soaking
+            await asyncio.sleep(0)
+        g, = agg._groups.values()
+        assert not waiter.done()
+        g.task.cancel()
+        out = await asyncio.wait_for(waiter, timeout=60.0)
+        assert (way.rows(out) == way.direct(ec, d)).all()
+        dmp = agg.dump()
+        assert dmp["flushes"] == {"window": 1, "full": 0, "idle": 0}
+        assert dmp["pending_ops"] == 0 and dmp["pending_groups"] == 0
+    run(go())
+
+
+def test_aggregator_off_is_per_op_baseline(ec, way):
+    """``<prefix>=off`` (read LIVE) serves every op per-op and
+    UNPADDED: no batches, a bypass count, identical results — the
+    measured baseline the bench compares against."""
+    ops = way.ops(ec, _rng(10), (3, 3, 3))
+    spy = _Spy(ec)
+
+    async def go():
+        cfg = {way.opt: False}
+        agg = way.cls(cfg)
         for d in ops:
-            p, c = await agg.encode(ec, d, with_crc=True)
-            assert (p == np.asarray(ec.encode_batch(d))).all()
-            assert c is not None      # fusion is orthogonal to agg
+            out = await way.submit(agg, spy, d, with_crc=True)
+            assert (way.rows(out) == way.direct(ec, d)).all()
+            if way is _Encode:
+                assert out[1] is not None   # fusion is orthogonal to agg
         dmp = agg.dump()
         assert dmp["batches"] == 0 and dmp["bypass"] == len(ops)
         assert dmp["enabled"] is False
+        assert spy.launched == [3, 3, 3]    # UNPADDED per-op launches
         # live flip back on: the same instance coalesces again
-        cfg["osd_ec_agg"] = True
-        await asyncio.gather(*[agg.encode(ec, d) for d in ops])
+        cfg[way.opt] = True
+        await asyncio.gather(*[way.submit(agg, ec, d) for d in ops])
         assert agg.dump()["batches"] >= 1
     run(go())
 
 
-def test_aggregator_pads_to_pow2(ec):
+def test_aggregator_pads_to_pow2(ec, way):
     """Padded launch sizes bound the jit cache to O(log max_batch)
     shapes, and the pad rows never leak into results."""
     for b, want in ((1, 1), (2, 2), (3, 4), (5, 8), (9, 16),
                     (4096, 4096)):
-        assert ECAggregator._pad(b) == want, b
-    rng = _rng(11)
-    agg = ECAggregator({})
-    d = rng.integers(0, 256, (5, K, C), dtype=np.uint8)  # pads to 8
-    launched = []
-
-    class _Spy:
-        profile = "spy"
-
-        def encode_batch(self, data):
-            launched.append(data.shape[0])
-            return ec.encode_batch(data)
-
-        def encode_batch_with_crc(self, data):
-            launched.append(data.shape[0])
-            return ec.encode_batch_with_crc(data)
-
-    p, crcs = agg._run(_Spy(), d, True)
-    assert launched == [8]              # flush path pads 5 -> 8
-    assert p.shape == (5, M, C)
-    assert crcs.shape == (5, N)
-    assert (p == np.asarray(ec.encode_batch(d))).all()
-    # the osd_ec_agg=off bypass is the UNPADDED historical per-op
+        assert way.cls._pad(b) == want, b
+    agg = way.cls({})
+    d, = way.ops(ec, _rng(11), (5,))    # pads to 8
+    spy = _Spy(ec)
+    out = way.run(agg, spy, d)
+    assert spy.launched == [8]          # flush path pads 5 -> 8
+    assert way.rows(out).shape[0] == 5
+    assert (way.rows(out) == way.direct(ec, d)).all()
+    if way is _Encode:
+        assert out[0].shape == (5, M, C) and out[1].shape == (5, N)
+    # the <prefix>=off bypass is the UNPADDED historical per-op
     # launch — the measured baseline must not pay pad compute the
     # pre-aggregator path never paid
-    p2, _ = agg._run(_Spy(), d, False, pad=False)
-    assert launched == [8, 5]
-    assert (p2 == p).all()
+    out2 = way.run(agg, spy, d, pad=False)
+    assert spy.launched == [8, 5]
+    assert (way.rows(out2) == way.rows(out)).all()
 
 
-def test_aggregator_drain_cancels_waiters(ec):
+def test_aggregator_drain_cancels_waiters(ec, way):
     """Daemon stop: pending waiters are CANCELLED (their PG op workers
     are going down too), timers die, and the stopped aggregator serves
     later stragglers per-op instead of queueing them forever."""
-    rng = _rng(12)
+    d, = way.ops(ec, _rng(12), (1,))
 
     async def go():
-        agg = ECAggregator({"osd_ec_agg": True,
-                            "osd_ec_agg_window_us": 10e6,
-                            "osd_ec_agg_max_stripes": 1 << 20})
-        d = rng.integers(0, 256, (1, K, C), dtype=np.uint8)
-        waiter = asyncio.ensure_future(agg.encode(ec, d))
+        agg = way.cls(_cfg(way, window_us=10e6, max_stripes=1 << 20))
+        waiter = asyncio.ensure_future(way.submit(agg, ec, d))
         await asyncio.sleep(0)          # entry lands, timer armed
         assert agg.drain() == 1
         with pytest.raises(asyncio.CancelledError):
             await waiter
         assert agg.dump()["pending_ops"] == 0
-        p, _ = await agg.encode(ec, d)  # straggler: served, per-op
-        assert (p == np.asarray(ec.encode_batch(d))).all()
+        out = await way.submit(agg, ec, d)      # straggler: per-op
+        assert (way.rows(out) == way.direct(ec, d)).all()
     run(go())
 
 
@@ -357,10 +452,11 @@ def test_streaming_pipeline_matches_per_batch(ec):
 # -- the degrade ladder (round 16) -----------------------------------------
 
 class _FlakyEC:
-    """Delegates to the module plugin but fails on command: device
-    launches raise while a ``poison`` stripe rides in the batch (or
-    always, with ``fail_all``), and the reference encoder refuses the
-    poison stripe itself — the worst case the ladder must isolate."""
+    """Delegates to the module plugin but fails on command, in both
+    directions: device launches raise while a ``poison`` stripe rides
+    in the batch (or always, with ``fail_all``), and the reference
+    refuses the poison stripe itself — the worst case the ladder must
+    isolate."""
 
     profile = "flaky"
 
@@ -371,13 +467,17 @@ class _FlakyEC:
         self.device_calls = 0
 
     def _poisoned(self, data):
-        return self._poison is not None and \
-            bool((data == self._poison).all(axis=(1, 2)).any())
+        return self._poison is not None and bool(
+            (np.asarray(data) == self._poison).all(axis=(1, 2)).any())
 
     def _maybe_fail(self, data):
         self.device_calls += 1
         if self.fail_all or self._poisoned(data):
             raise RuntimeError("injected device failure")
+
+    def _reference_refuses(self, data):
+        if self._poisoned(data):
+            raise RuntimeError("reference refuses the poison stripe")
 
     def encode_batch(self, data):
         self._maybe_fail(data)
@@ -388,36 +488,39 @@ class _FlakyEC:
         return self._ec.encode_batch_with_crc(data)
 
     def encode_batch_reference(self, data):
-        if self._poisoned(data):
-            raise RuntimeError("reference refuses the poison stripe")
+        self._reference_refuses(data)
         return self._ec.encode_batch_reference(data)
 
+    def decode_batch(self, want, avail, chunks):
+        self._maybe_fail(chunks)
+        return self._ec.decode_batch(want, avail, chunks)
 
-def test_flush_failure_rejects_only_the_poisoned_op(ec):
+    def decode_batch_reference(self, want, avail, chunks):
+        self._reference_refuses(chunks)
+        return self._ec.decode_batch_reference(want, avail, chunks)
+
+
+def test_flush_failure_rejects_only_the_poisoned_op(ec, way):
     """A failed batched flush DISAGGREGATES: each batchmate retries
     per-op and is served lane-for-lane exactly; only the op whose
-    stripe fails even under the reference encoder sees the exception.
-    One poisoned stripe must not fail its batchmates."""
-    rng = _rng(16)
-    good = [rng.integers(0, 256, (2, K, C), dtype=np.uint8)
-            for _ in range(2)]
-    poison = np.full((1, K, C), 0xAB, dtype=np.uint8)
+    rows fail even under the reference sees the exception. One
+    poisoned stripe must not fail its batchmates."""
+    good = way.ops(ec, _rng(16), (2, 2))
+    poison = np.full((1,) + good[0].shape[1:], 0xAB, dtype=np.uint8)
     flaky = _FlakyEC(ec, poison=0xAB)
 
     async def go():
-        agg = ECAggregator({"osd_ec_agg": True,
-                            "osd_ec_agg_window_us": 2000.0,
-                            "osd_ec_fallback_retries": 1})
+        agg = way.cls({**_cfg(way, window_us=2000.0),
+                       "osd_ec_fallback_retries": 1})
         outs = await asyncio.gather(
-            agg.encode(flaky, good[0]),
-            agg.encode(flaky, poison),
-            agg.encode(flaky, good[1]),
+            way.submit(agg, flaky, good[0]),
+            way.submit(agg, flaky, poison),
+            way.submit(agg, flaky, good[1]),
             return_exceptions=True)
         for i, dat in ((0, good[0]), (2, good[1])):
-            p, c = outs[i]
-            assert c is None
-            assert (np.asarray(p) ==
-                    np.asarray(ec.encode_batch(dat))).all(), i
+            assert (way.rows(outs[i]) == way.direct(ec, dat)).all(), i
+            if way is _Encode:
+                assert outs[i][1] is None
         assert isinstance(outs[1], RuntimeError)
         d = agg.perf.dump()
         assert d.get("flush_failures", 0) == 1
@@ -426,9 +529,8 @@ def test_flush_failure_rejects_only_the_poisoned_op(ec):
         assert agg.dump()["pending_ops"] == 0
         # the aggregator stays LIVE after a failed flush: the next
         # batch coalesces and serves normally
-        p, _ = await agg.encode(flaky, good[0])
-        assert (np.asarray(p) ==
-                np.asarray(ec.encode_batch(good[0]))).all()
+        out = await way.submit(agg, flaky, good[0])
+        assert (way.rows(out) == way.direct(ec, good[0])).all()
         assert agg.perf.dump().get("batches", 0) == 1
     run(go())
 

@@ -10,15 +10,13 @@ acceptance rides tests/test_ec_cluster.py):
   jit) equals the device kernel bit for bit on BOTH kernel planes
   (GF(2^8) matmul and packet-plane bitmatrix XOR), and reconstructs
   real codewords;
-- **aggregator** — concurrent decodes coalesce into fewer launches
-  with lane-for-lane identical results, every flush trigger fires,
-  the ``osd_ec_read_agg=off`` baseline bypasses UNPADDED, padding is
-  pow2-bounded, and drain cancels cleanly;
-- **degrade ladder** — a failed batched flush disaggregates and
-  rejects ONLY its own poisoned waiter, per-op device retries are
-  bounded, the reference decoder serves bit-exactly as the last rung,
-  and repeated failures quarantine the device decode on exponential
-  backoff;
+- **aggregator** — what belongs to the decode direction alone (the
+  policy shared with the encode direction is held once per direction
+  in tests/test_ec_agg.py): ops with different erasure patterns never
+  share a launch;
+- **degrade ladder** — per-op device retries are bounded, the
+  reference decoder serves bit-exactly as the last rung, and repeated
+  failures quarantine the device decode on exponential backoff;
 - **QoS honesty** — a repair decode (charge_bytes > 0) pays a
   recovery-class size-scaled grant BEFORE queueing; client degraded
   reads (charge_bytes=0) pay nothing here (already cost-tagged at
@@ -43,7 +41,7 @@ import pytest
 
 from ceph_tpu.ec import crc as ec_crc
 from ceph_tpu.ec.jax_plugin import DeviceShardCache, ErasureCodeJax
-from ceph_tpu.osd.ec_read_aggregator import ECReadAggregator
+from ceph_tpu.osd.ec_aggregator import ECReadAggregator
 
 K, M, C = 3, 2, 64
 N = K + M
@@ -101,29 +99,6 @@ def test_reference_decoder_bit_exact_both_planes(ec):
 
 # -- the aggregator --------------------------------------------------------
 
-def test_read_aggregator_coalesces_bit_exact(ec):
-    """Concurrent decodes (non-pow2 sizes) coalesce into FEWER
-    launches than ops, and every op's slice equals its own per-op
-    decode lane for lane."""
-    rng = _rng(2)
-    ops = [_survivors(_codeword(ec, rng, b)[0])
-           for b in (1, 3, 2, 5, 1, 3, 2)]
-
-    async def go():
-        agg = ECReadAggregator({"osd_ec_read_agg": True,
-                                "osd_ec_read_agg_window_us": 2000.0})
-        outs = await asyncio.gather(*[
-            agg.decode(ec, WANT, AVAIL, d) for d in ops])
-        d = agg.dump()
-        assert 1 <= d["batches"] < len(ops)
-        assert d["ops"] == len(ops)
-        assert d["stripes"] == sum(o.shape[0] for o in ops)
-        for i, (chunks, out) in enumerate(zip(ops, outs)):
-            assert (np.asarray(out) == np.asarray(
-                ec.decode_batch(WANT, AVAIL, chunks))).all(), i
-    run(go())
-
-
 def test_read_aggregator_groups_by_erasure_pattern(ec):
     """Ops with DIFFERENT (avail, want) never share a launch — the
     group key is the decode-kernel cache key."""
@@ -143,125 +118,6 @@ def test_read_aggregator_groups_by_erasure_pattern(ec):
             ec.decode_batch(WANT, AVAIL, a))).all()
         assert (np.asarray(ob) == np.asarray(
             ec.decode_batch((1,), (0, 2, 4), b))).all()
-    run(go())
-
-
-def test_read_aggregator_full_trigger(ec):
-    """``osd_ec_read_agg_max_stripes`` forces an immediate flush."""
-    rng = _rng(4)
-
-    async def go():
-        agg = ECReadAggregator({"osd_ec_read_agg": True,
-                                "osd_ec_read_agg_window_us": 1e6,
-                                "osd_ec_read_agg_max_stripes": 4})
-        ops = [_survivors(_codeword(ec, rng, 2)[0]) for _ in range(4)]
-        t0 = asyncio.get_event_loop().time()
-        await asyncio.gather(*[agg.decode(ec, WANT, AVAIL, d)
-                               for d in ops])
-        took = asyncio.get_event_loop().time() - t0
-        assert agg.dump()["flushes"]["full"] >= 1
-        assert took < 1.0      # nobody waited for the 1s window
-    run(go())
-
-
-def test_read_aggregator_lone_op_never_held_past_window(ec):
-    """A lone degraded read flushes EARLY on queue idleness."""
-    rng = _rng(5)
-
-    async def go():
-        agg = ECReadAggregator({"osd_ec_read_agg": True,
-                                "osd_ec_read_agg_window_us": 10e6})
-        d = _survivors(_codeword(ec, rng, 1)[0])
-        t0 = asyncio.get_event_loop().time()
-        out = await agg.decode(ec, WANT, AVAIL, d)
-        took = asyncio.get_event_loop().time() - t0
-        assert (np.asarray(out) == np.asarray(
-            ec.decode_batch(WANT, AVAIL, d))).all()
-        assert took < 9.0, "lone op pinned to the window"
-        assert agg.dump()["flushes"]["idle"] == 1
-    run(go())
-
-
-def test_read_aggregator_off_is_per_op_baseline(ec):
-    """``osd_ec_read_agg=off`` (read LIVE) serves every decode per-op
-    and UNPADDED: no batches, a bypass count, identical results — the
-    measured baseline the bench compares against."""
-    rng = _rng(6)
-    ops = [_survivors(_codeword(ec, rng, 3)[0]) for _ in range(3)]
-    launched = []
-
-    class _Spy:
-        profile = "spy"
-
-        def decode_batch(self, want, avail, chunks):
-            launched.append(chunks.shape[0])
-            return ec.decode_batch(want, avail, chunks)
-
-    async def go():
-        cfg = {"osd_ec_read_agg": False}
-        agg = ECReadAggregator(cfg)
-        for d in ops:
-            out = await agg.decode(_Spy(), WANT, AVAIL, d)
-            assert (np.asarray(out) == np.asarray(
-                ec.decode_batch(WANT, AVAIL, d))).all()
-        dmp = agg.dump()
-        assert dmp["batches"] == 0 and dmp["bypass"] == len(ops)
-        assert dmp["enabled"] is False
-        assert launched == [3, 3, 3]     # UNPADDED per-op launches
-        # live flip back on: the same instance coalesces again
-        cfg["osd_ec_read_agg"] = True
-        await asyncio.gather(*[agg.decode(ec, WANT, AVAIL, d)
-                               for d in ops])
-        assert agg.dump()["batches"] >= 1
-    run(go())
-
-
-def test_read_aggregator_pads_to_pow2(ec):
-    """Padded flush launches bound the jit cache to O(log max_batch)
-    shapes, and the pad rows never leak into results."""
-    for b, want in ((1, 1), (2, 2), (3, 4), (5, 8), (9, 16),
-                    (4096, 4096)):
-        assert ECReadAggregator._pad(b) == want, b
-    rng = _rng(7)
-    d = _survivors(_codeword(ec, rng, 5)[0])    # pads to 8
-    launched = []
-
-    class _Spy:
-        profile = "spy"
-
-        def decode_batch(self, want, avail, chunks):
-            launched.append(chunks.shape[0])
-            return ec.decode_batch(want, avail, chunks)
-
-    agg = ECReadAggregator({})
-    out = agg._run(_Spy(), WANT, AVAIL, d)
-    assert launched == [8]              # flush path pads 5 -> 8
-    assert out.shape == (5, len(WANT), C)
-    assert (out == np.asarray(ec.decode_batch(WANT, AVAIL, d))).all()
-    out2 = agg._run(_Spy(), WANT, AVAIL, d, pad=False)
-    assert launched == [8, 5]           # the bypass baseline: unpadded
-    assert (out2 == out).all()
-
-
-def test_read_aggregator_drain_cancels_waiters(ec):
-    """Daemon stop: pending waiters are CANCELLED, timers die, and the
-    stopped aggregator serves later stragglers per-op."""
-    rng = _rng(8)
-
-    async def go():
-        agg = ECReadAggregator({"osd_ec_read_agg": True,
-                                "osd_ec_read_agg_window_us": 10e6,
-                                "osd_ec_read_agg_max_stripes": 1 << 20})
-        d = _survivors(_codeword(ec, rng, 1)[0])
-        waiter = asyncio.ensure_future(agg.decode(ec, WANT, AVAIL, d))
-        await asyncio.sleep(0)          # entry lands, timer armed
-        assert agg.drain() == 1
-        with pytest.raises(asyncio.CancelledError):
-            await waiter
-        assert agg.dump()["pending_ops"] == 0
-        out = await agg.decode(ec, WANT, AVAIL, d)   # straggler
-        assert (np.asarray(out) == np.asarray(
-            ec.decode_batch(WANT, AVAIL, d))).all()
     run(go())
 
 
@@ -297,39 +153,6 @@ class _FlakyDecodeEC:
         return self._ec.decode_batch_reference(want, avail, chunks)
 
 
-def test_read_flush_failure_rejects_only_the_poisoned_op(ec):
-    """A failed batched flush DISAGGREGATES: each batchmate retries
-    per-op and is served lane-for-lane exactly; only the op whose
-    chunks fail even under the reference decoder sees the exception."""
-    rng = _rng(9)
-    good = [_survivors(_codeword(ec, rng, 2)[0]) for _ in range(2)]
-    poison = np.full((1, len(AVAIL), C), 0xAB, dtype=np.uint8)
-    flaky = _FlakyDecodeEC(ec, poison=0xAB)
-
-    async def go():
-        agg = ECReadAggregator({"osd_ec_read_agg": True,
-                                "osd_ec_read_agg_window_us": 2000.0,
-                                "osd_ec_fallback_retries": 1})
-        outs = await asyncio.gather(
-            agg.decode(flaky, WANT, AVAIL, good[0]),
-            agg.decode(flaky, WANT, AVAIL, poison),
-            agg.decode(flaky, WANT, AVAIL, good[1]),
-            return_exceptions=True)
-        for i, chunks in ((0, good[0]), (2, good[1])):
-            assert (np.asarray(outs[i]) == np.asarray(
-                ec.decode_batch(WANT, AVAIL, chunks))).all(), i
-        assert isinstance(outs[1], RuntimeError)
-        d = agg.perf.dump()
-        assert d.get("flush_failures", 0) == 1
-        assert d.get("per_op_retries", 0) >= 1
-        assert agg.dump()["pending_ops"] == 0
-        # the aggregator stays LIVE after a failed flush
-        out = await agg.decode(flaky, WANT, AVAIL, good[0])
-        assert (np.asarray(out) == np.asarray(
-            ec.decode_batch(WANT, AVAIL, good[0]))).all()
-    run(go())
-
-
 def test_read_degrade_ladder_reference_and_quarantine(ec):
     """Device decode hard-down: the op is served by the bit-exact
     reference decoder after bounded retries; repeated failures
@@ -337,6 +160,8 @@ def test_read_degrade_ladder_reference_and_quarantine(ec):
     zero device calls), and the quarantine expires on backoff."""
     rng = _rng(10)
     d = _survivors(_codeword(ec, rng, 3)[0])
+    # this shape's first decode compiles: outside the 50 ms quarantine
+    ec.decode_batch(WANT, AVAIL, d)
     flaky = _FlakyDecodeEC(ec, fail_all=True)
 
     async def go():

@@ -4,10 +4,11 @@ all k+m sub-write payloads and ``_hcrc`` stamps in one pass
 all shards).
 
 A bare harness: one real ``ECPG`` over a ``MemStore`` behind a stub OSD
-with no aggregator (``_agg_encode`` then makes the direct, still fused,
-call) and the fan-out captured instead of sent. Every sent position is
-held to the idiom the pass replaced: ``data_chunks[:, pos, :].tobytes()``
-(parity alike) and ``zlib.crc32`` of those bytes.
+that holds the two aggregators a daemon would (an ``ECPG`` has no
+launch of its own) and the fan-out captured instead of sent. Every sent
+position is held to the idiom the pass replaced:
+``data_chunks[:, pos, :].tobytes()`` (parity alike) and ``zlib.crc32``
+of those bytes.
 """
 
 import asyncio
@@ -19,6 +20,7 @@ import pytest
 
 from ceph_tpu.ec import crc as ec_crc
 from ceph_tpu.os_.objectstore import MemStore
+from ceph_tpu.osd.ec_aggregator import ECAggregator, ECReadAggregator
 from ceph_tpu.osd.ec_pg import ECPG
 from ceph_tpu.osd.types import pg_t
 
@@ -35,6 +37,8 @@ class _StubOSD:
     def __init__(self):
         self.store = MemStore()
         self.config = {}
+        self.ec_agg = ECAggregator(self.config)
+        self.ec_read_agg = ECReadAggregator(self.config)
         self.down: set[int] = set()
         self._tid = 0
 

@@ -311,10 +311,27 @@ def test_noout_and_mark_me_down():
 
 # -- cluster: backoff ------------------------------------------------------
 
+async def _wait_until(cond, what: str, limit: float = 60.0) -> None:
+    """Poll ``cond`` until it holds; ``limit`` is for a machine that
+    runs six test workers at once, not an expectation."""
+    loop = asyncio.get_event_loop()
+    deadline = loop.time() + limit
+    while not cond():
+        assert loop.time() < deadline, what
+        await asyncio.sleep(0.02)
+
+
 def test_backoff_released_on_pg_activation():
     """An op hitting a not-active primary gets MOSDBackoff BLOCK (the
     objecter parks — no timeout churn); when the PG activates the
-    UNBLOCK releases the op, which then completes for real."""
+    UNBLOCK releases the op, which then completes for real.
+
+    The PG is held mid-peering by gating ``_peer_inner`` itself, not
+    by a bare ``state = "peering"``: a retry timer of an earlier
+    peering round (``OSD.request_repeer``, the up_thru wait's 0.3 s)
+    that is still pending when the cluster first reads clean re-advances
+    any PG it finds in ``peering``, and would activate a PG frozen by
+    its state alone while the op is meant to sit parked."""
     async def go():
         c = await Cluster(n_mons=1, n_osds=3).start()
         try:
@@ -327,23 +344,31 @@ def test_backoff_released_on_pg_activation():
             seed, primary = objecter._calc_target(
                 osdmap, io.pool_id, "bo-obj")
             pg = c.osds[primary].pgs[f"{io.pool_id}.{seed:x}"]
-            # freeze the PG mid-peering (a legit intermediate state:
-            # ops arriving now must be backed off, not queued forever)
+            # hold the PG mid-peering (a legit intermediate state:
+            # ops arriving now must be backed off, not queued forever):
+            # whoever re-advances it meanwhile waits at the gate too
+            gate = asyncio.Event()
+            peer_inner = pg._peer_inner
+
+            async def held_peer_inner():
+                await gate.wait()
+                await peer_inner()
+
+            pg._peer_inner = held_peer_inner
             pg.state = "peering"
             parked = asyncio.ensure_future(
-                io.write_full("bo-obj", b"v2", timeout=30.0))
-            deadline = asyncio.get_event_loop().time() + 5.0
-            while not pg.backoffs:
-                assert asyncio.get_event_loop().time() < deadline, \
-                    "primary never asserted a backoff"
-                await asyncio.sleep(0.05)
-            await asyncio.sleep(0.3)
+                io.write_full("bo-obj", b"v2", timeout=120.0))
+            await _wait_until(lambda: pg.backoffs,
+                              "primary never asserted a backoff")
+            await _wait_until(lambda: objecter._backoffs,
+                              "objecter did not record BLOCK")
             assert not parked.done()        # parked client-side
-            assert objecter._backoffs, "objecter did not record BLOCK"
+            assert pg.state == "peering"
             # drive the REAL activation path: re-advance triggers
             # peering which releases backoffs on completion
+            gate.set()
             pg.advance(pg.up, pg.acting, pg.primary, pg.epoch)
-            await asyncio.wait_for(parked, timeout=15.0)
+            await asyncio.wait_for(parked, timeout=90.0)
             assert not pg.backoffs, "backoffs survived activation"
             assert await io.read("bo-obj") == b"v2"
         finally:

@@ -58,6 +58,14 @@ def test_proc_cluster_storm_and_live_config():
         try:
             assert c.spawn_to_healthy_s is not None
             await c.client.pool_create("t", pg_num=16, size=3)
+
+            # the mon acks the command when the epoch commits; this
+            # client's subscription may deliver it a moment later
+            async def sees_pool():
+                om = c.client.monc.osdmap
+                return om is not None and any(
+                    p.name == "t" for p in om.pools.values())
+            await _wait(sees_pool, timeout=60.0)
             io = await c.client.open_ioctx("t")
 
             # -- 1: live config lands typed, no restart ----------------
