@@ -127,15 +127,17 @@ def sweep_rate(n_osds: int = 10240, n_pgs: int = 1 << 22, num_rep: int = 3,
     # Mapper to the XLA path (by design — correctness first), and the
     # PR 4 choose_args regression hid behind exactly that silence
     expected_path = mapper.mapping_path(rule, num_rep)
-    # quantize both sizes to DISTINCT block counts: the per-block program
-    # does full-block work regardless of the tail mask, so sizes that
-    # round to the same block count would make the slope pure noise
+    # both sizes are whole numbers of the rule's widest block, and
+    # DISTINCT ones: every block of both sweeps is then the same
+    # program at full fill, so the slope is that program's time a lane
+    # (a size off the multiple would end in a narrower tail block, a
+    # second program with its own compile in the timed sweep)
     blk = mapper.effective_block(rule, num_rep)
     hi_blocks = max(2, -(-n_pgs // blk))
     lo_blocks = max(1, hi_blocks // 4)
     n_hi = hi_blocks * blk
     n_lo = lo_blocks * blk if lo_blocks < hi_blocks else 0
-    # warm/compile (the per-block program is size-independent, but warm so
+    # warm/compile (both sizes run the one full-width program; warm so
     # the first-compile cost is excluded from timing)
     _timed_sweep(mapper, rule, n_lo or n_hi, num_rep)
     t_hi = min(_timed_sweep(mapper, rule, n_hi, num_rep) for _ in range(2))

@@ -158,6 +158,10 @@ PERF = (_PCB("crush_mapper")
                          "reweights that flipped skip_is_out (new jit key)")
         .add_u64_counter("pgs_mapped", "PG lanes through map_pgs/sweep")
         .add_u64_counter("sweep_blocks", "device blocks dispatched by sweep")
+        .add_u64_counter("sweep_lanes",
+                         "lanes dispatched by sweep: the sum of its "
+                         "blocks' widths (pgs_mapped over it is the "
+                         "fill share)")
         .create_perf_counters())
 
 
@@ -832,6 +836,26 @@ def _compact(w):
 # Rule execution
 # ---------------------------------------------------------------------------
 
+# Narrowest block a Mapper chooses by itself. A block does full-width
+# work whatever its tail mask, and every width is a program of its own,
+# so the floor bounds the programs: six widths, 2^16..2^21, on the
+# kernel path. Under 2^16 lanes the kernel takes less than 2.5 ms on a
+# v5e (37 ns a lane) against the host's 6-7 ms a sweep (PERF.md):
+# narrower would buy nothing.
+MIN_BLOCK_WIDTH = 1 << 16
+
+
+def block_width(lanes: int, cap: int, floor: int = 1) -> int:
+    """Width of the block that maps ``lanes`` lanes when the widest
+    block is ``cap``: the next power of two at or above ``lanes``, at
+    least ``floor``, never above ``cap`` (a cap below the floor wins).
+    The one width rule of ``Mapper.sweep``, ``Mapper.map_pgs``'s tail
+    block and ``sharded_sweep``'s per-shard tile."""
+    if lanes >= cap:
+        return cap
+    return min(cap, max(floor, 1 << max(0, int(lanes) - 1).bit_length()))
+
+
 class Mapper:
     """Compiled batched CRUSH mapper for one CrushMap.
 
@@ -1502,20 +1526,26 @@ class Mapper:
         return out
 
     def effective_block(self, ruleno: int, result_max: int) -> int:
-        """The chunk width sweep/map_pgs will actually use for this
-        rule (kernel-path rules take wider blocks) — benches must
-        quantize their two-size slope on this, not on self.block."""
+        """The widest block sweep/map_pgs use for this rule (kernel-path
+        rules take wider blocks; a block with fewer lanes left to map
+        is narrower, see ``block_width``) — the sharded path tiles on
+        it and benches quantize their two-size slope on it, not on
+        self.block."""
         if self._scalar_reason:
             return self.block
-        return self._block_for(
+        return self._block_cap(
             self._kernel_body(ruleno, result_max) is not None)
 
-    def _block_for(self, kernel: bool) -> int:
-        """Chunk width. The fused kernel's working set is VMEM-resident
-        per LANES-wide grid cell (no (N, S) straw2 temps), so it takes
-        much wider blocks — fewer dispatches (what a dispatch costs is
-        not re-measured on a local chip; the width stands)."""
+    def _block_cap(self, kernel: bool) -> int:
+        """The widest block. The fused kernel's working set is
+        VMEM-resident per LANES-wide grid cell (no (N, S) straw2 temps),
+        so it takes much wider blocks than the XLA path's memory bound:
+        fewer dispatches for a long sweep."""
         return max(self.block, 1 << 21) if kernel else self.block
+
+    def _block_for(self, kernel: bool, lanes: int) -> int:
+        """Width of the block that maps the next ``lanes`` lanes."""
+        return block_width(lanes, self._block_cap(kernel), MIN_BLOCK_WIDTH)
 
     def _record_path(self, path: str, expected: str | None) -> str:
         """Per-CALL path record (round 14): the returned value is this
@@ -1570,7 +1600,8 @@ class Mapper:
                 PERF.inc("kernel_compiles")
         else:
             fn = self._rule_fn(ruleno, result_max)
-        block = self._block_for(kb is not None)
+        kb_kern = kb is not None
+        block = self._block_cap(kb_kern)
         if len(xs) == 0:     # the kernel rejects n=0 (and the guard
             with jax.enable_x64(True):     # readback would IndexError)
                 return (jnp.zeros((0, result_max), dtype=jnp.int32),
@@ -1580,7 +1611,6 @@ class Mapper:
             with jax.enable_x64(True):
                 xs = jnp.asarray(xs, dtype=jnp.uint32)
                 n = xs.shape[0]
-                kb_kern = kb is not None
                 if n <= block:
                     out = dm.jit_call(
                         "crush_map_pgs",
@@ -1590,21 +1620,18 @@ class Mapper:
                     pieces = []
                     for start in range(0, n, block):
                         piece = xs[start:start + block]
-                        if piece.shape[0] < block:  # pad the tail block
-                            pad = block - piece.shape[0]  # so the jit
-                            piece = jnp.pad(piece, (0, pad))  # cache
-                            pieces.append(      # stays one entry/shape
-                                dm.jit_call(
-                                    "crush_map_pgs",
-                                    self._jit_key(ruleno, result_max,
-                                                  kb_kern, block),
-                                    fn, self.arrays, piece)[:-pad])
-                        else:
-                            pieces.append(dm.jit_call(
-                                "crush_map_pgs",
-                                self._jit_key(ruleno, result_max,
-                                              kb_kern, block),
-                                fn, self.arrays, piece))
+                        lanes = piece.shape[0]
+                        # the tail block is padded to the width its
+                        # lanes need, so the jit cache holds one entry
+                        # a width (block_width), not one a length
+                        width = self._block_for(kb_kern, lanes)
+                        if lanes < width:
+                            piece = jnp.pad(piece, (0, width - lanes))
+                        pieces.append(dm.jit_call(
+                            "crush_map_pgs",
+                            self._jit_key(ruleno, result_max, kb_kern,
+                                          width),
+                            fn, self.arrays, piece)[:lanes])
                     out = jnp.concatenate(pieces, axis=0)
                 if kb is not None:
                     # dispatch is async: an execution-time kernel
@@ -1698,32 +1725,41 @@ class Mapper:
         fn_body = kb or _rule_body(*self._rule_key(ruleno, result_max))
         firstn = self.rule_is_firstn(ruleno)
         nd = device_counts_size or self.packed.max_devices
-        block = self._block_for(kb is not None)
-        nblocks = -(-n // block)
-
-        step_fn = _compiled_sweep(fn_body, firstn, nd, block, result_max)
+        kb_kern = kb is not None
         dm = _devmon()
+        nblocks = lanes = 0
+        forced = None
         try:
             with jax.enable_x64(True):
                 counts = jnp.zeros(nd + 1, dtype=jnp.int64)
                 bad = jnp.int64(0)
-                for i in range(nblocks):
+                while lanes < n:
+                    # every block is as wide as the lanes left need:
+                    # a sweep under the cap is one block of its own
+                    # width, a longer one ends in a narrower tail block
+                    block = self._block_for(kb_kern, n - lanes)
+                    step_fn = _compiled_sweep(fn_body, firstn, nd, block,
+                                              result_max)
                     counts, bad = dm.jit_call(
                         "crush_sweep",
-                        self._jit_key(ruleno, result_max,
-                                      kb is not None,
+                        self._jit_key(ruleno, result_max, kb_kern,
                                       (block, nd, firstn)), step_fn,
                         self.arrays, counts, bad,
-                        jnp.uint32((start_x + i * block) % (1 << 32)),
-                        jnp.int64(n - i * block))
-                    if kb is not None and i == 0:
-                        # force the first block's execution (tiny
-                        # readback; see map_pgs): a kernel that fails
-                        # at run time must fail INSIDE this try. Later
-                        # blocks run the identical program, so only
-                        # the first can reveal a compile/launch fault,
-                        # and the rest still pipeline.
+                        jnp.uint32((start_x + lanes) % (1 << 32)),
+                        jnp.int64(n - lanes))
+                    nblocks += 1
+                    lanes += block
+                    if kb_kern and block != forced:
+                        # force the execution of the first block of
+                        # each width (tiny readback; see map_pgs): a
+                        # kernel that fails at run time must fail
+                        # INSIDE this try. Blocks of one width run the
+                        # identical program, so only the first can
+                        # reveal a compile/launch fault, and the rest
+                        # still pipeline (a narrower program runs last,
+                        # where the caller's read-back follows anyway).
                         np.asarray(counts[0])
+                        forced = block
         except Exception as e:
             if kb is None:
                 raise                        # XLA path: a real error
@@ -1733,7 +1769,8 @@ class Mapper:
                                    _expected=_expected)
         path = self.mapping_path(ruleno, result_max)
         PERF.inc("pgs_mapped", int(n))       # success only (no double
-        PERF.inc("sweep_blocks", int(nblocks))   # count via the retry)
+        PERF.inc("sweep_blocks", nblocks)    # count via the retry)
+        PERF.inc("sweep_lanes", lanes)
         return counts[:nd], bad, self._record_path(path, _expected)
 
     def _sharded_sweep(self, ruleno: int, start_x: int, n: int,
@@ -1825,9 +1862,12 @@ def _compiled_sweep(fn_body, firstn, n_devices, block, result_max):
     CrushTester aggregation, without the (N, rep) device->host ship of
     round 1). The host loops over blocks — dispatches are async, so
     consecutive blocks pipeline and only the final count readback
-    synchronizes. (A fused fori_loop-over-blocks variant compiled to a
-    far larger program at the same speed — not re-measured on a local
-    chip; per-block programs stand.)
+    synchronizes. The step maps ``x0 + arange(block)`` in full and
+    masks the lanes past ``remaining`` only when it counts: kernel,
+    flagged-lane fallback and counting all cost the block's width, not
+    the lanes asked for, so the caller picks ``block`` from the lanes
+    it has left (``Mapper._block_for``). On a v5e the program at 2^21
+    lanes takes 77.7 ms, of it the kernel 59.8 (PERF.md).
 
     counts has n_devices+1 bins: the last collects ITEM_NONE/out-of-range
     lanes and is dropped by the caller."""

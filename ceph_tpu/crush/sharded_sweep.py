@@ -70,17 +70,19 @@ def _mesh_axis(mesh):
     return mesh.axis_names[0]
 
 
-def _quantize_local(local_n: int, block: int) -> int:
-    """Bound the compiled-shape zoo: every distinct per-shard width
-    compiles (and, on the kernel path, caches) its own shard program.
-    In the small-batch regime (one tile per shard) quantize the width
-    up to the next power of two — at most log2(block) distinct shapes,
-    wasting < 2x lanes on batches that are small anyway. Wider sweeps
-    keep their exact width so the 100M-PG bench pays zero padding
-    (their sizes are stable per pool/bench anyway)."""
-    if local_n <= block:
-        return 1 << max(0, local_n - 1).bit_length()
-    return local_n
+def _shard_widths(mapper, ruleno: int, result_max: int,
+                  local_n: int) -> tuple[int, int]:
+    """``(local_n, block)`` of a shard program that maps ``local_n``
+    lanes a shard. Every distinct pair compiles (and, on the kernel
+    path, caches) its own shard program, so a shard of one tile runs
+    it at ``mapper.block_width``'s power of two, as the single-device
+    sweep does: at most log2(cap) shapes, under 2x the lanes on batches
+    that are small anyway. Wider shards keep their exact width and
+    tile it by the rule's widest block, so the 100M-PG sweep pays no
+    padding (its sizes are stable per pool/bench anyway)."""
+    from ceph_tpu.crush.mapper import block_width
+    block = block_width(local_n, mapper.effective_block(ruleno, result_max))
+    return max(local_n, block), block
 
 
 def _fn_body(mapper, ruleno: int, result_max: int):
@@ -157,14 +159,13 @@ def sharded_map_pgs(mesh, mapper, ruleno: int, xs,
         n = xs.shape[0]
         if n == 0:
             return jnp.zeros((0, result_max), dtype=jnp.int32)
-        eff = mapper.effective_block(ruleno, result_max)
-        local_n = _quantize_local(-(-n // ndev), eff)
+        local_n, block = _shard_widths(mapper, ruleno, result_max,
+                                       -(-n // ndev))
         pad = local_n * ndev - n
         if pad:
             xs = jnp.concatenate(
                 [xs, jnp.broadcast_to(xs[0], (pad,))])
         fn_body, used_kernel = _fn_body(mapper, ruleno, result_max)
-        block = min(eff, local_n)
         fn = _shard_fn(mapper, used_kernel, _compiled_sharded_map,
                        fn_body, mesh, block, local_n, result_max)
         from ceph_tpu.utils.devmon import devmon as _devmon
@@ -239,10 +240,9 @@ def sharded_sweep(mesh, mapper, ruleno: int, start_x: int, n: int,
             f"scalar fallback cannot shard — use Mapper.sweep")
     ndev = mesh.devices.size
     nd = mapper.packed.max_devices
-    eff = mapper.effective_block(ruleno, result_max)
-    local_n = _quantize_local(max(1, -(-n // ndev)), eff)
+    local_n, block = _shard_widths(mapper, ruleno, result_max,
+                                   max(1, -(-n // ndev)))
     fn_body, used_kernel = _fn_body(mapper, ruleno, result_max)
-    block = min(eff, local_n)
     fn = _shard_fn(mapper, used_kernel, _compiled_sharded_sweep,
                    fn_body, mapper.rule_is_firstn(ruleno), nd, mesh,
                    block, local_n, result_max)

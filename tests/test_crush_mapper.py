@@ -412,3 +412,203 @@ class TestUniformFastPath:
             ref = mapper_ref.do_rule(m, rid, int(x), 3)
             ref = ref + [ITEM_NONE] * (3 - len(ref))
             assert list(got[i]) == ref, (x,)
+
+
+# ---------------------------------------------------------------------------
+# The width of a block: chosen from the lanes left to map (block_width)
+# ---------------------------------------------------------------------------
+
+_CAP = 1 << 9            # the small explicit cap
+_LOW_FLOOR = 1 << 6      # a floor under it, so 64..512 are all in play
+_WRAP = (1 << 32) - 300  # a start whose range crosses 2^32
+_SIZES = {"1": lambda cap: 1, "255": lambda cap: 255,
+          "256": lambda cap: 256, "257": lambda cap: 257,
+          "cap": lambda cap: cap, "cap+1": lambda cap: cap + 1,
+          "2cap+3": lambda cap: 2 * cap + 3}
+# mode -> (map, Mapper's block, the floor the case runs under)
+_MODES = {
+    # cap under the floor: an explicit block below the floor wins
+    "explicit": (lambda: builder.build_hierarchy(6, 4), _CAP, None),
+    # cap above the floor: every width between the two is a program
+    "lowfloor": (lambda: builder.build_hierarchy(6, 4), _CAP, _LOW_FLOOR),
+    # the block Mapper sizes by itself (a wide bucket keeps it small
+    # enough for the CPU: 2^15 lanes)
+    "auto": (lambda: builder.build_flat(100), None, None),
+}
+_width_cases: dict = {}
+
+
+def _set_floor(monkeypatch, floor):
+    from ceph_tpu.crush import mapper as mapper_mod
+    if floor is not None:
+        monkeypatch.setattr(mapper_mod, "MIN_BLOCK_WIDTH", floor)
+    return mapper_mod.MIN_BLOCK_WIDTH
+
+
+def _width_case(mode, indep, start):
+    """(mapper, rule, numrep, cap, every mapping of the longest range
+    from ``start``), built once a (mode, rule kind, start): the cases of
+    one share its compiled programs. The mappings are ``map_pgs``'s,
+    themselves held to ``mapper_ref.do_rule`` on a sample."""
+    key = (mode, indep, start)
+    if key not in _width_cases:
+        build, block, _floor = _MODES[mode]
+        m, root = build()
+        leaf = builder.TYPE_OSD if mode == "auto" else builder.TYPE_HOST
+        rid = builder.add_simple_rule(m, root, leaf, indep=indep)
+        numrep = 4 if indep else 3
+        mapper = Mapper(m, block=block)
+        cap = mapper.effective_block(rid, numrep)
+        assert cap == mapper.block      # the XLA path: no wider cap
+        xs = ((start + np.arange(2 * cap + 3, dtype=np.uint64))
+              % (1 << 32)).astype(np.uint32)
+        ref = np.asarray(mapper.map_pgs(rid, xs, numrep))
+        sample = sorted({0, 1, 299, 300, 301, cap - 1, cap, 2 * cap,
+                         2 * cap + 2})
+        for i in sample:
+            want = mapper_ref.do_rule(m, rid, int(xs[i]), numrep)
+            want = want + [ITEM_NONE] * (numrep - len(want))
+            assert list(ref[i]) == want, (int(xs[i]), list(ref[i]), want)
+        _width_cases[key] = (mapper, rid, numrep, cap, ref)
+    return _width_cases[key]
+
+
+def _sweep_lanes_want(n, cap, floor):
+    from ceph_tpu.crush.mapper import block_width
+    k, r = divmod(n, cap)
+    return k * cap + (block_width(r, cap, floor) if r else 0), \
+        k + (1 if r else 0)
+
+
+@pytest.mark.parametrize("size", list(_SIZES))
+@pytest.mark.parametrize("indep", [False, True], ids=["firstn", "indep"])
+@pytest.mark.parametrize("mode,start", [
+    ("explicit", 12345), ("explicit", _WRAP),
+    ("lowfloor", 12345), ("lowfloor", _WRAP), ("auto", _WRAP)])
+def test_sweep_blocks_fit_the_lanes(monkeypatch, mode, start, indep, size):
+    """A sweep's (counts, bad) are the bincount of ``map_pgs`` over the
+    same range whatever widths its blocks took, and the lanes it
+    dispatched are the width rule's: under the cap one block of the
+    next power of two (at least the floor), above it whole blocks and a
+    tail block as wide as the remainder needs."""
+    from ceph_tpu.crush.mapper import PERF
+    floor = _set_floor(monkeypatch, _MODES[mode][2])
+    mapper, rid, numrep, cap, ref = _width_case(mode, indep, start)
+    n = _SIZES[size](cap)
+    before = PERF.dump()
+    counts, bad = mapper.sweep(rid, start, n, numrep)
+    counts = np.asarray(counts)
+    after = PERF.dump()
+    live = ref[:n] != ITEM_NONE
+    want = np.bincount(ref[:n][live], minlength=mapper.packed.max_devices)
+    assert np.array_equal(counts, want)
+    assert int(counts.sum()) == int(live.sum())
+    want_bad = 0 if indep else int((live.sum(axis=1) < numrep).sum())
+    assert int(bad) == want_bad
+    lanes = after["sweep_lanes"] - before["sweep_lanes"]
+    want_lanes, want_blocks = _sweep_lanes_want(n, cap, floor)
+    assert lanes == want_lanes
+    assert after["sweep_blocks"] - before["sweep_blocks"] == want_blocks
+    assert after["pgs_mapped"] - before["pgs_mapped"] == n
+    if n < cap:
+        assert n <= lanes < 2 * max(n, floor)
+
+
+def test_sweep_compiles_one_program_a_width(monkeypatch):
+    """Sweeping every n of 1..cap compiles no more programs than the
+    rule has widths between the floor and the cap, and dispatches the
+    width rule's lanes for each."""
+    from ceph_tpu.crush.mapper import PERF, block_width
+    cap, floor = 1 << 7, _set_floor(monkeypatch, 1 << 4)
+    m, root = builder.build_hierarchy(5, 3)
+    rid = builder.add_simple_rule(m, root, builder.TYPE_HOST)
+    mapper = Mapper(m, block=cap)
+    widths = [block_width(n, cap, floor) for n in range(1, cap + 1)]
+    assert set(widths) == {16, 32, 64, 128}
+    before = PERF.dump()
+    for n in range(1, cap + 1):
+        counts, bad = mapper.sweep(rid, 7, n, 2)
+    assert int(np.asarray(counts).sum()) == 2 * cap and int(bad) == 0
+    after = PERF.dump()
+    assert after["sweep_compiles"] - before["sweep_compiles"] \
+        <= len(set(widths))
+    assert after["sweep_lanes"] - before["sweep_lanes"] == sum(widths)
+    assert after["pgs_mapped"] - before["pgs_mapped"] \
+        == cap * (cap + 1) // 2
+
+
+@pytest.mark.parametrize("lanes,cap,floor,want", [
+    (1, 1 << 21, 1 << 16, 1 << 16),
+    ((1 << 16) + 1, 1 << 21, 1 << 16, 1 << 17),
+    (1 << 20, 1 << 21, 1 << 16, 1 << 20),          # crushtool-10k-1m
+    ((1 << 20) + 1, 1 << 21, 1 << 16, 1 << 21),
+    (1 << 21, 1 << 21, 1 << 16, 1 << 21),          # a pod device's share
+    (100_000_000, 1 << 21, 1 << 16, 1 << 21),
+    (300, 1 << 9, 1 << 16, 1 << 9),                # explicit block wins
+    (600, 1000, 1, 1000),                          # never above the cap
+    (np.int64(300), 1 << 14, 1, 1 << 9),
+    (0, 1 << 14, 1, 1), (1, 1 << 14, 1, 1), (2, 1 << 14, 1, 2),
+    (3, 1 << 14, 1, 4)])
+def test_block_width(lanes, cap, floor, want):
+    from ceph_tpu.crush.mapper import block_width
+    assert block_width(lanes, cap, floor) == want
+
+
+def test_kernel_path_has_six_widths():
+    """The floor and the kernel path's cap bound what one Mapper can
+    compile for sweeps: one program a power of two from 2^16 to 2^21."""
+    from ceph_tpu.crush.mapper import MIN_BLOCK_WIDTH, block_width
+    widths = {block_width(n, 1 << 21, MIN_BLOCK_WIDTH)
+              for e in range(23) for n in ((1 << e) - 1, 1 << e,
+                                           (1 << e) + 1)}
+    assert sorted(widths) == [1 << e for e in range(16, 22)]
+
+
+def test_sweep_kernel_path_narrow_block(monkeypatch):
+    """The fused kernel (interpret mode) under the same rule: a sweep
+    of 257 lanes runs one 512-lane block, not the kernel path's 2^21,
+    and counts what the XLA path counts."""
+    from ceph_tpu.crush.mapper import PERF
+    _set_floor(monkeypatch, _LOW_FLOOR)
+    m, root = builder.build_hierarchy(6, 4)
+    rid = builder.add_simple_rule(m, root, builder.TYPE_HOST)
+    monkeypatch.setenv("CEPH_TPU_CRUSH_KERNEL", "interpret")
+    mk = Mapper(m, block=_CAP)
+    assert mk._kernel_body(rid, 3) is not None
+    assert mk.effective_block(rid, 3) == 1 << 21
+    mx, _rid, _numrep, _cap, ref = _width_case("lowfloor", False, 12345)
+    before = PERF.dump()
+    counts, bad, path = mk.sweep_path(rid, 12345, 257, 3)
+    after = PERF.dump()
+    assert path == "pallas-interpret"
+    assert after["sweep_lanes"] - before["sweep_lanes"] == 512
+    assert after["sweep_blocks"] - before["sweep_blocks"] == 1
+    assert np.array_equal(
+        np.asarray(counts),
+        np.bincount(ref[:257].reshape(-1), minlength=m.max_devices))
+    assert int(bad) == 0
+
+
+def test_map_pgs_tail_block_is_as_wide_as_its_lanes(monkeypatch):
+    """``map_pgs`` past the cap pads its tail block to the width the
+    remainder needs and returns exactly the rows asked for."""
+    _set_floor(monkeypatch, _LOW_FLOOR)
+    mapper, rid, numrep, cap, ref = _width_case("lowfloor", False, 12345)
+    from ceph_tpu.utils.devmon import devmon
+    xs = ((12345 + np.arange(2 * cap + 3, dtype=np.uint64))
+          % (1 << 32)).astype(np.uint32)
+    seen = []
+    real = devmon().jit_call
+
+    def spy(name, key, fn, *args):
+        seen.append((name, key[-1], args[-1].shape[0]))
+        return real(name, key, fn, *args)
+
+    monkeypatch.setattr(devmon(), "jit_call", spy)
+    for n, widths in ((cap + 1, [cap, 64]), (cap + 65, [cap, 128]),
+                      (2 * cap, [cap, cap]), (2 * cap + 3, [cap, cap, 64])):
+        seen.clear()
+        got = np.asarray(mapper.map_pgs(rid, xs[:n], numrep))
+        assert got.shape == (n, numrep) and np.array_equal(got, ref[:n])
+        assert [s[2] for s in seen] == widths
+        assert [s[1] for s in seen] == widths     # the jit key's width

@@ -99,7 +99,6 @@ class TestMapperLifecycleCounters:
         assert flipped["reweight_recompiles"] == \
             after_same["reweight_recompiles"] + 1
 
-    @pytest.mark.slow
     def test_sweep_counters(self):
         import numpy as np
         from ceph_tpu.crush import builder
@@ -113,4 +112,9 @@ class TestMapperLifecycleCounters:
         mapper.sweep(0, 0, 256, 3)
         after = PERF.dump()
         assert after["pgs_mapped"] == before["pgs_mapped"] + 256
-        assert after["sweep_blocks"] >= before["sweep_blocks"] + 1
+        assert after["sweep_blocks"] == before["sweep_blocks"] + 1
+        # one block, as wide as 256 lanes need and no narrower than the
+        # floor: the fill share is pgs_mapped over sweep_lanes
+        from ceph_tpu.crush.mapper import MIN_BLOCK_WIDTH
+        assert after["sweep_lanes"] - before["sweep_lanes"] == \
+            min(mapper.block, MIN_BLOCK_WIDTH)

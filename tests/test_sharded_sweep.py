@@ -176,6 +176,51 @@ class TestBitExact:
             sharded_sweep(mesh, mp, rid, 0, 64, 3)
 
 
+class TestShardWidths:
+    """The per-shard (local_n, block) are ``mapper.block_width``'s now,
+    the rule the single-device sweep shares; the widths themselves are
+    what ``_quantize_local`` and ``min(eff, local_n)`` gave."""
+
+    @pytest.mark.parametrize("n,ndev,cap,want", [
+        (N, 8, 1 << 10, (128, 128)),       # 97 a shard
+        (757, 8, 1 << 10, (128, 128)),     # 95
+        (300, 8, 1 << 10, (64, 64)),       # 38
+        (300 + 757, 8, 1 << 10, (256, 256)),
+        (203, 8, 1 << 10, (32, 32)),       # 26
+        (130, 8, 1 << 8, (32, 32)),        # 17
+        (257, 8, 1 << 9, (64, 64)),        # 33
+        (1, 8, 1 << 10, (1, 1)),
+        (8 * 1024, 8, 1 << 10, (1024, 1024)),
+        (8 * 3000, 8, 1 << 10, (3000, 1024)),   # wide shards: exact
+        (1 << 23, 4, 1 << 21, (1 << 21, 1 << 21)),  # crush-pod-sweep-8m
+        (100_000_000, 8, 1 << 21, (12_500_000, 1 << 21)),
+    ])
+    def test_widths_are_what_they_were(self, n, ndev, cap, want):
+        from ceph_tpu.crush.sharded_sweep import _shard_widths
+        m, rid = _hier(4, 2)
+        mp = Mapper(m, block=cap)
+        assert mp.effective_block(rid, 3) == cap
+        local = max(1, -(-n // ndev))
+        assert _shard_widths(mp, rid, 3, local) == want
+        # the arithmetic that stood in sharded_sweep before the move
+        old = 1 << max(0, local - 1).bit_length() if local <= cap \
+            else local
+        assert want == (old, min(cap, old))
+
+    def test_effective_block_is_the_cap(self, monkeypatch):
+        """Narrower blocks for short sweeps leave ``effective_block``
+        the widest one: the explicit or auto block on the XLA path, at
+        least 2^21 on the kernel path."""
+        m, rid = _hier(4, 2)
+        assert Mapper(m, block=1 << 10).effective_block(rid, 3) == 1 << 10
+        auto = Mapper(m)
+        assert auto.effective_block(rid, 3) == auto.block >= 1 << 14
+        monkeypatch.setenv("CEPH_TPU_CRUSH_KERNEL", "interpret")
+        mk = Mapper(m, block=1 << 10)
+        assert mk._kernel_body(rid, 3) is not None
+        assert mk.effective_block(rid, 3) == 1 << 21
+
+
 class TestKernelPath:
     """The fused kernel (interpret mode) through the sharded path —
     including lanes the kernel flags to its bit-exact XLA fallback."""
