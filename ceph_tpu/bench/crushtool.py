@@ -134,16 +134,18 @@ def main(argv=None) -> dict:
         if args.show_utilization:
             for dev, c in enumerate(res.device_counts):
                 print(f"  device {dev}:\t\t stored : {int(c)}")
-        if args.show_bad_mappings and res.bad_mappings:
-            print(f"bad mappings: {res.bad_mappings}")
+        if args.show_bad_mappings:
+            for line in tester.bad_mapping_lines(res):
+                print(line)
         if args.show_statistics:
             print(f"total mappings {res.total_x} in {res.seconds:.4f}s "
-                  f"({res.mappings_per_second:,.0f}/s)")
+                  f"({res.mappings_per_second:,.0f}/s) on path {res.path}")
         out.update({
             "rule": args.rule, "num_rep": args.num_rep,
             "total_x": res.total_x, "seconds": res.seconds,
             "mappings_per_second": res.mappings_per_second,
             "bad_mappings": res.bad_mappings,
+            "mapping_path": res.path,
             "utilization": res.utilization_summary(),
         })
     if args.json:

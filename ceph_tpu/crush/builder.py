@@ -10,7 +10,8 @@ from __future__ import annotations
 from ceph_tpu.crush.types import (
     ALG_STRAW, ALG_STRAW2, ALG_TREE,
     OP_CHOOSELEAF_FIRSTN, OP_CHOOSELEAF_INDEP, OP_CHOOSE_FIRSTN,
-    OP_CHOOSE_INDEP, OP_EMIT, OP_TAKE, WEIGHT_ONE,
+    OP_CHOOSE_INDEP, OP_EMIT, OP_SET_CHOOSELEAF_TRIES, OP_SET_CHOOSE_TRIES,
+    OP_TAKE, WEIGHT_ONE,
     Bucket, CrushMap, Rule, RuleStep, Tunables,
 )
 
@@ -253,18 +254,22 @@ def _adjust_ancestors(map_: CrushMap, bucket_id: int, delta: int) -> None:
 def add_simple_rule(map_: CrushMap, root: int, failure_domain_type: int,
                     name: str = "", rule_id: int | None = None,
                     indep: bool = False) -> int:
-    """take root; chooseleaf firstn|indep 0 type <fd>; emit
+    """take root; chooseleaf firstn|indep 0 type <fd>; emit, an indep
+    rule opening with ``set_chooseleaf_tries 5`` and ``set_choose_tries
+    100`` -- the rule ``ceph osd crush rule create-erasure`` makes
     (ref: src/crush/CrushWrapper.cc add_simple_rule_at)."""
     rid = rule_id if rule_id is not None else len(map_.rules)
     op = OP_CHOOSELEAF_INDEP if indep else OP_CHOOSELEAF_FIRSTN
     if failure_domain_type == TYPE_OSD:
         op = OP_CHOOSE_INDEP if indep else OP_CHOOSE_FIRSTN
-    rule = Rule(id=rid, name=name or f"rule{rid}",
-                type=3 if indep else 1,
-                steps=[RuleStep(OP_TAKE, root),
-                       RuleStep(op, 0, failure_domain_type),
-                       RuleStep(OP_EMIT)])
-    map_.rules[rid] = rule
+    steps = [RuleStep(OP_TAKE, root),
+             RuleStep(op, 0, failure_domain_type),
+             RuleStep(OP_EMIT)]
+    if indep:
+        steps[:0] = [RuleStep(OP_SET_CHOOSELEAF_TRIES, 5),
+                     RuleStep(OP_SET_CHOOSE_TRIES, 100)]
+    map_.rules[rid] = Rule(id=rid, name=name or f"rule{rid}",
+                           type=3 if indep else 1, steps=steps)
     return rid
 
 

@@ -133,6 +133,7 @@ _MAPPER_TOKEN = _itertools.count(1)
 # skip_is_out jit key — this makes pack/compile traffic observable via
 # `perf dump` instead of guessed. Registered process-wide like a
 # daemon's counters (ref: the role of src/common/perf_counters.h).
+from ceph_tpu.utils import tracing
 from ceph_tpu.utils.devmon import devmon as _devmon
 from ceph_tpu.utils.perf_counters import PerfCountersBuilder as _PCB
 
@@ -162,6 +163,19 @@ PERF = (_PCB("crush_mapper")
                          "lanes dispatched by sweep: the sum of its "
                          "blocks' widths (pgs_mapped over it is the "
                          "fill share)")
+        .add_u64_counter("indep_blocks",
+                         "choose_indep blocks a sweep ran on the rule VM")
+        .add_u64_counter("indep_rounds",
+                         "rounds those blocks ran: the sum of their "
+                         "final ftotal (a round is every position's "
+                         "descent at the block's full width)")
+        .add_u64_counter("indep_lane_rounds_needed",
+                         "lanes that still had a position to fill, "
+                         "summed over those rounds (over indep_rounds x "
+                         "the block's width it is the share of the "
+                         "lane-rounds that had anything to place)")
+        .add_u64_counter("indep_holes",
+                         "positions an indep sweep emitted as ITEM_NONE")
         .create_perf_counters())
 
 
@@ -775,14 +789,24 @@ def _leaf_choose_indep(arrs, cfg, item, item_ok, x, parent_r, rep, numrep,
 def _choose_indep_block(arrs, cfg, root_rows, root_valid, x, out_size,
                         numrep, target_type, recurse_to_leaf, tries,
                         recurse_tries, pos_base: int = 0):
-    """ref: mapper.c crush_choose_indep — position-stable EC placement."""
+    """ref: mapper.c crush_choose_indep — position-stable EC placement.
+
+    A round runs every position's descent at the block's full width and
+    the block goes round again while any lane has a position unfilled,
+    so its cost is its unluckiest lane's. Returns (out, leaves, rounds,
+    needed): ``rounds`` the final ftotal, ``needed`` the lanes that
+    still had a position to fill summed over the rounds -- the one
+    reduce a round, which is also what ends the loop."""
     n = x.shape[0]
     out0 = jnp.full((n, out_size), ITEM_NONE - 1, dtype=jnp.int32)  # UNDEF
     leaves0 = jnp.full((n, out_size), ITEM_NONE - 1, dtype=jnp.int32)
     UNDEF = ITEM_NONE - 1
 
+    def unfilled(out):
+        return jnp.any(out == UNDEF, axis=1).sum(dtype=jnp.int32)
+
     def cond(c):
-        return (c["ftotal"] < tries) & jnp.any(c["out"] == UNDEF)
+        return (c["ftotal"] < tries) & (c["left"] > 0)
 
     def body(c):
         out, leaves = c["out"], c["leaves"]
@@ -814,14 +838,16 @@ def _choose_indep_block(arrs, cfg, root_rows, root_valid, x, out_size,
             out = out.at[:, rep].set(jnp.where(place, item, out[:, rep]))
             leaves = leaves.at[:, rep].set(
                 jnp.where(place, leaf, leaves[:, rep]))
-        return {"out": out, "leaves": leaves, "ftotal": ftotal + 1}
+        return {"out": out, "leaves": leaves, "ftotal": ftotal + 1,
+                "left": unfilled(out), "needed": c["needed"] + c["left"]}
 
     res = lax.while_loop(cond, body,
                          {"out": out0, "leaves": leaves0,
-                          "ftotal": jnp.int32(0)})
+                          "ftotal": jnp.int32(0), "left": unfilled(out0),
+                          "needed": jnp.int32(0)})
     out = jnp.where(res["out"] == UNDEF, ITEM_NONE, res["out"])
     leaves = jnp.where(res["leaves"] == UNDEF, ITEM_NONE, res["leaves"])
-    return out, leaves
+    return out, leaves, res["ftotal"], res["needed"]
 
 
 def _compact(w):
@@ -1709,8 +1735,7 @@ class Mapper:
                          ).astype(np.uint32), result_max)
             live = out != ITEM_NONE
             counts = np.bincount(out[live], minlength=nd_)[:nd_]
-            bad = int((live.sum(axis=1) < result_max).sum()) \
-                if self.rule_is_firstn(ruleno) else 0
+            bad = int((live.sum(axis=1) < result_max).sum())
             return (np.asarray(counts, dtype=np.int64), np.int64(bad),
                     self._record_path("scalar", _expected))
         if _expected is None:
@@ -1722,23 +1747,29 @@ class Mapper:
             path = self.mapping_path(ruleno, result_max) + "+sharded"
             return counts, bad, self._record_path(path, _expected)
         kb = self._kernel_body(ruleno, result_max)
-        fn_body = kb or _rule_body(*self._rule_key(ruleno, result_max))
         firstn = self.rule_is_firstn(ruleno)
+        # an indep rule on the rule VM tallies what its blocks did
+        indep = kb is None and not firstn
+        fn_body = kb or _rule_body(*self._rule_key(ruleno, result_max),
+                                   indep_stats=indep)
         nd = device_counts_size or self.packed.max_devices
         kb_kern = kb is not None
         dm = _devmon()
-        nblocks = lanes = 0
+        nblocks = lanes = width = 0
         forced = None
+        sec = tracing.section("crush.indep_block", service="crush") \
+            if indep else None
         try:
             with jax.enable_x64(True):
                 counts = jnp.zeros(nd + 1, dtype=jnp.int64)
-                bad = jnp.int64(0)
+                bad = jnp.zeros(1 + len(INDEP_TALLY), dtype=jnp.int64) \
+                    if indep else jnp.int64(0)
                 while lanes < n:
                     # every block is as wide as the lanes left need:
                     # a sweep under the cap is one block of its own
                     # width, a longer one ends in a narrower tail block
                     block = self._block_for(kb_kern, n - lanes)
-                    step_fn = _compiled_sweep(fn_body, firstn, nd, block,
+                    step_fn = _compiled_sweep(fn_body, indep, nd, block,
                                               result_max)
                     counts, bad = dm.jit_call(
                         "crush_sweep",
@@ -1749,6 +1780,7 @@ class Mapper:
                         jnp.int64(n - lanes))
                     nblocks += 1
                     lanes += block
+                    width = max(width, block)
                     if kb_kern and block != forced:
                         # force the execution of the first block of
                         # each width (tiny readback; see map_pgs): a
@@ -1767,6 +1799,14 @@ class Mapper:
             return self.sweep_path(ruleno, start_x, n, result_max,
                                    device_counts_size,
                                    _expected=_expected)
+        if indep:
+            # the tally comes back with the counts' read-back: one read
+            counts, bad = jax.device_get((counts, bad))
+            for name, v in zip(INDEP_TALLY, bad[1:]):
+                PERF.inc(name, int(v))
+            bad = bad[0]
+            sec.tag("lanes", int(n)).tag("width", width)
+            sec.finish()
         path = self.mapping_path(ruleno, result_max)
         PERF.inc("pgs_mapped", int(n))       # success only (no double
         PERF.inc("sweep_blocks", nblocks)    # count via the retry)
@@ -1814,6 +1854,9 @@ def _compiled_rule(steps, result_max, tkey, max_depth, present,
 _COUNT_CHUNK = 1 << 13
 _COUNT_SHIFT = 7
 _COUNT_LANES = 1 << _COUNT_SHIFT
+# columns of a (block, rmax) result counted in one pass: a wider result
+# is counted eight columns at a time (see _count_placements)
+_COUNT_COLS = 8
 
 
 def _count_placements(flat, nbins):
@@ -1827,7 +1870,14 @@ def _count_placements(flat, nbins):
     serialises on colliding ids: 522-607 ms into int64 bins (88% of a
     sweep), 42-49 ms into int32; sort-and-difference 12.5 ms (PERF.md).
 
-    ``flat`` may have any shape of fewer than 2^31 elements."""
+    ``flat`` may have any shape of fewer than 2^31 elements. A 2-D
+    ``flat`` wider than ``_COUNT_COLS`` is counted that many columns at
+    a time: on a v5e one pass over a (2^20, 10) or (2^20, 11) block lost
+    128 ids, one vector row, where (2^20, 8) and every narrower block
+    lose none (PERF.md, PR 33); three columns lower as they did."""
+    if flat.ndim == 2 and flat.shape[1] > _COUNT_COLS:
+        return sum(_count_placements(flat[:, lo:lo + _COUNT_COLS], nbins)
+                   for lo in range(0, flat.shape[1], _COUNT_COLS))
     # column-major: a (block, rmax) result lives lane-major on the TPU,
     # where a row-major flatten first pads rmax to 128 lanes
     ids = flat.T.reshape(-1)
@@ -1853,8 +1903,14 @@ def _count_placements(flat, nbins):
     return acc.reshape(-1)[:nbins]
 
 
+# what an indep sweep's ``bad`` vector carries after the bad mappings,
+# under the names ``PERF`` counts them by
+INDEP_TALLY = ("indep_blocks", "indep_rounds", "indep_lane_rounds_needed",
+               "indep_holes")
+
+
 @functools.lru_cache(maxsize=256)
-def _compiled_sweep(fn_body, firstn, n_devices, block, result_max):
+def _compiled_sweep(fn_body, indep_stats, n_devices, block, result_max):
     """Per-block aggregated sweep step: map one x block, count its
     placements per device on device (``_count_placements``: no scatter,
     the colliding scatter-add that stood here took 88% of a v5e's time)
@@ -1870,21 +1926,31 @@ def _compiled_sweep(fn_body, firstn, n_devices, block, result_max):
     lanes takes 77.7 ms, of it the kernel 59.8 (PERF.md).
 
     counts has n_devices+1 bins: the last collects ITEM_NONE/out-of-range
-    lanes and is dropped by the caller."""
+    lanes and is dropped by the caller. With ``indep_stats`` the body is
+    ``_rule_body(..., indep_stats=True)`` and ``bad`` an int64 vector:
+    the bad mappings, then ``INDEP_TALLY``'s counters."""
     PERF.inc("sweep_compiles")           # body runs only on an lru miss
 
     def run(arrs, counts, bad, x0, remaining):
         xs = x0 + jnp.arange(block, dtype=jnp.uint32)
         inb = jnp.arange(block, dtype=jnp.int64) < remaining
         w = fn_body(arrs, xs)                         # (block, rmax) int32
+        if indep_stats:
+            w, stats = w
         live = w != ITEM_NONE
         flat = jnp.where(live & inb[:, None], w, n_devices)
         counts = counts + _count_placements(
             flat, n_devices + 1).astype(jnp.int64)
-        if firstn:
-            short = (live.sum(axis=1) < result_max) & inb
-            bad = bad + short.sum(dtype=jnp.int64)
-        return counts, bad
+        # a bad mapping is upstream's: fewer than result_max entries
+        # or an ITEM_NONE among them (an indep rule's hole)
+        short = (live.sum(axis=1) < result_max) & inb
+        if not indep_stats:
+            return counts, bad + short.sum(dtype=jnp.int64)
+        # ``bad`` is the indep tally: bad mappings, then INDEP_TALLY
+        holes = (~live & inb[:, None]).sum(dtype=jnp.int64)
+        return counts, bad + jnp.concatenate([
+            short.sum(dtype=jnp.int64)[None], stats.astype(jnp.int64),
+            holes[None]])
 
     return jax.jit(run, donate_argnums=(1,))
 
@@ -1903,7 +1969,11 @@ def _depth_between(type_depth, from_type, to_type):
 
 @functools.lru_cache(maxsize=256)
 def _rule_body(steps, result_max, tkey, max_depth, present, type_depth=(),
-               tree_depth=0, flags=(False, False)):
+               tree_depth=0, flags=(False, False), indep_stats=False):
+    """The rule VM: ``run(arrs, xs) -> (n, result_max)`` mappings.
+    With ``indep_stats`` it returns ``(mappings, stats)``, stats the
+    int32 triple (choose_indep blocks run, their rounds, their needed
+    lane-rounds) that the sweep step tallies (``_choose_indep_block``)."""
     total_tries, descend_once, vary_r, stable = tkey
     base_cfg = {"max_depth": max_depth, "present": present,
                 "tree_depth": tree_depth,
@@ -1919,6 +1989,7 @@ def _rule_body(steps, result_max, tkey, max_depth, present, type_depth=(),
         w_cols: list = []
         emitted: list = []
         any_firstn = False
+        stats = jnp.zeros(3, dtype=jnp.int32)
         cur_type = None   # static type of the current columns' items
         for step in steps:
             op, arg1, arg2 = step[0], step[1], step[2]
@@ -1974,10 +2045,12 @@ def _rule_body(steps, result_max, tkey, max_depth, present, type_depth=(),
                             arg2, recurse, choose_tries, recurse_tries, vr)
                     else:
                         blk = min(numrep, result_max - osize)
-                        out, leaves = _choose_indep_block(
+                        out, leaves, rounds, needed = _choose_indep_block(
                             arrs, cfg, root_rows, root_valid, xs, blk,
                             numrep, arg2, recurse, choose_tries,
                             recurse_tries)
+                        stats = stats + jnp.stack(
+                            [jnp.int32(1), rounds, needed])
                     chosen = leaves if recurse else out
                     # Device roots with matching type pass through.
                     if arg2 == 0:
@@ -2008,6 +2081,7 @@ def _rule_body(steps, result_max, tkey, max_depth, present, type_depth=(),
             pad = jnp.full((n, result_max - w.shape[1]), ITEM_NONE,
                            dtype=jnp.int32)
             w = jnp.concatenate([w, pad], axis=1)
-        return w[:, :result_max]
+        w = w[:, :result_max]
+        return (w, stats) if indep_stats else w
 
     return run

@@ -286,9 +286,10 @@ def choose_indep(map_: CrushMap, bucket: Bucket, weight: list[int], x: int,
                  left: int, numrep: int, type_: int, out: list, outpos: int,
                  tries: int, recurse_tries: int, recurse_to_leaf: bool,
                  out2: list | None, parent_r: int,
-                 choose_args: dict | None = None) -> None:
+                 choose_args: dict | None = None) -> int:
     """ref: mapper.c crush_choose_indep. Fills out[outpos:outpos+left] with
-    items (position-stable; failures become ITEM_NONE for EC shards)."""
+    items (position-stable; failures become ITEM_NONE for EC shards).
+    Returns the rounds it ran (the final ftotal)."""
     endpos = outpos + left
     for rep in range(outpos, endpos):
         out[rep] = ITEM_UNDEF
@@ -345,6 +346,7 @@ def choose_indep(map_: CrushMap, bucket: Bucket, weight: list[int], x: int,
             out[rep] = ITEM_NONE
         if out2 is not None and out2[rep] == ITEM_UNDEF:
             out2[rep] = ITEM_NONE
+    return ftotal
 
 
 # ---------------------------------------------------------------------------
@@ -353,10 +355,13 @@ def choose_indep(map_: CrushMap, bucket: Bucket, weight: list[int], x: int,
 
 def do_rule(map_: CrushMap, ruleno: int, x: int, result_max: int,
             weight: list[int] | None = None,
-            choose_args: dict | None = None) -> list[int]:
+            choose_args: dict | None = None,
+            indep_rounds: list[int] | None = None) -> list[int]:
     """Execute rule `ruleno` for input x (ref: mapper.c crush_do_rule).
 
     weight: per-device 16.16 reweights for is_out; default all-in.
+    indep_rounds: a list that takes the rounds of every choose_indep
+    call a step made (what the vectorized mapper's indep counters sum).
     Returns the device list (may contain ITEM_NONE for indep rules).
     """
     if weight is None:
@@ -440,11 +445,13 @@ def do_rule(map_: CrushMap, ruleno: int, x: int, result_max: int,
                     out_size = min(numrep, result_max - osize)
                     block = [ITEM_NONE] * out_size
                     block2 = [ITEM_NONE] * out_size
-                    choose_indep(
+                    rounds = choose_indep(
                         map_, bucket, weight, x, out_size, numrep,
                         step.arg2, block, 0, choose_tries,
                         choose_leaf_tries if choose_leaf_tries else 1,
                         recurse_to_leaf, block2, 0, choose_args)
+                    if indep_rounds is not None:
+                        indep_rounds.append(rounds)
                     o.extend(block)
                     c.extend(block2)
                     osize += out_size

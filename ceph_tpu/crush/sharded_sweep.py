@@ -180,8 +180,7 @@ def sharded_map_pgs(mesh, mapper, ruleno: int, xs,
 
 
 @functools.lru_cache(maxsize=64)
-def _compiled_sharded_sweep(fn_body, firstn, nd, mesh, block, local_n,
-                            result_max):
+def _compiled_sharded_sweep(fn_body, nd, mesh, block, local_n, result_max):
     """shard_map'd aggregated sweep step: per-shard iota + local
     counts through the single-device step's ``_count_placements`` (no
     scatter: the colliding scatter-add that stood here took 752 of a
@@ -212,9 +211,9 @@ def _compiled_sharded_sweep(fn_body, firstn, nd, mesh, block, local_n,
             flat = jnp.where(live, w, nd)
             counts = counts + _count_placements(
                 flat, nd + 1).astype(jnp.int64)
-            if firstn:
-                short = (live.sum(axis=1) < result_max) & inb
-                bad = bad + short.sum(dtype=jnp.int64)
+            # upstream's bad mapping: short, or an indep rule's hole
+            short = (live.sum(axis=1) < result_max) & inb
+            bad = bad + short.sum(dtype=jnp.int64)
         return (jax.lax.psum(counts[:nd], axis),
                 jax.lax.psum(bad, axis))
 
@@ -244,8 +243,7 @@ def sharded_sweep(mesh, mapper, ruleno: int, start_x: int, n: int,
                                    max(1, -(-n // ndev)))
     fn_body, used_kernel = _fn_body(mapper, ruleno, result_max)
     fn = _shard_fn(mapper, used_kernel, _compiled_sharded_sweep,
-                   fn_body, mapper.rule_is_firstn(ruleno), nd, mesh,
-                   block, local_n, result_max)
+                   fn_body, nd, mesh, block, local_n, result_max)
     from ceph_tpu.utils.devmon import devmon as _devmon
     with jax.enable_x64(True):
         out = _devmon().jit_call(

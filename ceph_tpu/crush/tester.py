@@ -26,9 +26,12 @@ class TestResult:
     num_rep: int
     total_x: int
     device_counts: np.ndarray          # (max_devices,) placements per device
-    bad_mappings: int                  # x's with < num_rep distinct devices
+    bad_mappings: int                  # x's with < num_rep devices or a hole
     seconds: float
     mappings: np.ndarray | None = None  # (N, num_rep) if requested
+    path: str = ""                     # the engine that served the sweep
+    min_x: int = 0
+    indep: bool = False                # the rule's results keep holes
 
     @property
     def mappings_per_second(self) -> float:
@@ -49,6 +52,13 @@ class TestResult:
         }
 
 
+def _bad_rows(mappings: np.ndarray) -> np.ndarray:
+    """(N,) bool: the result has fewer than num_rep entries or holds a
+    CRUSH_ITEM_NONE. A mapped block is ITEM_NONE-filled to num_rep
+    columns, so both are one test."""
+    return (mappings == ITEM_NONE).any(axis=1)
+
+
 class CrushTester:
     """ref: src/crush/CrushTester.h CrushTester."""
 
@@ -66,7 +76,7 @@ class CrushTester:
         self.perf = existing or (
             PerfCountersBuilder("crush_tester")
             .add_u64_counter("mappings", "PGs mapped")
-            .add_u64_counter("bad_mappings", "short firstn results")
+            .add_u64_counter("bad_mappings", "short or holed results")
             .add_time("map_seconds", "time in test sweeps")
             .create_perf_counters())
 
@@ -79,26 +89,23 @@ class CrushTester:
         the (max_devices,) count vector is read back — round 1 shipped
         every (N, rep) mapping block to the host and bincounted there.
 
-        Bad mappings follow CrushTester's meaning (result size < num_rep):
-        counted for firstn rules only — indep/EC rules emit ITEM_NONE
-        holes as *expected* degraded output (ref: src/crush/CrushTester.cc
-        CrushTester::test size check on do_rule's result vector).
+        A mapping is bad as upstream's CrushTester::test reports it:
+        fewer than num_rep entries (a short firstn result) or any
+        CRUSH_ITEM_NONE (an indep rule's hole), so ``crushtool --test
+        --show-bad-mappings`` says of an EC rule what it says upstream.
         """
         n = max_x - min_x + 1
         t0 = time.perf_counter()
         if keep_mappings:
-            out = np.asarray(self.mapper.map_pgs(
-                rule, np.arange(min_x, max_x + 1, dtype=np.uint32), num_rep))
-            valid = out != ITEM_NONE
-            counts = np.bincount(out[valid],
+            kept, path = self.mapper.map_pgs_path(
+                rule, np.arange(min_x, max_x + 1, dtype=np.uint32), num_rep)
+            kept = np.asarray(kept)
+            counts = np.bincount(kept[kept != ITEM_NONE],
                                  minlength=self.map.max_devices)
-            if self.mapper.rule_is_firstn(rule):
-                bad = int((valid.sum(axis=1) < num_rep).sum())
-            else:
-                bad = 0
-            kept = out
+            bad = int(_bad_rows(kept).sum())
         else:
-            counts_dev, bad_dev = self.mapper.sweep(rule, min_x, n, num_rep)
+            counts_dev, bad_dev, path = self.mapper.sweep_path(
+                rule, min_x, n, num_rep)
             counts = np.asarray(counts_dev)     # readback = execution anchor
             bad = int(bad_dev)
             kept = None
@@ -109,7 +116,30 @@ class CrushTester:
         res = TestResult(
             rule=rule, num_rep=num_rep, total_x=n,
             device_counts=counts, bad_mappings=bad, seconds=seconds,
-            mappings=kept)
+            mappings=kept, path=path, min_x=min_x,
+            indep=not self.mapper.rule_is_firstn(rule))
         log.dout(5, "test done", rule=rule, num_rep=num_rep, n=n,
                  secs=round(seconds, 3))
         return res
+
+    def bad_mapping_lines(self, res: TestResult) -> list[str]:
+        """``--show-bad-mappings``' line for every bad mapping of
+        ``res``: ``bad mapping rule R x X num_rep N result [...]``, an
+        indep rule's holes as CRUSH_ITEM_NONE (2147483647), a firstn
+        rule's short result as the devices it got (ref: CrushTester.cc
+        CrushTester::test). A sweep keeps no mapping, so where it
+        counted a bad one the range is mapped once more, keeping them."""
+        if not res.bad_mappings:
+            return []
+        if res.mappings is None:
+            res = self.test(res.rule, res.num_rep, res.min_x,
+                            res.min_x + res.total_x - 1, keep_mappings=True)
+        lines = []
+        for i in np.nonzero(_bad_rows(res.mappings))[0]:
+            row = [int(d) for d in res.mappings[i]
+                   if res.indep or d != ITEM_NONE]
+            lines.append(
+                f"bad mapping rule {res.rule} x {res.min_x + int(i)} "
+                f"num_rep {res.num_rep} result "
+                f"[{','.join(map(str, row))}]")
+        return lines

@@ -68,6 +68,18 @@ def test_odd_shapes_and_out_of_range_ids():
     assert np.array_equal(got, np.bincount(keep, minlength=257))
 
 
+@pytest.mark.parametrize("cols", [8, 9, 11, 16, 20])
+def test_a_wide_result_counts_every_column(cols):
+    """An 11-wide EC result is counted eight columns at a time (one
+    pass lost a vector row on the chip): every column still counts."""
+    rng = np.random.default_rng(cols)
+    ids = rng.integers(-1, 600, size=(4096, cols), dtype=np.int32)
+    got = np.asarray(_count_placements(jnp.asarray(ids), 513))
+    keep = ids[(ids >= 0) & (ids < 513)]
+    assert np.array_equal(got, np.bincount(keep, minlength=513))
+    assert got.sum() == len(keep)
+
+
 @pytest.fixture(scope="module")
 def swept():
     m, root = builder.build_hierarchy(8, 4, n_racks=2)
@@ -85,7 +97,7 @@ def test_sweep_step_holds_no_scatter_add(swept):
     mp, rid = swept
     fn_body, _ = _fn_body(mp, rid, RMAX)
     nd = mp.packed.max_devices
-    step = _compiled_sweep(fn_body, True, nd, mp.block, RMAX)
+    step = _compiled_sweep(fn_body, False, nd, mp.block, RMAX)
     with jax.enable_x64(True):
         jaxpr = jax.make_jaxpr(step)(
             mp.arrays, jnp.zeros(nd + 1, dtype=jnp.int64), jnp.int64(0),
@@ -97,7 +109,7 @@ def test_sharded_step_holds_no_scatter_add(swept):
     mp, rid = swept
     fn_body, _ = _fn_body(mp, rid, RMAX)
     step = _compiled_sharded_sweep(
-        fn_body, True, mp.packed.max_devices, local_mesh(), mp.block,
+        fn_body, mp.packed.max_devices, local_mesh(), mp.block,
         mp.block, RMAX)
     with jax.enable_x64(True):
         jaxpr = jax.make_jaxpr(step)(mp.arrays, jnp.uint32(0),
