@@ -168,12 +168,24 @@ PERF = (_PCB("crush_mapper")
         .add_u64_counter("indep_rounds",
                          "rounds those blocks ran: the sum of their "
                          "final ftotal (a round is every position's "
-                         "descent at the block's full width)")
+                         "descent, at the block's full width or, once "
+                         "it narrowed, at an eighth or a 128th of it)")
         .add_u64_counter("indep_lane_rounds_needed",
                          "lanes that still had a position to fill, "
                          "summed over those rounds (over indep_rounds x "
                          "the block's width it is the share of the "
-                         "lane-rounds that had anything to place)")
+                         "rule's lane-rounds that had anything to "
+                         "place; over indep_lane_rounds_run the share "
+                         "of the lane-rounds the device ran)")
+        .add_u64_counter("indep_lane_rounds_run",
+                         "the width of every round those blocks ran, "
+                         "summed: the block's width for a full-width "
+                         "round, an eighth or a 128th of it for a "
+                         "narrow one")
+        .add_u64_counter("indep_blocks_narrowed",
+                         "blocks that gathered their unfilled lanes "
+                         "into a block an eighth as wide and went on "
+                         "with their rounds there")
         .add_u64_counter("indep_holes",
                          "positions an indep sweep emitted as ITEM_NONE")
         .create_perf_counters())
@@ -789,65 +801,135 @@ def _leaf_choose_indep(arrs, cfg, item, item_ok, x, parent_r, rep, numrep,
 def _choose_indep_block(arrs, cfg, root_rows, root_valid, x, out_size,
                         numrep, target_type, recurse_to_leaf, tries,
                         recurse_tries, pos_base: int = 0):
-    """ref: mapper.c crush_choose_indep — position-stable EC placement.
+    """ref: mapper.c crush_choose_indep -- position-stable EC placement.
 
-    A round runs every position's descent at the block's full width and
-    the block goes round again while any lane has a position unfilled,
-    so its cost is its unluckiest lane's. Returns (out, leaves, rounds,
-    needed): ``rounds`` the final ftotal, ``needed`` the lanes that
-    still had a position to fill summed over the rounds -- the one
-    reduce a round, which is also what ends the loop."""
+    A round runs every position's descent at the width it is given, and
+    a block goes round again while any lane has a position unfilled.
+    The rounds run at the block's full width ``n`` while more than
+    ``n >> 3`` lanes are unfilled (round 1 always; on a healthy map
+    nothing after it: 11 positions over 640 hosts leave 8.4%). Then the
+    unfilled lanes are gathered into a block an eighth as wide, the
+    rounds go on there, and once no more than ``n >> 7`` are left
+    (0.12% after round 2 there) in one that wide; the rows are put back
+    where they came from. The round counter goes on where it stood: a
+    lane still unfilled has been in every round, so ``r`` is what
+    upstream's ``ftotal`` gives it, and only its company changes. A
+    block under ``MIN_NARROW_WIDTH`` is the one loop at full width.
+
+    On a v5e a round of 11 positions takes 435 ms at 2^20 lanes, 69 at
+    2^17 with the gathers into it, 3.5 at 2^13, and gathering and
+    putting back cost 37 ms at 2^20 (PERF.md, PR 34): the 10,240-OSD
+    map's four rounds took 1.76 s at full width and take 0.57 s.
+
+    Returns (out, leaves, tally), ``tally`` int32: the final ftotal;
+    the lanes that still had a position to fill summed over the rounds
+    (the one reduce a round, which also ends the loops); the widths of
+    the rounds summed; 1 if the block narrowed."""
     n = x.shape[0]
-    out0 = jnp.full((n, out_size), ITEM_NONE - 1, dtype=jnp.int32)  # UNDEF
-    leaves0 = jnp.full((n, out_size), ITEM_NONE - 1, dtype=jnp.int32)
     UNDEF = ITEM_NONE - 1
 
     def unfilled(out):
-        return jnp.any(out == UNDEF, axis=1).sum(dtype=jnp.int32)
+        return jnp.any(out == UNDEF, axis=1)
 
-    def cond(c):
-        return (c["ftotal"] < tries) & (c["left"] > 0)
+    def rounds(root_rows, root_valid, x, carry, stop_at):
+        """Rounds at the width of ``x`` while more than ``stop_at`` of
+        its lanes are unfilled."""
+        w = x.shape[0]
+        # The full-width round keeps its positions unrolled; a narrow
+        # one runs them as a loop. With unrolled narrow copies the 2^20
+        # sweep program was 231 MB of code against 119 and a process
+        # loaded it from the cache in 20.7 s against 7.5; as loops they
+        # make it 134 MB and 8.4 s, for 8-12 ms more a sweep. The
+        # full-width round as a loop takes 530 ms, not 440 (PERF.md).
+        unrolled = w == n
 
-    def body(c):
-        out, leaves = c["out"], c["leaves"]
-        ftotal = c["ftotal"]
-        for rep in range(out_size):
-            need = out[:, rep] == UNDEF
-            base_r = jnp.full(n, rep, dtype=jnp.int32)
-            pos_v = jnp.full(n, pos_base + rep, dtype=jnp.int32)
-            item, ok, r_parent = _descend(arrs, cfg, root_rows,
-                                          root_valid & need, x,
-                                          base_r, ftotal, target_type,
-                                          numrep,
-                                          levels=cfg.get("levels_main"),
-                                          pos=pos_v)
-            real = jnp.where(out == UNDEF, ITEM_NONE, out)
-            collide = jnp.any(item[:, None] == real, axis=1)
-            ok = ok & ~collide
-            if recurse_to_leaf:
-                # parent_r = the r at which `item` was drawn (scalar passes
-                # its loop-local r into the recursion).
-                leaf, ok = _leaf_choose_indep(arrs, cfg, item, ok, x,
-                                              r_parent, rep, numrep,
-                                              recurse_tries, pos=pos_v)
+        def cond(c):
+            return (c["ftotal"] < tries) & (c["left"] > stop_at)
+
+        def body(c):
+            ftotal = c["ftotal"]
+
+            def place(rep, placed):
+                """Position ``rep``'s descent for the lanes that need it."""
+                out, leaves = placed
+                col = lax.dynamic_index_in_dim(out, rep, 1, keepdims=False)
+                need = col == UNDEF
+                base_r = jnp.full(w, rep, dtype=jnp.int32)
+                pos_v = jnp.full(w, pos_base + rep, dtype=jnp.int32)
+                item, ok, r_parent = _descend(
+                    arrs, cfg, root_rows, root_valid & need, x, base_r,
+                    ftotal, target_type, numrep,
+                    levels=cfg.get("levels_main"), pos=pos_v)
+                real = jnp.where(out == UNDEF, ITEM_NONE, out)
+                collide = jnp.any(item[:, None] == real, axis=1)
+                ok = ok & ~collide
+                if recurse_to_leaf:
+                    # parent_r = the r at which `item` was drawn (scalar
+                    # passes its loop-local r into the recursion).
+                    leaf, ok = _leaf_choose_indep(
+                        arrs, cfg, item, ok, x, r_parent, rep, numrep,
+                        recurse_tries, pos=pos_v)
+                else:
+                    leaf = item
+                    if target_type == 0:
+                        ok = ok & ~_is_out(arrs, item, x, cfg)
+                ok = need & ok
+                lcol = lax.dynamic_index_in_dim(leaves, rep, 1,
+                                                keepdims=False)
+                return (lax.dynamic_update_index_in_dim(
+                            out, jnp.where(ok, item, col), rep, 1),
+                        lax.dynamic_update_index_in_dim(
+                            leaves, jnp.where(ok, leaf, lcol), rep, 1))
+
+            placed = c["out"], c["leaves"]
+            if unrolled:
+                for rep in range(out_size):
+                    placed = place(rep, placed)
             else:
-                leaf = item
-                if target_type == 0:
-                    ok = ok & ~_is_out(arrs, item, x, cfg)
-            place = need & ok
-            out = out.at[:, rep].set(jnp.where(place, item, out[:, rep]))
-            leaves = leaves.at[:, rep].set(
-                jnp.where(place, leaf, leaves[:, rep]))
-        return {"out": out, "leaves": leaves, "ftotal": ftotal + 1,
-                "left": unfilled(out), "needed": c["needed"] + c["left"]}
+                placed = lax.fori_loop(0, out_size, place, placed)
+            out, leaves = placed
+            return {**c, "out": out, "leaves": leaves, "ftotal": ftotal + 1,
+                    "left": unfilled(out).sum(dtype=jnp.int32),
+                    "needed": c["needed"] + c["left"],
+                    "run": c["run"] + w}
 
-    res = lax.while_loop(cond, body,
-                         {"out": out0, "leaves": leaves0,
-                          "ftotal": jnp.int32(0), "left": unfilled(out0),
-                          "needed": jnp.int32(0)})
-    out = jnp.where(res["out"] == UNDEF, ITEM_NONE, res["out"])
-    leaves = jnp.where(res["leaves"] == UNDEF, ITEM_NONE, res["leaves"])
-    return out, leaves, res["ftotal"], res["needed"]
+        return lax.while_loop(cond, body, carry)
+
+    def finish(root_rows, root_valid, x, c, caps):
+        """The rounds left to a block of ``x``'s width: there while
+        more than ``caps[0]`` of its lanes are unfilled, then in a
+        block that wide."""
+        c = rounds(root_rows, root_valid, x, c, caps[0] if caps else 0)
+        if not caps:
+            return c
+
+        def narrow(c):
+            # top_k, not jnp.nonzero under a cond (see _kernel_body's
+            # fallback): the unfilled lanes, then lanes already filled,
+            # whose ``need`` is false in every position: they place
+            # nothing and put back what they held
+            _, idx = lax.top_k(unfilled(c["out"]).astype(jnp.int32),
+                               caps[0])
+            sub = finish(root_rows[idx], root_valid[idx], x[idx],
+                         {**c, "out": c["out"][idx],
+                          "leaves": c["leaves"][idx]}, caps[1:])
+            return {**sub, "narrowed": jnp.int32(1),
+                    "out": c["out"].at[idx].set(sub["out"]),
+                    "leaves": c["leaves"].at[idx].set(sub["leaves"])}
+
+        return lax.cond((c["left"] > 0) & (c["ftotal"] < tries), narrow,
+                        lambda c: c, c)
+
+    out0 = jnp.full((n, out_size), UNDEF, dtype=jnp.int32)
+    c = finish(root_rows, root_valid, x,
+               {"out": out0, "leaves": out0, "ftotal": jnp.int32(0),
+                "left": unfilled(out0).sum(dtype=jnp.int32),
+                "needed": jnp.int32(0), "run": jnp.int32(0),
+                "narrowed": jnp.int32(0)}, narrow_widths(n))
+    out = jnp.where(c["out"] == UNDEF, ITEM_NONE, c["out"])
+    leaves = jnp.where(c["leaves"] == UNDEF, ITEM_NONE, c["leaves"])
+    return out, leaves, jnp.stack([c[k] for k in
+                                   ("ftotal", "needed", "run", "narrowed")])
 
 
 def _compact(w):
@@ -869,6 +951,21 @@ def _compact(w):
 # v5e (37 ns a lane) against the host's 6-7 ms a sweep (PERF.md):
 # narrower would buy nothing.
 MIN_BLOCK_WIDTH = 1 << 16
+
+# Narrowest indep block that finishes its later rounds in narrower
+# blocks (``_choose_indep_block``). Every narrow width is one more copy
+# of the round body in the program, and under 2^13 lanes a full-width
+# round takes a v5e under 3.5 ms (PERF.md): the copies would cost every
+# small program its compile time to save less than a dispatch.
+MIN_NARROW_WIDTH = 1 << 13
+
+
+def narrow_widths(width: int) -> tuple[int, ...]:
+    """Widths, widest first, of the blocks in which an indep block of
+    ``width`` lanes finishes its later rounds: an eighth once no more
+    lanes than that are unfilled, then a 128th; none for a block that
+    never narrows."""
+    return (width >> 3, width >> 7) if width >= MIN_NARROW_WIDTH else ()
 
 
 def block_width(lanes: int, cap: int, floor: int = 1) -> int:
@@ -1805,7 +1902,8 @@ class Mapper:
             for name, v in zip(INDEP_TALLY, bad[1:]):
                 PERF.inc(name, int(v))
             bad = bad[0]
-            sec.tag("lanes", int(n)).tag("width", width)
+            sec.tag("lanes", int(n)).tag("width", width) \
+                .tag("narrow_width", next(iter(narrow_widths(width)), 0))
             sec.finish()
         path = self.mapping_path(ruleno, result_max)
         PERF.inc("pgs_mapped", int(n))       # success only (no double
@@ -1904,8 +2002,14 @@ def _count_placements(flat, nbins):
 
 
 # what an indep sweep's ``bad`` vector carries after the bad mappings,
-# under the names ``PERF`` counts them by
+# under the names ``PERF`` counts them by. A round costs the width it
+# runs at: ``indep_lane_rounds_run`` over ``indep_rounds`` is a round's
+# mean width (on the 10,240-OSD map's 11-wide rule 1.14 x 2^20 over 4:
+# one full round, one an eighth as wide, two a 128th), and
+# ``indep_lane_rounds_needed`` over it the share of that which had
+# anything to place.
 INDEP_TALLY = ("indep_blocks", "indep_rounds", "indep_lane_rounds_needed",
+               "indep_lane_rounds_run", "indep_blocks_narrowed",
                "indep_holes")
 
 
@@ -1928,7 +2032,11 @@ def _compiled_sweep(fn_body, indep_stats, n_devices, block, result_max):
     counts has n_devices+1 bins: the last collects ITEM_NONE/out-of-range
     lanes and is dropped by the caller. With ``indep_stats`` the body is
     ``_rule_body(..., indep_stats=True)`` and ``bad`` an int64 vector:
-    the bad mappings, then ``INDEP_TALLY``'s counters."""
+    the bad mappings, then ``INDEP_TALLY``'s counters. An indep step
+    costs its rounds, and a round the width it runs at
+    (``_choose_indep_block``): the first the block's, the later ones an
+    eighth and then a 128th of it once no more lanes than that are
+    unfilled."""
     PERF.inc("sweep_compiles")           # body runs only on an lru miss
 
     def run(arrs, counts, bad, x0, remaining):
@@ -1972,8 +2080,9 @@ def _rule_body(steps, result_max, tkey, max_depth, present, type_depth=(),
                tree_depth=0, flags=(False, False), indep_stats=False):
     """The rule VM: ``run(arrs, xs) -> (n, result_max)`` mappings.
     With ``indep_stats`` it returns ``(mappings, stats)``, stats the
-    int32 triple (choose_indep blocks run, their rounds, their needed
-    lane-rounds) that the sweep step tallies (``_choose_indep_block``)."""
+    int32 vector (choose_indep blocks run, then the sum of their
+    ``_choose_indep_block`` tallies: rounds, needed lane-rounds,
+    lane-rounds run, blocks narrowed) that the sweep step tallies."""
     total_tries, descend_once, vary_r, stable = tkey
     base_cfg = {"max_depth": max_depth, "present": present,
                 "tree_depth": tree_depth,
@@ -1989,7 +2098,7 @@ def _rule_body(steps, result_max, tkey, max_depth, present, type_depth=(),
         w_cols: list = []
         emitted: list = []
         any_firstn = False
-        stats = jnp.zeros(3, dtype=jnp.int32)
+        stats = jnp.zeros(len(INDEP_TALLY) - 1, dtype=jnp.int32)
         cur_type = None   # static type of the current columns' items
         for step in steps:
             op, arg1, arg2 = step[0], step[1], step[2]
@@ -2045,12 +2154,12 @@ def _rule_body(steps, result_max, tkey, max_depth, present, type_depth=(),
                             arg2, recurse, choose_tries, recurse_tries, vr)
                     else:
                         blk = min(numrep, result_max - osize)
-                        out, leaves, rounds, needed = _choose_indep_block(
+                        out, leaves, tally = _choose_indep_block(
                             arrs, cfg, root_rows, root_valid, xs, blk,
                             numrep, arg2, recurse, choose_tries,
                             recurse_tries)
-                        stats = stats + jnp.stack(
-                            [jnp.int32(1), rounds, needed])
+                        stats = stats + jnp.concatenate(
+                            [jnp.ones(1, dtype=jnp.int32), tally])
                     chosen = leaves if recurse else out
                     # Device roots with matching type pass through.
                     if arg2 == 0:

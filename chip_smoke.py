@@ -55,6 +55,7 @@ class Sizes:
     max_x: int = (1 << 20) - 1
     variant_pgs: int = 1 << 21
     pool_pgs: int = 16384
+    indep_pgs: int = 1 << 16        # one indep block wide enough to narrow
     sample: int = 4096
     block: int | None = None        # Mapper block (None: its default)
     ref_workers: int = 8            # CPU-pinned mapper_ref processes
@@ -67,7 +68,8 @@ FULL = Sizes()
 REHEARSAL = Sizes(object_size=256 << 10, in_flight=4, objects_83=4,
                   objects_42=2, degraded_reads=2, n_osds=256, hosts=16,
                   racks=4, max_x=4095, variant_pgs=1 << 12,
-                  pool_pgs=256, sample=64, block=1024, ref_workers=0,
+                  pool_pgs=256, indep_pgs=256, sample=64, block=1024,
+                  ref_workers=0,
                   ec_iterations=2, sharded_pgs=1 << 13,
                   sharded_stripes=8)
 
@@ -357,6 +359,46 @@ def _check_path(what: str, mapper, ruleno: int, numrep: int) -> str:
     return promised
 
 
+def _indep_block(cmap, sz: Sizes, start: int) -> list:
+    """``map_pgs`` of ``sz.indep_pgs`` ids from ``start`` by upstream's
+    erasure rule (the ``rule_text`` of the benchmark's configuration
+    ``crush-10k-ec83-indep``), 11 wide, against ``benchmark/reference``'s
+    ``crush_indep_ref``, position by position, holes included. Returns
+    the widths the block narrows to (none: it is under the floor)."""
+    import pathlib
+
+    import numpy as np
+
+    from ceph_tpu.crush import builder, mapper as mapper_mod
+    from ceph_tpu.crush.mapper import Mapper
+    bench = pathlib.Path(__file__).resolve().parent / "benchmark"
+    if str(bench) not in sys.path:
+        sys.path.insert(0, str(bench))
+    from reference import crush_indep_ref, crush_ref
+    rule_text = json.loads((bench / "configs" / "crush-10k-ec83-indep.json"
+                            ).read_text())["rule_text"]
+    root = cmap.rules[0].steps[0].arg1
+    rid = builder.add_simple_rule(cmap, root, builder.TYPE_HOST, indep=True)
+    rmap = crush_ref.build_map({"osds": sz.n_osds, "hosts": sz.hosts,
+                                "racks": sz.racks, "failure_domain": "host"})
+    steps = crush_indep_ref.parse_rule(rule_text, rmap)
+    check([(s.op, s.arg1, s.arg2) for s in cmap.rules[rid].steps]
+          == crush_indep_ref.step_codes(steps),
+          "placement: indep: the program's erasure rule is not upstream's")
+    mapper = Mapper(cmap, block=sz.block)
+    xs = np.arange(start, start + sz.indep_pgs, dtype=np.uint32)
+    got = np.asarray(mapper.map_pgs(rid, xs, 11)).astype(np.int64)
+    check(mapper.last_map_path == "xla",
+          f"placement: indep ran on {mapper.last_map_path!r}, not the rule VM")
+    want = crush_indep_ref.map_batch(rmap, steps, xs, 11)
+    differing = int((got != want).sum())
+    check(got.shape == want.shape and differing == 0,
+          f"placement: indep: {differing} of {want.size} positions differ "
+          f"from crush_indep_ref")
+    # ids up to the mapper's block run as one block of their own width
+    return list(mapper_mod.narrow_widths(sz.indep_pgs))
+
+
 def phase_placement(sz: Sizes, seed: int) -> dict:
     import numpy as np
 
@@ -430,6 +472,14 @@ def phase_placement(sz: Sizes, seed: int) -> dict:
               "placement: up_primary differs from the reference")
         paths["pool"] = _check_path("pg_to_up_acting_osds",
                                     omap.serving_mapper(1), 0, 3)
+
+        # (d) an EC pool's rule, 11 wide, on the rule VM: one block of
+        # consecutive ids, at full size wide enough to finish its later
+        # rounds narrow, every position against the benchmark's plain
+        # crush_choose_indep (the sweeps above are 3 wide)
+        t_indep = time.perf_counter()
+        indep_width = _indep_block(cmap, sz, int(rng.integers(1, 1 << 20)))
+        t_indep = time.perf_counter() - t_indep
     finally:
         ref.close()
     return emit(
@@ -439,7 +489,9 @@ def phase_placement(sz: Sizes, seed: int) -> dict:
         variants={n: {k: v[k] for k in ("mappings_per_s", "n_pgs", "path",
                                         "kernel_lanes", "candidate_fold")
                       if k in v} for n, v in variants.items()},
-        pool_pgs=sz.pool_pgs, pool_map_s=round(t_pool, 3))
+        pool_pgs=sz.pool_pgs, pool_map_s=round(t_pool, 3),
+        indep_pgs=sz.indep_pgs, indep_narrow_widths=indep_width,
+        indep_s=round(t_indep, 3))
 
 
 # -- phase 3: the kernels ---------------------------------------------------

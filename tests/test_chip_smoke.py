@@ -34,6 +34,12 @@ def test_phase_at_rehearsal_size(cpu_expectations, capsys, phase):
         # the fact the next perf issue starts from: stripe_unit 4096
         # never reaches the fused kernel
         assert all("xla bitmatmul" in e for e in line["ec_engines"])
+    if phase == "placement":
+        # the 11-wide indep block ran (under the narrowing floor here)
+        assert line["indep_pgs"] == chip_smoke.REHEARSAL.indep_pgs
+        assert line["indep_narrow_widths"] == []
+        from ceph_tpu.crush.mapper import narrow_widths
+        assert narrow_widths(chip_smoke.FULL.indep_pgs) == (1 << 13, 1 << 9)
     if phase == "sharded":
         assert line["sweep_devices"] == line["encode_devices"] == 4
 

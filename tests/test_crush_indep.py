@@ -4,12 +4,14 @@
 benchmark's plain reference, position by position; bad mappings counted
 and printed as upstream's CrushTester does; the builder and the mon
 making that rule; the indep counters against what the scalar spec
-counts."""
+counts; the block that finishes its later rounds an eighth as wide, with
+the floor of that patched down to a CPU's sizes."""
 
 import asyncio
 import pathlib
 import sys
 
+import jax
 import numpy as np
 import pytest
 
@@ -44,12 +46,19 @@ CASES = {
     # fewer hosts than positions: every mapping has a hole
     "few": (4, 3, 2, (), 4),
 }
+# maps only the narrowing tests use (they would slow the others)
+WIDE_CASES = {
+    # hosts far over the width: a round leaves few lanes unfilled
+    "wide": (96, 2, 8, (), 96),
+    # one of the two OSDs out in every other host: the leaf retries
+    "wide-out": (96, 2, 8, tuple(range(0, 192, 4)), 96),
+}
 
 
 def _maps(case):
     """(program's map, its erasure rule id, weights, reference's map
     and steps) of a case."""
-    hosts, per, racks, out, _usable = CASES[case]
+    hosts, per, racks, out, _usable = {**CASES, **WIDE_CASES}[case]
     m, root = builder.build_hierarchy(hosts, per, n_racks=racks)
     rid = builder.add_simple_rule(m, root, builder.TYPE_HOST, indep=True)
     w = np.full(m.max_devices, WEIGHT_ONE, dtype=np.int64)
@@ -240,28 +249,74 @@ def test_an_erasure_pool_made_by_the_mon_carries_the_set_steps():
     assert ("rule ec_p21 {\n" + DOCS_RULE.format(id=rid, root=root)) in text
 
 
-@pytest.mark.parametrize("case,width,n", [("in", 11, 512), ("in", 6, 512),
-                                          ("out", 11, 256), ("out", 6, 256),
-                                          ("few", 6, 32)])
-def test_indep_counters_are_what_the_scalar_spec_counts(case, width, n):
+def _spec_rounds(m, rid, w, xs, width):
+    """(rounds of every input's one choose_indep, holes, the vectors)
+    by the scalar spec."""
+    rounds, rows = [], []
+    for x in xs:
+        rows.append(mapper_ref.do_rule(m, rid, int(x), width, w.tolist(),
+                                       indep_rounds=rounds))
+    assert len(rounds) == len(rows)
+    return rounds, sum(r.count(ITEM_NONE) for r in rows), rows
+
+
+def _widths_run(rounds, n, tries=100):
+    """The width of every round a block of n lanes runs, from the
+    scalar spec's rounds: after round k the inputs that took more than
+    k rounds are unfilled, and the block goes on in the next of
+    ``narrow_widths(n)`` once no more lanes than that are."""
+    caps = list(mapper_mod.narrow_widths(n))
+    widths, width, left = [], n, n
+    while len(widths) < tries and left > 0:
+        while caps and left <= caps[0]:
+            width = caps.pop(0)
+        widths.append(width)
+        left = sum(r > len(widths) for r in rounds)
+    return widths
+
+
+@pytest.fixture
+def narrow_from(monkeypatch):
+    """Sets ``MIN_NARROW_WIDTH``. The rule VM's programs are cached by
+    rule and not by the floor: the caches are emptied round the patch."""
+    def clear():
+        for cached in (mapper_mod._rule_body, mapper_mod._compiled_rule,
+                       mapper_mod._compiled_sweep):
+            cached.cache_clear()
+
+    def patch(floor):
+        clear()
+        monkeypatch.setattr(mapper_mod, "MIN_NARROW_WIDTH", floor)
+    yield patch
+    clear()
+
+
+@pytest.mark.parametrize("case,width,n,floor", [
+    ("in", 11, 512, None), ("in", 6, 512, None), ("out", 11, 256, None),
+    ("out", 6, 256, None), ("few", 6, 32, None),
+    # narrowing: after round 1; after five rounds, hosts and an OSD out
+    ("wide", 4, 128, 128), ("out", 6, 128, 128)])
+def test_indep_counters_are_what_the_scalar_spec_counts(case, width, n,
+                                                        floor, narrow_from):
     """One block of n lanes: ``indep_rounds`` is its unluckiest
     input's rounds, ``indep_lane_rounds_needed`` the sum of every
-    input's, ``indep_holes`` the ITEM_NONEs emitted."""
+    input's, ``indep_lane_rounds_run`` the widths of its rounds,
+    ``indep_holes`` the ITEM_NONEs emitted."""
+    if floor:
+        narrow_from(floor)
     m, rid, w, _rm, _steps = _maps(case)
-    rounds, holes = [], 0
-    for x in range(1, n + 1):
-        per_x = []
-        row = mapper_ref.do_rule(m, rid, x, width, w.tolist(),
-                                 indep_rounds=per_x)
-        rounds += per_x
-        holes += row.count(ITEM_NONE)
-    assert len(rounds) == n
+    rounds, holes, _rows = _spec_rounds(m, rid, w, range(1, n + 1), width)
+    widths = _widths_run(rounds, n)
+    narrowed = widths[-1] < n
+    assert narrowed == bool(floor)
     before = mapper_mod.PERF.dump()
     res = CrushTester(m, w, batch=n).test(rid, width, 1, n)
     after = mapper_mod.PERF.dump()
     delta = {k: after[k] - before[k] for k in mapper_mod.INDEP_TALLY}
     assert delta == {"indep_blocks": 1, "indep_rounds": max(rounds),
                      "indep_lane_rounds_needed": sum(rounds),
+                     "indep_lane_rounds_run": sum(widths),
+                     "indep_blocks_narrowed": int(narrowed),
                      "indep_holes": holes}
     assert after["sweep_lanes"] - before["sweep_lanes"] == n
     assert (res.bad_mappings > 0) == (holes > 0)
@@ -282,17 +337,91 @@ def test_a_firstn_sweep_moves_no_indep_counter():
 def test_the_indep_sweep_is_a_tracing_section(monkeypatch):
     from ceph_tpu.utils import tracing
     m, rid, w, _rm, _steps = _maps("in")
-    seen = []
-    real = tracing.section
+    seen, made = [], []
 
-    def section(name, *a, **kw):
+    def section(name, ctx=None, tracer=None, service=""):
+        # a Span as a profiler session would have it: its tags are kept
         seen.append(name)
-        return real(name, *a, **kw)
+        made.append(tracing.Span(tracer, name, 0, 0, None, tracing.SECTION,
+                                 service))
+        return made[-1]
     monkeypatch.setattr(mapper_mod.tracing, "section", section)
     CrushTester(m, w, batch=64).test(rid, 6, 0, 63)
     assert seen == ["crush.indep_block"]
+    assert made[0].tags == {"lanes": 64, "width": 64, "narrow_width": 0}
+    assert mapper_mod.narrow_widths(1 << 20) == (1 << 17, 1 << 13)
     seen.clear()
     root = m.rules[rid].steps[2].arg1
     firstn = builder.add_simple_rule(m, root, builder.TYPE_HOST)
     CrushTester(m, w, batch=64).test(firstn, 3, 0, 63)
     assert seen == []
+
+
+def _block_program(m, w, rid, width, n):
+    """The rule VM's program for a block of n lanes, with its tally:
+    ``(jitted run(arrays, xs) -> (mappings, stats), arrays)``."""
+    mp = Mapper(m, w, block=n)
+    return jax.jit(mapper_mod._rule_body(*mp._rule_key(rid, width),
+                                         indep_stats=True)), mp.arrays
+
+
+# case, width, the widths of a 128-lane block's rounds (it narrows to 16
+# lanes, then to 1)
+NARROWING = {
+    "after-round-1": ("wide", 4, [128, 16]),
+    # exactly 16 lanes are left after round 1, and one after round 2;
+    # the leaf retries (set_chooseleaf_tries 5) run inside narrow rounds
+    "osd-out-leaf-retries": ("wide-out", 4, [128, 16, 1]),
+    # over an eighth unfilled after round 1: full width again, then narrow
+    "after-round-4": ("in", 6, [128] * 4 + [16] * 2),
+    "hosts-out-after-round-5": ("out", 6, [128] * 5 + [16] * 4 + [1]),
+    "eleven-wide": ("wide", 11, [128] * 2 + [16] * 2 + [1]),
+    # hosts < width: every lane keeps its holes in place, the loop runs
+    # to set_choose_tries at full width
+    "holes-never": ("few", 6, [128] * 100),
+}
+
+
+@pytest.mark.parametrize("name", sorted(NARROWING))
+def test_a_narrowed_block_is_both_references_position_by_position(
+        name, narrow_from):
+    case, width, widths = NARROWING[name]
+    n = 128
+    narrow_from(n)
+    assert mapper_mod.narrow_widths(n) == (16, 1)
+    m, rid, w, rm, steps = _maps(case)
+    xs = np.arange(1, 1 + n, dtype=np.uint32)
+    rounds, holes, spec = _spec_rounds(m, rid, w, xs, width)
+    # the map does what the case is named for
+    assert _widths_run(rounds, n) == widths and len(widths) == max(rounds)
+    fn, arrays = _block_program(m, w, rid, width, n)
+    with jax.enable_x64(True):
+        got, stats = fn(arrays, xs)
+    got = np.asarray(got)
+    assert np.array_equal(got, np.array(spec))
+    assert np.array_equal(
+        got, crush_indep_ref.map_batch(rm, steps, xs, width, w.tolist()))
+    assert int((got == ITEM_NONE).sum()) == holes
+    assert (holes > 0) == (case == "few")
+    assert np.asarray(stats).tolist() == [
+        1, max(rounds), sum(rounds), sum(widths), int(widths[-1] < n)]
+
+
+def test_a_block_under_the_floor_is_the_one_loop(narrow_from):
+    """Under ``MIN_NARROW_WIDTH`` the block lowers to the single
+    full-width loop it was: a ``while`` for the rounds and one for
+    every position's leaf retries, no gather of unfilled lanes."""
+    narrow_from(128)
+    m, rid, w, _rm, _steps = _maps("wide")
+    text = {}
+    for n in (64, 128):
+        fn, arrays = _block_program(m, w, rid, 4, n)
+        with jax.enable_x64(True):
+            text[n] = fn.lower(arrays, np.zeros(n, np.uint32)).as_text()
+    assert text[64].count("stablehlo.while") == 1 + 4
+    assert "top_k" not in text[64] and "stablehlo.case" not in text[64]
+    # two narrow widths, their positions a loop and not unrolled: the
+    # rounds, the positions, one leaf loop
+    assert text[128].count("stablehlo.while") == (1 + 4) + 2 * 3
+    assert text[128].count("top_k") == 2
+    assert text[128].count("stablehlo.case") == 2
