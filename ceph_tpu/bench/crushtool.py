@@ -54,7 +54,10 @@ def parse_args(argv=None):
     ap.add_argument("--alg", choices=sorted(ALGS), default="straw2")
     ap.add_argument("--indep", action="store_true",
                     help="build an erasure (indep) rule")
-    ap.add_argument("--test", action="store_true")
+    ap.add_argument("--test", action="store_true",
+                    help="map --min-x..--max-x with --rule; a weight-set "
+                         "in the map (choose_args id 0, else the compat "
+                         "set -1) is honoured, as upstream's tool does")
     ap.add_argument("--rule", type=int, default=0)
     ap.add_argument("--num-rep", type=int, default=3)
     ap.add_argument("--min-x", type=int, default=0)
@@ -127,6 +130,9 @@ def main(argv=None) -> dict:
         tester = CrushTester(m, weights, batch=args.batch)
         res = tester.test(args.rule, args.num_rep, args.min_x, args.max_x,
                           keep_mappings=args.show_mappings)
+        # the weight-set that served, as upstream's tool picks it: the
+        # map's set of id 0, else its compat set (-1), else none
+        served = "none" if res.choose_args is None else res.choose_args
         if args.show_mappings:
             for i, row in enumerate(res.mappings):
                 devs = [int(d) for d in row if d != ITEM_NONE]
@@ -139,13 +145,15 @@ def main(argv=None) -> dict:
                 print(line)
         if args.show_statistics:
             print(f"total mappings {res.total_x} in {res.seconds:.4f}s "
-                  f"({res.mappings_per_second:,.0f}/s) on path {res.path}")
+                  f"({res.mappings_per_second:,.0f}/s), choose_args "
+                  f"{served}, on path {res.path}")
         out.update({
             "rule": args.rule, "num_rep": args.num_rep,
             "total_x": res.total_x, "seconds": res.seconds,
             "mappings_per_second": res.mappings_per_second,
             "bad_mappings": res.bad_mappings,
             "mapping_path": res.path,
+            "choose_args": served,
             "utilization": res.utilization_summary(),
         })
     if args.json:

@@ -12,7 +12,7 @@ from ceph_tpu.crush.types import (
     OP_CHOOSELEAF_FIRSTN, OP_CHOOSELEAF_INDEP, OP_CHOOSE_FIRSTN,
     OP_CHOOSE_INDEP, OP_EMIT, OP_SET_CHOOSELEAF_TRIES, OP_SET_CHOOSE_TRIES,
     OP_TAKE, WEIGHT_ONE,
-    Bucket, CrushMap, Rule, RuleStep, Tunables,
+    Bucket, ChooseArg, CrushMap, Rule, RuleStep, Tunables,
 )
 
 # Conventional type ids (ref: default crushmap types in
@@ -286,21 +286,95 @@ def add_multistep_rule(map_: CrushMap, root: int, steps: list[RuleStep],
     return rid
 
 
+# -- installing a weight-set as upstream does --------------------------------
+
+def create_choose_args(map_: CrushMap, key: int, positions: int = 1) -> None:
+    """A new weight-set under id ``key`` (-1: the compat set): every
+    bucket gets ``positions`` copies of its CRUSH weights as its
+    vectors, upstream's starting point, from which
+    ``choose_args_adjust_item_weight`` moves single items (ref:
+    CrushWrapper::create_choose_args)."""
+    if key in map_.choose_args:
+        raise ValueError(f"choose_args {key} exists")
+    map_.choose_args[key] = {
+        bid: ChooseArg(weight_set=[list(b.weights)
+                                   for _ in range(positions)])
+        for bid, b in map_.buckets.items()}
+
+
+def choose_args_set_item_weights(map_: CrushMap, key: int,
+                                 weights: dict[int, list[int]]) -> int:
+    """``choose_args_adjust_item_weight`` for every item of ``weights``
+    in turn (item -> one weight a position), with the map's buckets
+    indexed by what they hold once for all of them. Returns how many
+    entries changed hands."""
+    args = map_.choose_args[key]
+    parents: dict[int, list[int]] = {}
+    for b in map_.buckets.values():
+        for child in b.items:
+            parents.setdefault(child, []).append(b.id)
+
+    def adjust(item: int, ws_new: list[int]) -> int:
+        changed = 0
+        for bid in parents.get(item, ()):
+            b = map_.buckets[bid]
+            arg = args.get(bid)
+            if arg is None or not arg.weight_set:
+                # a bucket the set has no vectors for starts from its
+                # CRUSH weights, as upstream populates it on first touch
+                arg = args[bid] = ChooseArg(
+                    weight_set=[list(b.weights) for _ in ws_new],
+                    ids=arg.ids if arg is not None else None)
+            if len(arg.weight_set) != len(ws_new):
+                raise ValueError(
+                    f"weight-set {key} has {len(arg.weight_set)} "
+                    f"positions, {len(ws_new)} weights given")
+            i = b.items.index(item)
+            for ws, w in zip(arg.weight_set, ws_new):
+                ws[i] = int(w)
+            changed += 1
+            if bid in parents:
+                changed += adjust(bid, [sum(ws) for ws in arg.weight_set])
+        if not changed and item not in map_.buckets:
+            raise ValueError(f"item {item} is in no bucket")
+        return changed
+
+    return sum(adjust(item, ws) for item, ws in weights.items())
+
+
+def choose_args_adjust_item_weight(map_: CrushMap, key: int, item: int,
+                                   weights: list[int]) -> int:
+    """Set ``item``'s weight in weight-set ``key``, one weight a
+    position: its entry in the vectors of every bucket that holds it,
+    then that bucket's entry in ITS parent's vectors to the sum of the
+    bucket's vector, and so on up to the root, so every ancestor's
+    entry stays the sum below it (ref: CrushWrapper::
+    choose_args_adjust_item_weight and
+    _choose_args_adjust_item_weight_in_bucket, which carry the
+    bucket's new sum up the same way). Returns how many entries
+    changed hands; an item no bucket holds raises."""
+    return choose_args_set_item_weights(map_, key, {item: weights})
+
+
 # -- choose_args weight-set discipline --------------------------------------
-# The vectorized mapper's fused kernel carries at most 4 distinct
-# positive weights per bucket (crush/pallas_mapper.py MAX_CLASSES); a
-# weight-set where every item gets its own continuous weight — what an
-# unconstrained crush-compat balancer emits — forces every draw onto
-# the general ln-table path, measured ~35x slower (BENCH_r05
-# variants.choose_args). Quantizing to <=4 classes keeps balancer
-# output on the kernel path at negligible balance cost.
+# The fused kernel's class draw carries at most 4 distinct positive
+# weights per bucket (crush/pallas_mapper.py MAX_CLASSES); a weight-set
+# where every item gets its own continuous weight -- what upstream's
+# crush-compat balancer emits, and what any map from a real cluster
+# carries -- takes the kernel's per-slot continuous draw instead (not
+# the XLA general path, as this said when the kernel had no such
+# draw). Measured on a v5e (PERF.md §6, PR 35): the 10,240-OSD map with
+# a continuous compat weight-set sweeps at 10.6 M mappings/s against
+# 23.7 M with none, 2.2 times slower, the kernel itself 1.5 times; the
+# class draw has no cell yet. Whether the mgr should go on quantizing
+# is ROADMAP.md C1/C5's to decide from that.
 KERNEL_WEIGHT_CLASSES = 4
 
 
 def choose_args_weight_classes(m: CrushMap) -> int:
     """Worst-case distinct positive weights any single weight-set
     vector carries (0 = no choose_args). Above KERNEL_WEIGHT_CLASSES
-    the map leaves the fused-kernel mapping path."""
+    the kernel draws per slot (continuous) and no longer per class."""
     worst = 0
     for args in m.choose_args.values():
         for arg in args.values():

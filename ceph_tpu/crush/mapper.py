@@ -63,7 +63,11 @@ the bottom of the serving stack —
   fixed-point lookup decomposes into two 256-wide one-hot matmuls
   (hi/lo byte split, same MXU trick as ``_zg_pair``), so a
   balancer-style weight-set no longer falls off the kernel onto the
-  XLA gather path (the 34x ``choose_args`` cliff in BENCH_r05). Since
+  XLA gather path (how much slower that path is on a v5e is not
+  measured; on the kernel the 10,240-OSD map with a compat weight-set
+  sweeps at 10.6 M mappings/s against 23.7 M without one, 2.2 times
+  slower, half of a sweep the flagged-lane recompute: PERF.md §5-§6,
+  PR 35). Since
   round 15 its descent is level-major with the replica-candidate axis
   folded into the lane axis — one fused fetch+choose per level for
   ALL candidates, O(l_total) MXU ops independent of numrep
@@ -163,6 +167,21 @@ PERF = (_PCB("crush_mapper")
                          "lanes dispatched by sweep: the sum of its "
                          "blocks' widths (pgs_mapped over it is the "
                          "fill share)")
+        .add_u64_counter("kernel_flagged_lanes",
+                         "lanes the fused kernel flagged to the "
+                         "bit-exact recompute, summed over a sweep's "
+                         "blocks (over sweep_lanes the flag rate). "
+                         "Counted for plans with a class or a "
+                         "continuous level, whose draws decide inside "
+                         "a margin; an all-uniform plan's sweep "
+                         "program carries no tally")
+        .add_u64_counter("kernel_fallback_blocks",
+                         "sweep blocks in which the compact recompute "
+                         "of flagged lanes ran (any lane flagged)")
+        .add_u64_counter("kernel_fallback_overflows",
+                         "sweep blocks whose flagged lanes exceeded "
+                         "the fallback buffer and were recomputed at "
+                         "full width on the XLA general path")
         .add_u64_counter("indep_blocks",
                          "choose_indep blocks a sweep ran on the rule VM")
         .add_u64_counter("indep_rounds",
@@ -580,51 +599,94 @@ def _leaf_choose(arrs, cfg, item, item_ok, x, sub_r, prior_leaves, tries,
 def _choose_one_firstn(arrs, cfg, root_rows, root_valid, x, rep,
                        prior_out, prior_leaves, target_type,
                        recurse_to_leaf, tries, recurse_tries, vary_r,
-                       ftotal0: int = 0, pos: int = 0):
+                       ftotal0: int = 0, pos: int = 0,
+                       narrow: tuple[int, ...] = ()):
     """One replica slot of crush_choose_firstn, all lanes at once.
 
     ftotal0 > 0 resumes after the caller's speculative tries: the while
     cond is False when no lane is active, so the fallback costs nothing
-    on collision-free blocks."""
+    on collision-free blocks.
+
+    A round is a descent of every lane, and the loop goes round while
+    any lane has a try to make: at one width a slot costs its
+    unluckiest lane's tries. ``narrow``: widths, widest first, at which
+    the loop goes on once no more lanes than that have a try left (the
+    kernel's flagged-lane recompute, ``Mapper._make_kernel_body``): the
+    lanes are gathered into a block that wide and put back where they
+    came from, as ``_choose_indep_block`` does. ``ftotal`` is a lane's
+    own, so its ``r`` is what it was; only its company changes."""
+    def rounds(root_rows, root_valid, x, base_r, prior_out, prior_leaves,
+               c, stop_at):
+        """Rounds at the width of ``x`` while more than ``stop_at`` of
+        its lanes have a try to make."""
+        n = x.shape[0]
+
+        def cond(c):
+            if not stop_at:
+                return jnp.any(~c["done"])
+            return (~c["done"]).sum(dtype=jnp.int32) > stop_at
+
+        def body(c):
+            active = ~c["done"]
+            pos_v = jnp.full(n, pos, dtype=jnp.int32)
+            item, ok, r_fin = _descend(arrs, cfg, root_rows, root_valid, x,
+                                       base_r, c["ftotal"], target_type,
+                                       None, levels=cfg.get("levels_main"),
+                                       pos=pos_v)
+            collide = jnp.zeros(n, dtype=bool)
+            if prior_out.shape[1]:
+                collide = jnp.any(item[:, None] == prior_out, axis=1)
+            ok = ok & ~collide
+            if recurse_to_leaf:
+                r_cur = base_r + c["ftotal"]
+                if vary_r:
+                    sub_r = r_cur >> (vary_r - 1)
+                else:
+                    sub_r = jnp.zeros_like(r_cur)
+                leaf, ok = _leaf_choose(arrs, cfg, item, ok, x, sub_r,
+                                        prior_leaves, recurse_tries,
+                                        pos=pos_v)
+            else:
+                leaf = item
+                if target_type == 0:
+                    ok = ok & ~_is_out(arrs, item, x, cfg)
+            succeed = active & ok
+            ftotal_next = c["ftotal"] + 1
+            give_up = active & ~ok & (ftotal_next >= tries)
+            return {
+                "item": jnp.where(succeed, item, c["item"]),
+                "leaf": jnp.where(succeed, leaf, c["leaf"]),
+                "ok": c["ok"] | succeed,
+                "done": c["done"] | succeed | give_up,
+                "ftotal": jnp.where(active & ~ok, ftotal_next, c["ftotal"]),
+            }
+
+        return lax.while_loop(cond, body, c)
+
+    def finish(root_rows, root_valid, x, base_r, prior_out, prior_leaves,
+               c, caps):
+        """The rounds left to a block of ``x``'s width: there while
+        more than ``caps[0]`` of its lanes have a try to make, then in
+        a block that wide."""
+        c = rounds(root_rows, root_valid, x, base_r, prior_out,
+                   prior_leaves, c, caps[0] if caps else 0)
+        if not caps:
+            return c
+
+        def go_on(c):
+            # top_k, not jnp.nonzero under a cond (see _kernel_body's
+            # fallback): the lanes with a try to make, then lanes that
+            # are done, which stay as they are and are put back so
+            _, idx = lax.top_k((~c["done"]).astype(jnp.int32), caps[0])
+            sub = finish(root_rows[idx], root_valid[idx], x[idx],
+                         base_r[:caps[0]], prior_out[idx], prior_leaves[idx],
+                         {k: v[idx] for k, v in c.items()}, caps[1:])
+            return {k: v.at[idx].set(sub[k]) for k, v in c.items()}
+
+        return lax.cond(jnp.any(~c["done"]), go_on, lambda c: c, c)
+
     n = x.shape[0]
     base_r = jnp.full(n, rep, dtype=jnp.int32)
-
-    def cond(c):
-        return jnp.any(~c["done"])
-
-    def body(c):
-        active = ~c["done"]
-        pos_v = jnp.full(n, pos, dtype=jnp.int32)
-        item, ok, r_fin = _descend(arrs, cfg, root_rows, root_valid, x,
-                                   base_r, c["ftotal"], target_type, None,
-                                   levels=cfg.get("levels_main"), pos=pos_v)
-        collide = jnp.zeros(n, dtype=bool)
-        if prior_out.shape[1]:
-            collide = jnp.any(item[:, None] == prior_out, axis=1)
-        ok = ok & ~collide
-        if recurse_to_leaf:
-            r_cur = base_r + c["ftotal"]
-            if vary_r:
-                sub_r = r_cur >> (vary_r - 1)
-            else:
-                sub_r = jnp.zeros_like(r_cur)
-            leaf, ok = _leaf_choose(arrs, cfg, item, ok, x, sub_r,
-                                    prior_leaves, recurse_tries, pos=pos_v)
-        else:
-            leaf = item
-            if target_type == 0:
-                ok = ok & ~_is_out(arrs, item, x, cfg)
-        succeed = active & ok
-        ftotal_next = c["ftotal"] + 1
-        give_up = active & ~ok & (ftotal_next >= tries)
-        return {
-            "item": jnp.where(succeed, item, c["item"]),
-            "leaf": jnp.where(succeed, leaf, c["leaf"]),
-            "ok": c["ok"] | succeed,
-            "done": c["done"] | succeed | give_up,
-            "ftotal": jnp.where(active & ~ok, ftotal_next, c["ftotal"]),
-        }
-
     init = {
         "item": jnp.full(n, ITEM_NONE, dtype=jnp.int32),
         "leaf": jnp.full(n, ITEM_NONE, dtype=jnp.int32),
@@ -633,7 +695,8 @@ def _choose_one_firstn(arrs, cfg, root_rows, root_valid, x, rep,
         else jnp.ones(n, dtype=bool),
         "ftotal": jnp.full(n, ftotal0, dtype=jnp.int32),
     }
-    out = lax.while_loop(cond, body, init)
+    out = finish(root_rows, root_valid, x, base_r, prior_out, prior_leaves,
+                 init, tuple(w for w in narrow if w < n))
     return out["item"], out["leaf"], out["ok"]
 
 
@@ -952,19 +1015,21 @@ def _compact(w):
 # narrower would buy nothing.
 MIN_BLOCK_WIDTH = 1 << 16
 
-# Narrowest indep block that finishes its later rounds in narrower
-# blocks (``_choose_indep_block``). Every narrow width is one more copy
-# of the round body in the program, and under 2^13 lanes a full-width
-# round takes a v5e under 3.5 ms (PERF.md): the copies would cost every
-# small program its compile time to save less than a dispatch.
+# Narrowest block that finishes its later rounds in narrower blocks
+# (``_choose_indep_block``; the kernel's flagged-lane buffer in
+# ``_choose_one_firstn``, which is this wide for a 2^21 block). Every
+# narrow width is one more copy of the round body in the program, and
+# under 2^13 lanes a full-width indep round takes a v5e under 3.5 ms
+# (PERF.md): the copies would cost every small program its compile time
+# to save less than a dispatch.
 MIN_NARROW_WIDTH = 1 << 13
 
 
 def narrow_widths(width: int) -> tuple[int, ...]:
-    """Widths, widest first, of the blocks in which an indep block of
+    """Widths, widest first, of the blocks in which a block of
     ``width`` lanes finishes its later rounds: an eighth once no more
-    lanes than that are unfilled, then a 128th; none for a block that
-    never narrows."""
+    lanes than that have a round to make, then a 128th; none for a
+    block that never narrows."""
     return (width >> 3, width >> 7) if width >= MIN_NARROW_WIDTH else ()
 
 
@@ -1162,8 +1227,9 @@ class Mapper:
         # promised before it degraded — under
         # devmon_expected_engine=auto every later sweep keeps counting
         # a mismatch instead of the baseline silently re-healing to
-        # the fallback engine (the ISSUE's 34x-slower-with-no-signal
-        # case).
+        # the fallback engine (a kernel-path map served by the XLA
+        # path with no signal; how much slower that is on a v5e is not
+        # measured).
         self._devmon_token = next(_MAPPER_TOKEN)
         self._arrays_sig: tuple | None = None
         self._degraded_from: str | None = None
@@ -1408,13 +1474,16 @@ class Mapper:
             else plan.numrep_arg + result_max
         return min(numrep, result_max)
 
-    def _kernel_body(self, ruleno: int, result_max: int):
+    def _kernel_body(self, ruleno: int, result_max: int,
+                     tally: bool = False):
         """fn_body(arrs, xs) -> (N, result_max), backed by the fused
         kernel with a masked XLA fallback for flagged lanes, or None
-        when this rule is ineligible (the XLA path stands)."""
+        when this rule is ineligible (the XLA path stands). With
+        ``tally`` the body returns ``(mappings, stats)``, stats the
+        block's ``KERNEL_TALLY``."""
         if self._kernel_mode is None:
             return None
-        key = (ruleno, result_max)
+        key = (ruleno, result_max, tally)
         if key in self._kernel_bodies:
             return self._kernel_bodies[key]
         from ceph_tpu.crush import pallas_mapper as _pm
@@ -1424,12 +1493,12 @@ class Mapper:
             numrep = self._plan_numrep(plan, result_max)
             if numrep >= 1:
                 body = self._make_kernel_body(plan, ruleno, result_max,
-                                              numrep)
+                                              numrep, tally)
         self._kernel_bodies[key] = body
         return body
 
     def _make_kernel_body(self, plan, ruleno: int, result_max: int,
-                          numrep: int):
+                          numrep: int, tally: bool):
         from ceph_tpu.crush import pallas_mapper as _pm
         interpret = self._kernel_mode == "interpret"
         rule = self.map.rules[ruleno]
@@ -1459,22 +1528,41 @@ class Mapper:
                 interpret=interpret)
             leaves, bad = leaves[:n], bad[:n]
 
-            # XLA fallback for flagged lanes (candidate-table
-            # exhaustion ~1e-8/lane; ambiguous class draws ~1e-6 to
-            # ~1e-4/lane depending on bucket weight scale — heavy
-            # buckets draw small quotients where genuine floor ties
-            # concentrate): the loop path recomputes flagged lanes
-            # bit-exactly. At kernel-path block widths (2^21 lanes)
-            # flags land EVERY block, so the fallback must not cost
-            # O(block): gather the flagged lanes into a small buffer
-            # (sized ~10x the worst observed flag rate), recompute only
+            # XLA fallback for flagged lanes: the loop path recomputes
+            # them bit-exactly. Two causes. Candidate-table exhaustion:
+            # a lane whose numrep + SPEC_EXTRA candidates hold fewer
+            # than numrep different items of the failure domain; ~1e-8
+            # a lane over 640 hosts, but 1.8e-3 for 3 replicas over 20
+            # racks (5 draws landing on 2 racks or fewer). Ambiguous
+            # class or continuous draws: ~1e-6 to ~2e-4 a lane by
+            # bucket weight scale -- heavy buckets draw small quotients
+            # where genuine floor ties concentrate. Counted on a v5e
+            # (``kernel_flagged_lanes``, PERF.md, PR 35): 2,009 a
+            # million on the 10,240-OSD map with a compat weight-set,
+            # 4,220 lanes of a 2^21 block. So flags land EVERY block
+            # and the fallback must not cost O(block): gather the
+            # flagged lanes into a small buffer (``fallback_lanes``: a
+            # 256th of the block, 1.94 times that rate), recompute only
             # those, scatter back. Fill slots recompute lane xs_[0] and
             # scatter its (identical, because recomputation is exact)
             # value — no masking needed. The full-width masked
             # recompute survives only as the >FB overflow guard.
-            FB = min(n, max(256, n >> 8))
+            FB = fallback_lanes(n)
+            # Where the plan draws inside a margin the recompute draws
+            # by the general path's ln-table gathers, 11.2 ms a round of
+            # 8,192 lanes on a v5e, and at one width a slot's loop goes
+            # round for its unluckiest lane (a flagged lane's third
+            # replica collides on its first three tries by what flagged
+            # it, then one time in ten): 14.5 rounds a block, and a
+            # sweep's time follows its ids (six seeded runs spread by
+            # 0.66%; PERF.md, PR 35). So the buffer's later rounds run
+            # in narrower blocks (``_choose_one_firstn``), 7 of them at
+            # full width. An all-uniform plan's recompute draws by hash
+            # alone (14 ms of a window's 3.5 s) and its program stays
+            # the text it was.
+            narrow = narrow_widths(FB) if plan.rhlh is not None else ()
 
-            def _recompute(arrs_, xs_, active):
+            def _recompute(arrs_, xs_, active, narrow=()):
                 nn = xs_.shape[0]
                 rows = jnp.full(nn, root_row, dtype=jnp.int32)
                 fb = jnp.full((nn, numrep), ITEM_NONE, dtype=jnp.int32)
@@ -1485,7 +1573,7 @@ class Mapper:
                         arrs_, cfg, rows, active, xs_, rep,
                         fb[:, :rep], fb_lv[:, :rep], plan.target_type,
                         plan.recurse, tries, recurse_tries,
-                        plan.vary_r)
+                        plan.vary_r, narrow=narrow)
                     fb = fb.at[:, rep].set(
                         jnp.where(ok, item, ITEM_NONE))
                     fb_lv = fb_lv.at[:, rep].set(
@@ -1504,7 +1592,7 @@ class Mapper:
                     # harmlessly.
                     _, idx = jax.lax.top_k(bad2.astype(jnp.int32), FB)
                     sub = _recompute(arrs2, xs2[idx],
-                                     jnp.ones(FB, dtype=bool))
+                                     jnp.ones(FB, dtype=bool), narrow)
                     return leaves2.at[idx].set(sub)
 
                 def _all(op2):
@@ -1521,7 +1609,14 @@ class Mapper:
                 padc = jnp.full((n, result_max - w.shape[1]), ITEM_NONE,
                                 dtype=jnp.int32)
                 w = jnp.concatenate([w, padc], axis=1)
-            return w[:, :result_max]
+            w = w[:, :result_max]
+            if not tally:
+                return w
+            # KERNEL_TALLY, from the flags alone: which branch the
+            # conds above took is a function of their count
+            flagged = jnp.sum(bad, dtype=jnp.int32)
+            return w, jnp.stack([flagged, (flagged > 0).astype(jnp.int32),
+                                 (flagged > FB).astype(jnp.int32)])
 
         return fn_body
 
@@ -1809,9 +1904,12 @@ class Mapper:
         x is crush_do_rule's 32-bit input, so the range wraps modulo
         2^32 (a ``start_x`` at or past 2^32 is its low word).
 
-        Returns (counts, bad) device arrays: counts int64 (max_devices,),
-        bad int64 scalar. Nothing of O(n) touches the host. The engine
-        path is recorded per call — ``sweep_path`` returns it."""
+        Returns (counts, bad): counts int64 (max_devices,), bad int64
+        scalar; device arrays, or host arrays where the sweep carries a
+        tally (an indep rule on the rule VM, a kernel plan with a
+        margin draw), which comes back with them in one read. Nothing
+        of O(n) touches the host. The engine path is recorded per call
+        — ``sweep_path`` returns it."""
         counts, bad, _path = self.sweep_path(ruleno, start_x, n,
                                              result_max,
                                              device_counts_size)
@@ -1845,10 +1943,20 @@ class Mapper:
             return counts, bad, self._record_path(path, _expected)
         kb = self._kernel_body(ruleno, result_max)
         firstn = self.rule_is_firstn(ruleno)
-        # an indep rule on the rule VM tallies what its blocks did
+        # an indep rule on the rule VM tallies what its blocks did, a
+        # kernel plan with a margin draw what its fallback did
         indep = kb is None and not firstn
+        tally = INDEP_TALLY if indep else ()
         fn_body = kb or _rule_body(*self._rule_key(ruleno, result_max),
                                    indep_stats=indep)
+        # a plan that decides draws inside a margin (a class or a
+        # continuous level: it carries the crush_ln planes) sweeps with
+        # the tally; an all-uniform plan flags candidate exhaustion
+        # only, and its sweep program stays the one it was
+        if kb is not None and getattr(self._kernel_plan(ruleno), "rhlh",
+                                      None) is not None:
+            tally = KERNEL_TALLY
+            fn_body = self._kernel_body(ruleno, result_max, tally=True)
         nd = device_counts_size or self.packed.max_devices
         kb_kern = kb is not None
         dm = _devmon()
@@ -1859,14 +1967,14 @@ class Mapper:
         try:
             with jax.enable_x64(True):
                 counts = jnp.zeros(nd + 1, dtype=jnp.int64)
-                bad = jnp.zeros(1 + len(INDEP_TALLY), dtype=jnp.int64) \
-                    if indep else jnp.int64(0)
+                bad = jnp.zeros(1 + len(tally), dtype=jnp.int64) \
+                    if tally else jnp.int64(0)
                 while lanes < n:
                     # every block is as wide as the lanes left need:
                     # a sweep under the cap is one block of its own
                     # width, a longer one ends in a narrower tail block
                     block = self._block_for(kb_kern, n - lanes)
-                    step_fn = _compiled_sweep(fn_body, indep, nd, block,
+                    step_fn = _compiled_sweep(fn_body, tally, nd, block,
                                               result_max)
                     counts, bad = dm.jit_call(
                         "crush_sweep",
@@ -1896,12 +2004,13 @@ class Mapper:
             return self.sweep_path(ruleno, start_x, n, result_max,
                                    device_counts_size,
                                    _expected=_expected)
-        if indep:
+        if tally:
             # the tally comes back with the counts' read-back: one read
             counts, bad = jax.device_get((counts, bad))
-            for name, v in zip(INDEP_TALLY, bad[1:]):
+            for name, v in zip(tally, bad[1:]):
                 PERF.inc(name, int(v))
             bad = bad[0]
+        if indep:
             sec.tag("lanes", int(n)).tag("width", width) \
                 .tag("narrow_width", next(iter(narrow_widths(width)), 0))
             sec.finish()
@@ -2011,10 +2120,24 @@ def _count_placements(flat, nbins):
 INDEP_TALLY = ("indep_blocks", "indep_rounds", "indep_lane_rounds_needed",
                "indep_lane_rounds_run", "indep_blocks_narrowed",
                "indep_holes")
+# what a kernel sweep's ``bad`` vector carries after the bad mappings
+# where the plan draws inside a margin (``_make_kernel_body``): of each
+# block, the lanes the kernel flagged, whether the compact recompute
+# ran, and whether the flags overflowed its buffer
+KERNEL_TALLY = ("kernel_flagged_lanes", "kernel_fallback_blocks",
+                "kernel_fallback_overflows")
+
+
+def fallback_lanes(n: int) -> int:
+    """Lanes of the buffer a kernel block of ``n`` lanes gathers its
+    flagged lanes into for the bit-exact recompute: a 256th of the
+    block, 8,192 at 2^21 lanes; a block that flags more is recomputed
+    at full width and counted (``kernel_fallback_overflows``)."""
+    return min(n, max(256, n >> 8))
 
 
 @functools.lru_cache(maxsize=256)
-def _compiled_sweep(fn_body, indep_stats, n_devices, block, result_max):
+def _compiled_sweep(fn_body, tally, n_devices, block, result_max):
     """Per-block aggregated sweep step: map one x block, count its
     placements per device on device (``_count_placements``: no scatter,
     the colliding scatter-add that stood here took 88% of a v5e's time)
@@ -2030,9 +2153,11 @@ def _compiled_sweep(fn_body, indep_stats, n_devices, block, result_max):
     lanes takes 77.7 ms, of it the kernel 59.8 (PERF.md).
 
     counts has n_devices+1 bins: the last collects ITEM_NONE/out-of-range
-    lanes and is dropped by the caller. With ``indep_stats`` the body is
-    ``_rule_body(..., indep_stats=True)`` and ``bad`` an int64 vector:
-    the bad mappings, then ``INDEP_TALLY``'s counters. An indep step
+    lanes and is dropped by the caller. With a ``tally`` the body
+    returns ``(mappings, stats)`` and ``bad`` is an int64 vector: the
+    bad mappings, then the tally's counters. ``INDEP_TALLY``: the body
+    is ``_rule_body(..., indep_stats=True)``, and the step adds the
+    holes. ``KERNEL_TALLY``: ``Mapper._kernel_body(..., tally=True)``. An indep step
     costs its rounds, and a round the width it runs at
     (``_choose_indep_block``): the first the block's, the later ones an
     eighth and then a 128th of it once no more lanes than that are
@@ -2043,7 +2168,7 @@ def _compiled_sweep(fn_body, indep_stats, n_devices, block, result_max):
         xs = x0 + jnp.arange(block, dtype=jnp.uint32)
         inb = jnp.arange(block, dtype=jnp.int64) < remaining
         w = fn_body(arrs, xs)                         # (block, rmax) int32
-        if indep_stats:
+        if tally:
             w, stats = w
         live = w != ITEM_NONE
         flat = jnp.where(live & inb[:, None], w, n_devices)
@@ -2052,8 +2177,11 @@ def _compiled_sweep(fn_body, indep_stats, n_devices, block, result_max):
         # a bad mapping is upstream's: fewer than result_max entries
         # or an ITEM_NONE among them (an indep rule's hole)
         short = (live.sum(axis=1) < result_max) & inb
-        if not indep_stats:
+        if not tally:
             return counts, bad + short.sum(dtype=jnp.int64)
+        if tally == KERNEL_TALLY:
+            return counts, bad + jnp.concatenate([
+                short.sum(dtype=jnp.int64)[None], stats.astype(jnp.int64)])
         # ``bad`` is the indep tally: bad mappings, then INDEP_TALLY
         holes = (~live & inb[:, None]).sum(dtype=jnp.int64)
         return counts, bad + jnp.concatenate([

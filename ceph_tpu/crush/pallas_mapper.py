@@ -58,7 +58,9 @@ CONTINUOUS weights (round 6): buckets whose slots carry more than
 MAX_CLASSES distinct weights — exactly what an upstream-style
 balancer's choose_args weight-set produces (every slot perturbed a few
 percent) — previously gated the whole map off the kernel and onto the
-~35x-slower XLA general path. The class decomposition degenerates
+XLA general path (how much slower that is on a v5e is not measured;
+what the continuous draw costs is: PERF.md §5-§6, PR 35). The class
+decomposition degenerates
 cleanly: treat EVERY slot as its own class. No within-class tie
 argument (and hence no ln-gap license G) is needed at all, because a
 one-slot class has no internal tie to break. Per-slot weights ride the
@@ -564,6 +566,18 @@ def build_plan(m: CrushMap, packed, ruleno: int,
         # into two sub-32768 values, so the same biased byte-plane
         # fetch stays exact).
         cont_l = any(bucket_cls[bid][0] == "cont" for bid in lvl)
+        if not cont_l and \
+                max(len(bucket_cls[bid][1]) for bid in lvl) == 1 and \
+                any(-1 in bucket_cls[bid][0] for bid in lvl):
+            # one weight class and a zero-weight slot (an OSD at CRUSH
+            # weight 0, a drained entry of a weight-set): the uniform
+            # layout has no row to say a slot is dead and would let it
+            # win. The per-slot layout draws a dead slot with w = 0,
+            # which never wins (_choose_level_cont's ``live``).
+            if any(w >= MAX_CONT_WEIGHT
+                   for bid in lvl for w in bucket_cls[bid][2]):
+                return None
+            cont_l = True
         if cont_l and S > MAX_CONT_SLOTS:
             # the continuous layout's table rows (4S+1) and phase-1
             # temps scale with the LEVEL's padded width S, not each

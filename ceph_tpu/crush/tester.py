@@ -32,6 +32,7 @@ class TestResult:
     path: str = ""                     # the engine that served the sweep
     min_x: int = 0
     indep: bool = False                # the rule's results keep holes
+    choose_args: int | None = None     # id of the weight-set that served
 
     @property
     def mappings_per_second(self) -> float:
@@ -66,9 +67,15 @@ class CrushTester:
                  device_weights: np.ndarray | None = None,
                  batch: int | None = None):
         self.map = crush_map
+        # the map's weight-set, resolved as upstream's tool resolves it:
+        # CrushTester::test calls do_rule(..., 0), so the set of id 0,
+        # else the compat set (-1), else none. A balanced map is tested
+        # as balanced, with no flag.
+        self.choose_args_key = crush_map.choose_args_with_fallback(0)
         # batch bounds device memory: it becomes the Mapper's tile size
         # (None = auto-sized from the map's bucket width)
-        self.mapper = Mapper(crush_map, device_weights, block=batch)
+        self.mapper = Mapper(crush_map, device_weights, block=batch,
+                             choose_args=self.choose_args_key)
         self.batch = self.mapper.block
         from ceph_tpu.utils.perf_counters import (PerfCountersBuilder,
                                                   PerfCountersCollection)
@@ -117,7 +124,8 @@ class CrushTester:
             rule=rule, num_rep=num_rep, total_x=n,
             device_counts=counts, bad_mappings=bad, seconds=seconds,
             mappings=kept, path=path, min_x=min_x,
-            indep=not self.mapper.rule_is_firstn(rule))
+            indep=not self.mapper.rule_is_firstn(rule),
+            choose_args=self.choose_args_key)
         log.dout(5, "test done", rule=rule, num_rep=num_rep, n=n,
                  secs=round(seconds, 3))
         return res

@@ -50,6 +50,10 @@ ITEM_NONE = 0x7FFFFFFF
 ITEM_UNDEF = 0x7FFFFFFE
 
 WEIGHT_ONE = 0x10000  # 16.16 fixed point 1.0
+# id of the compat weight-set in CrushMap.choose_args (ref:
+# CrushWrapper::DEFAULT_CHOOSE_ARGS): the one `ceph osd crush
+# weight-set create-compat` and the balancer's crush-compat mode keep
+COMPAT_CHOOSE_ARGS = -1
 
 
 @dataclass
@@ -160,6 +164,18 @@ class CrushMap:
 
     def bucket(self, item: int) -> Bucket:
         return self.buckets[item]
+
+    def choose_args_with_fallback(self, key: int) -> int | None:
+        """Which weight-set serves id ``key``: the set of that id, else
+        the compat set (-1, upstream's DEFAULT_CHOOSE_ARGS), else none
+        (ref: CrushWrapper::choose_args_get_with_fallback). ``key`` is
+        a pool's id for the OSDMap and 0 for ``crushtool --test``
+        (CrushTester::test calls ``do_rule(..., 0)``)."""
+        if key in self.choose_args:
+            return key
+        if COMPAT_CHOOSE_ARGS in self.choose_args:
+            return COMPAT_CHOOSE_ARGS
+        return None
 
     def is_bucket(self, item: int) -> bool:
         return item < 0

@@ -410,11 +410,7 @@ class OSDMap:
         """Weight-set selection: a pool-keyed entry wins, else the
         compat/default set (-1), else none (ref: CrushWrapper::
         choose_args_get_with_fallback)."""
-        if pool_id in self.crush.choose_args:
-            return pool_id
-        if -1 in self.crush.choose_args:
-            return -1
-        return None
+        return self.crush.choose_args_with_fallback(pool_id)
 
     def attach_mesh(self, mesh, mesh_min_batch: int | None = None):
         """Route bulk mapping sweeps over a device mesh (round 10):
