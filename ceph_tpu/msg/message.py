@@ -148,7 +148,12 @@ class Message:
         return cls(**kw)
 
     # -- framing ----------------------------------------------------------
-    def encode(self) -> bytes:
+    def encode_segments(self) -> tuple[list, int]:
+        """The encoding as the buffers the encoder made it of, and how
+        many of their bytes it referenced (``Encoder.segments``): a
+        large immutable blob field is in the list as the caller's own
+        object, for the messenger to gather on send; one run and 0
+        where no field is."""
         e = Encoder()
         e.u16(self.TYPE).u64(self.seq)
         self.encode_payload(e)
@@ -156,7 +161,10 @@ class Message:
         # stop at their payload's end, and old blobs (no trailing pair)
         # decode below with a zeroed context
         e.u64(self.trace_id).u64(self.parent_span_id)
-        return e.tobytes()
+        return e.segments()
+
+    def encode(self) -> bytes:
+        return b"".join(self.encode_segments()[0])
 
     @staticmethod
     def decode(data: bytes) -> "Message":

@@ -9,7 +9,25 @@ architecture mapped onto asyncio instead of epoll threads:
 - The wire protocol performs a banner + cephx-lite auth exchange, then
   length-prefixed frames carrying MSG/ACK/KEEPALIVE tags with a crc32
   trailer ('crc' mode) or an HMAC trailer ('secure' mode)
-  (ref: ProtocolV2 banner/auth frames, crc vs secure modes).
+  (ref: ProtocolV2 banner/auth frames, crc vs secure modes):
+  ``len u32 | tag u8 | seq u64 | body | crc32 u32``, ``len`` counting
+  tag, seq and body.
+- A bulk payload is not copied in user space between the message's
+  field and the socket, in either direction (``_Wire``). Sending: the
+  encoder *references* a blob that is immutable (``bytes``, or a
+  read-only ``memoryview`` of one) and at least ``denc.REF_MIN`` long,
+  and copies everything else (a ``bytearray``, a writable view, a short
+  blob), so only an object nobody can change is ever held after
+  ``send_message`` returns; the crc runs over the pieces and one
+  ``writelines`` (``sendmsg``) gathers them. A frame with nothing
+  referenced goes out as one joined buffer through ``write``; so does
+  every secure-mode frame (the seal makes a new ciphertext anyway).
+  Receiving: the socket is read into a reusable chunk, small frames
+  are cut out of it many to a ``recv``, and the rest of a frame that
+  is longer than what arrived of it lands by ``recv_into`` in a buffer
+  of the frame's own length; the body handed to ``Message.decode`` is
+  a view of that frame. Which path a frame takes is read from the
+  value's type and length alone.
 - ``Policy`` decides lossy vs lossless: lossless client connections
   keep unacked messages and resend them after a reconnect (the
   stateful-session half of ProtocolV2's reconnect/replay); lossy
@@ -27,9 +45,11 @@ from __future__ import annotations
 import asyncio
 import hmac
 import random
+import struct
 import time
 import traceback
 import zlib
+from collections import deque
 from dataclasses import dataclass
 
 from ceph_tpu.msg.auth import Authenticator, AuthError, Keyring
@@ -49,6 +69,57 @@ TAG_REKEY = 4   # secure mode: sender announces its next tx key epoch
 
 MODE_CRC = 1
 MODE_SECURE = 2
+
+# the reader's reusable buffer, and the most one recv asks for: what
+# the selector transport's own recv asks for; small frames are cut out
+# of it many to a recv
+CHUNK = 256 << 10
+# a frame that has not wholly arrived and is longer than this gets a
+# buffer of its own length for the rest to land in; a shorter one goes
+# on filling the chunk (it is copied out whole either way)
+_IN_PLACE_MIN = 32 << 10
+_FRAME_LEN = struct.Struct("<I").unpack_from
+_TAG_SEQ = struct.Struct("<BQ").unpack_from     # a frame's first 9 bytes
+
+
+class _Tally:
+    """How often the copy-free paths engage, process-wide and read as
+    ``crush.mapper.PERF`` is (``PERF.dump()``): the gather share of
+    the bytes sent is ``1 - tx_bytes_joined / tx_bytes``, the in-place
+    share of the bytes received ``rx_bytes_in_place / rx_bytes``.
+
+    Plain ints, bumped on every frame by the event loop's thread (a
+    process's messengers share its one loop; a second loop in a second
+    thread could lose an increment, nothing worse): the five locked
+    ``PerfCounters.inc`` a frame they would be cost 3-4 us of a small
+    frame's ~40 (PERF.md §6, PR 36).
+
+    tx_frames           frames handed to a socket
+    tx_bytes            their bytes, length prefix and trailer included
+    tx_bytes_joined     bytes copied in user space to build them: all
+                        of a joined frame, and of a gathered one
+                        everything but the blobs the encoder referenced
+    tx_frames_gathered  frames sent as their pieces in one writelines
+                        (a blob was referenced)
+    rx_frames           frames read off a socket
+    rx_bytes            bytes read off sockets once the handshake ended
+    rx_bytes_in_place   of them, those recv_into put directly into the
+                        buffer of their frame's own length
+    """
+
+    __slots__ = ("tx_frames", "tx_bytes", "tx_bytes_joined",
+                 "tx_frames_gathered", "rx_frames", "rx_bytes",
+                 "rx_bytes_in_place")
+
+    def __init__(self) -> None:
+        for key in self.__slots__:
+            setattr(self, key, 0)
+
+    def dump(self) -> dict[str, int]:
+        return {key: getattr(self, key) for key in self.__slots__}
+
+
+PERF = _Tally()
 
 
 class ConnectionError_(Exception):
@@ -113,7 +184,224 @@ class _Session:
 
     def __init__(self) -> None:
         self.out_seq = 0
-        self.unacked: list[tuple[int, bytes]] = []
+        # (seq, the body as Encoder.segments gave it)
+        self.unacked: list[tuple[int, tuple]] = []
+
+
+class _Wire(asyncio.streams.FlowControlMixin, asyncio.BufferedProtocol):
+    """One socket, both directions: the handshake's reads, frames read
+    in place, writes with the transport's flow control (``drain`` is
+    ``StreamWriter``'s, over the same ``FlowControlMixin``). It stands
+    where ``StreamReader``/``StreamWriter`` stood (a ``Connection``'s
+    ``reader`` and ``writer`` are one ``_Wire``) and owns its buffers.
+
+    The transport reads into ``get_buffer()``: the free end of a
+    reusable chunk, or, once a frame longer than ``_IN_PLACE_MIN`` has
+    arrived in part, the rest of a ``bytearray`` of that frame's own
+    length (checked against ``max_frame`` first). ``buffer_updated``
+    cuts the chunk's complete frames out as ``bytes`` (the chunk is
+    reused, and a ``blob_view`` field may outlive the read) and queues
+    them for ``read_frame``; a frame that filled its own buffer is
+    queued as that buffer. Reading pauses while ``CHUNK`` bytes or more
+    of completed frames wait for the connection's reader loop, so a
+    fast peer cannot queue frames without bound; what arrived behind
+    the handshake's last read is simply the chunk's first frames.
+    """
+
+    def __init__(self, on_accept=None):
+        super().__init__()
+        self._on_accept = on_accept     # the listening side's handler
+        self._task: asyncio.Task | None = None
+        self.transport: asyncio.Transport | None = None
+        self._chunk = memoryview(bytearray(CHUNK))
+        self._r = self._w = 0           # the chunk's unread bytes
+        self._big: memoryview | None = None   # a frame filling in place
+        self._big_w = 0
+        self._frames: deque = deque()   # complete, not yet read
+        self._queued = 0                # their bytes
+        self._trailer = -1              # bytes after a frame; -1: handshake
+        self._max_frame = 0
+        self._exc: Exception | None = None
+        self._reading = True
+        self._waiter: asyncio.Future | None = None    # the one reader
+
+    # -- transport callbacks -----------------------------------------------
+    def connection_made(self, transport) -> None:
+        self.transport = transport
+        self.write = transport.write    # not a wrapper: a call less
+        if self._on_accept is not None:
+            self._task = asyncio.ensure_future(self._on_accept(self))
+
+    def get_buffer(self, sizehint: int) -> memoryview:
+        if self._big is not None:
+            return self._big[self._big_w:]
+        return self._chunk[self._w:]    # never full: _parse leaves room
+
+    def buffer_updated(self, nbytes: int) -> None:
+        big = self._big
+        if big is not None:
+            PERF.rx_bytes += nbytes
+            PERF.rx_bytes_in_place += nbytes
+            self._big_w += nbytes
+            if self._big_w < len(big):
+                return
+            self._big = None
+            self._queue(big.obj)
+        else:
+            self._w += nbytes
+            if self._trailer < 0:               # handshake
+                if self._w == CHUNK and not self._compact():
+                    self._set_reading(False)    # readexactly resumes
+                self._wake()
+                return
+            PERF.rx_bytes += nbytes
+            self._parse()
+        if self._frames:
+            self._wake()
+
+    def eof_received(self) -> bool:
+        self._fail(ConnectionError_("connection closed by peer"))
+        return False                    # nothing more to say either
+
+    def connection_lost(self, exc) -> None:
+        self._fail(exc or ConnectionError_("connection lost"))
+        super().connection_lost(exc)    # wakes whoever drains
+
+    # -- reading -----------------------------------------------------------
+    def _fail(self, exc: Exception) -> None:
+        if self._exc is None:
+            self._exc = exc
+        self._wake()
+
+    def _wake(self) -> None:
+        w = self._waiter
+        if w is not None and not w.done():
+            w.set_result(None)
+
+    async def _wait(self) -> None:
+        self._waiter = asyncio.get_running_loop().create_future()
+        try:
+            await self._waiter
+        finally:
+            self._waiter = None
+
+    def _set_reading(self, on: bool) -> None:
+        if on == self._reading or (on and self._exc is not None):
+            return
+        self._reading = on
+        if on:
+            self.transport.resume_reading()
+        else:
+            self.transport.pause_reading()
+
+    def _compact(self) -> bool:
+        """Move the chunk's unread bytes to its start; was there room
+        to gain?"""
+        r, n = self._r, self._w - self._r
+        if not r:
+            return False
+        self._chunk[:n] = self._chunk[r:r + n]      # memmove
+        self._r, self._w = 0, n
+        return True
+
+    def _queue(self, frame: bytes | bytearray) -> None:
+        self._frames.append(frame)
+        self._queued += len(frame)
+        PERF.rx_frames += 1
+        # a reader that waits takes the frame before the loop polls the
+        # socket again: pausing for it would be two epoll_ctl for nothing
+        if self._queued >= CHUNK and self._waiter is None:
+            self._set_reading(False)
+
+    def _parse(self) -> None:
+        """Cut the chunk's complete frames out and leave the next read
+        room for the rest of the one that is not: in a buffer of its
+        own if it is long, else at the chunk's end."""
+        mv, r, w, t = self._chunk, self._r, self._w, self._trailer
+        while w - r >= 4:
+            ln, = _FRAME_LEN(mv, r)
+            if ln < 9 or ln > self._max_frame:
+                # before any buffer of that length exists
+                self._set_reading(False)
+                self._fail(ConnectionError_(f"bad frame length {ln}"))
+                break
+            end = r + 4 + ln + t
+            if end <= w:
+                self._queue(bytes(mv[r + 4:end]))
+                r = end
+                continue
+            if ln + t > _IN_PLACE_MIN:
+                self._big = memoryview(bytearray(ln + t))
+                self._big_w = w - r - 4
+                self._big[:self._big_w] = mv[r + 4:w]
+                w = r
+            break
+        if r == w:
+            self._r = self._w = 0
+        else:
+            self._r = r
+            if r + 4 + _IN_PLACE_MIN > CHUNK:
+                self._compact()
+
+    def start_frames(self, trailer: int, max_frame: int) -> None:
+        """The handshake is over: what the chunk holds, and all that
+        follows, is frames with ``trailer`` bytes after each."""
+        self._trailer, self._max_frame = trailer, max_frame
+        PERF.rx_bytes += self._w - self._r
+        self._parse()
+        if self._queued < CHUNK:
+            self._set_reading(True)
+
+    async def readexactly(self, n: int) -> bytes:
+        """A read of the handshake (before ``start_frames``)."""
+        if n > CHUNK:
+            raise ConnectionError_(f"handshake read of {n} bytes")
+        while self._w - self._r < n:
+            if self._exc is not None:
+                raise self._exc
+            if self._r + n > CHUNK:
+                self._compact()
+            self._set_reading(True)
+            await self._wait()
+        out = bytes(self._chunk[self._r:self._r + n])
+        self._r += n
+        if self._r == self._w:
+            self._r = self._w = 0
+        return out
+
+    async def read_frame(self) -> bytes | bytearray:
+        """The next frame without its length prefix (``tag | seq |
+        body | trailer``): ``bytes`` if it was cut out of the chunk,
+        the ``bytearray`` it landed in if it had one. Frames that were
+        complete before the connection failed are still delivered."""
+        while not self._frames:
+            if self._exc is not None:
+                raise self._exc
+            await self._wait()
+        frame = self._frames.popleft()
+        self._queued -= len(frame)
+        if not self._reading and self._queued < CHUNK:
+            self._set_reading(True)
+        return frame
+
+    # -- writing: write() is the transport's --------------------------------
+    def writelines(self, pieces) -> None:
+        # write() drops what is written to a closed transport and
+        # drain() then raises; the transport's writelines() has no
+        # such guard and would call into a socket that is gone
+        if not self.transport.is_closing():
+            self.transport.writelines(pieces)
+
+    async def drain(self) -> None:
+        """``StreamWriter.drain``: wait while the transport's buffer is
+        over its high-water mark; raise once the connection is lost."""
+        if self.transport.is_closing():
+            await asyncio.sleep(0)      # let connection_lost() be called
+        await self._drain_helper()
+
+    def close(self) -> None:
+        if self.transport is not None:
+            self.transport.close()
 
 
 class Connection:
@@ -134,7 +422,8 @@ class Connection:
         self.policy = policy
         self.out_seq = 0
         self.in_seq = 0
-        self.unacked: list[tuple[int, bytes]] = []   # lossless replay queue
+        # lossless replay queue: (seq, the body's segments and count)
+        self.unacked: list[tuple[int, tuple]] = []
         # outgoing lossless conns share per-peer-address session state
         # (seq counter + replay queue) across reconnects
         self.session: "_Session | None" = None
@@ -152,18 +441,23 @@ class Connection:
         # the ``msg.recv`` section the reader loop emits once the
         # decoded message says whose op the frame belonged to
         self._rx_t0 = self._rx_t1 = 0
+        # the handshake is over: what follows it, the part already in
+        # the reader's chunk too (the peer may send straight behind
+        # its last handshake bytes), is frames
+        reader.start_frames(0 if self._secure() else 4, msgr.max_frame)
 
     def _secure(self) -> bool:
         return self.msgr.mode == MODE_SECURE and self.auth is not None
 
     # -- framing -----------------------------------------------------------
-    def _trailer(self, seq: int, body: bytes) -> bytes:
-        return zlib.crc32(body).to_bytes(4, "little")
-
-    async def _send_frame(self, tag: int, seq: int, body: bytes,
+    async def _send_frame(self, tag: int, seq: int, body=((), 0),
                           ctx: Message | None = None) -> None:
-        """``ctx``: the message the frame carries, where the caller
-        has it — whose op the ``msg.send`` section belongs to."""
+        """``body``: the buffers it is made of and how many of their
+        bytes the encoder referenced, as ``Encoder.segments`` gives
+        them (nothing for an ACK). Any referenced: the frame is
+        gathered, not joined. ``ctx``: the message the frame carries,
+        where the caller has it — whose op the ``msg.send`` section
+        belongs to."""
         inj = self.msgr.faults
         if inj is not None:
             act = inj.on_frame(self.msgr.name, self.peer_name)
@@ -182,55 +476,68 @@ class Connection:
                                  self.msgr.name) as sec:
                 head = tag.to_bytes(1, "little") + \
                     seq.to_bytes(8, "little")
+                segs, referenced = body
                 if self._secure():
                     # AEAD: header authenticated as AAD, body encrypted;
                     # no separate trailer (the GCM tag rides in the
-                    # ciphertext)
+                    # ciphertext). The seal makes a new buffer anyway,
+                    # so a secure frame is always one joined write
                     ct = self.auth.seal(0 if self.is_client else 1,
                                         self._tx_epoch, tag, seq, head,
-                                        body)
-                    wire = head + ct
-                    trailer = b""
+                                        b"".join(segs))
+                    n = 9 + len(ct)
+                    pieces = (n.to_bytes(4, "little"), head, ct)
+                    sent, referenced = 4 + n, 0
                 else:
-                    wire = head + body
-                    trailer = self._trailer(seq, wire)
-                sec.tag("bytes", len(wire))
-                self.writer.write(len(wire).to_bytes(4, "little") +
-                                  wire + trailer)
+                    crc, n = zlib.crc32(head), 9
+                    for seg in segs:
+                        crc = zlib.crc32(seg, crc)
+                        n += len(seg)
+                    pieces = (n.to_bytes(4, "little"), head, *segs,
+                              crc.to_bytes(4, "little"))
+                    sent = 8 + n
+                if sec:
+                    sec.tag("bytes", n).tag("segs", len(segs))
+                if referenced:
+                    self.writer.writelines(pieces)      # one sendmsg
+                    PERF.tx_frames_gathered += 1
+                else:
+                    self.writer.write(b"".join(pieces))
+                PERF.tx_frames += 1
+                PERF.tx_bytes += sent
+                PERF.tx_bytes_joined += sent - referenced
             await self.writer.drain()
         except (ConnectionError, OSError) as e:
             self._abort()
             raise ConnectionError_(str(e)) from e
 
-    async def _recv_frame(self) -> tuple[int, int, bytes]:
+    async def _recv_frame(self) -> tuple[int, int, bytes | memoryview]:
         try:
-            ln = int.from_bytes(await self.reader.readexactly(4), "little")
-            if ln < 9 or ln > self.msgr.max_frame:
-                raise ConnectionError_(f"bad frame length {ln}")
-            frame = await self.reader.readexactly(ln)
-            trailer = b"" if self._secure() \
-                else await self.reader.readexactly(4)
-        except (asyncio.IncompleteReadError, ConnectionError, OSError) as e:
+            frame = await self.reader.read_frame()
+        except (ConnectionError, OSError) as e:
             raise ConnectionError_(str(e)) from e
         if self.msgr._inject_failure():
             self._abort()
             raise ConnectionError_("injected socket failure (recv)")
         self._rx_t0 = _clock()
-        tag = frame[0]
-        seq = int.from_bytes(frame[1:9], "little")
+        # views from here on: a slice of the frame is a copy of it
+        mv = memoryview(frame)
+        if not mv.readonly:
+            mv = mv.toreadonly()
+        tag, seq = _TAG_SEQ(mv)
         if self._secure():
-            from ceph_tpu.msg.auth import AuthError as _AE
             try:
-                body = self.auth.open(0 if not self.is_client else 1,
-                                      self._rx_epoch, tag, seq,
-                                      frame[:9], frame[9:])
-            except _AE as e:
+                body = self.auth.open(
+                    0 if not self.is_client else 1, self._rx_epoch,
+                    tag, seq, mv[:9], mv[9:])
+            except AuthError as e:
                 raise ConnectionError_(str(e)) from e
         else:
-            if not hmac.compare_digest(self._trailer(seq, frame),
-                                       trailer):
+            end = len(mv) - 4
+            if not hmac.compare_digest(
+                    zlib.crc32(mv[:end]).to_bytes(4, "little"), mv[end:]):
                 raise ConnectionError_("frame integrity check failed")
-            body = frame[9:]
+            body = mv[9:end]
         self._rx_t1 = _clock()
         return tag, seq, body
 
@@ -270,7 +577,7 @@ class Connection:
             return
         new_epoch = self._tx_epoch + 1
         body, secret = self._rekey_material(new_epoch)
-        await self._send_frame(TAG_REKEY, 0, body)
+        await self._send_frame(TAG_REKEY, 0, ((body,), 0))
         if secret is not None:
             self.auth.install_secret(0 if self.is_client else 1,
                                      secret, new_epoch)
@@ -309,9 +616,10 @@ class Connection:
             msg.seq = seq
             with tracing.section("msg.encode", msg, self.msgr.tracer,
                                  self.msgr.name) as sec:
-                body = msg.encode()
-                sec.tag("type", type(msg).__name__).tag(
-                    "bytes", len(body))
+                body = msg.encode_segments()
+                if sec:
+                    sec.tag("type", type(msg).__name__).tag(
+                        "bytes", sum(map(len, body[0])))
             if not self.policy.lossy:
                 (sess.unacked if sess is not None
                  else self.unacked).append((seq, body))
@@ -331,7 +639,7 @@ class Connection:
         # under the old epoch can hit the wire AFTER the REKEY frame
         # and fail decryption on a peer that already flipped rx_epoch
         async with self._send_lock:
-            await self._send_frame(TAG_ACK, seq, b"")
+            await self._send_frame(TAG_ACK, seq)
 
     def _handle_ack(self, seq: int) -> None:
         if self.session is not None:
@@ -359,7 +667,7 @@ class Connection:
             new_epoch = self._tx_epoch + 1
             body, secret = self._rekey_material(new_epoch)
             try:
-                await self._send_frame(TAG_REKEY, 0, body)
+                await self._send_frame(TAG_REKEY, 0, ((body,), 0))
             except ConnectionError_:
                 return               # dead conn: nothing left to rekey
             if secret is not None:
@@ -523,7 +831,8 @@ class Messenger:
 
     async def bind(self, host: str = "127.0.0.1",
                    port: int = 0) -> EntityAddr:
-        self._server = await asyncio.start_server(self._accept, host, port)
+        self._server = await asyncio.get_running_loop().create_server(
+            lambda: _Wire(self._accept), host, port)
         sock = self._server.sockets[0]
         self.addr = EntityAddr(*sock.getsockname()[:2])
         if self.policy.throttler_bytes:
@@ -531,15 +840,15 @@ class Messenger:
         return self.addr
 
     # -- handshake ---------------------------------------------------------
-    async def _accept(self, reader, writer) -> None:
+    async def _accept(self, wire: _Wire) -> None:
         try:
             conn = await asyncio.wait_for(
-                self._server_handshake(reader, writer),
+                self._server_handshake(wire, wire),
                 timeout=self.handshake_timeout)
         except (AuthError, ConnectionError_, ConnectionError, OSError,
-                asyncio.IncompleteReadError, asyncio.TimeoutError) as e:
+                asyncio.TimeoutError) as e:
             log.dout(5, f"accept failed: {e}")
-            writer.close()
+            wire.close()
             return
         self._accepted.add(conn)
         self._index_conn(conn)
@@ -586,14 +895,15 @@ class Messenger:
             # partitioned pair: the SYN never lands
             raise ConnectionError_(
                 f"injected partition: {self.name} -> {peer_name}")
-        reader, writer = await asyncio.open_connection(addr.host, addr.port)
+        _, wire = await asyncio.get_running_loop().create_connection(
+            _Wire, addr.host, addr.port)
         try:
             return await asyncio.wait_for(
-                self._client_handshake_inner(reader, writer, addr,
+                self._client_handshake_inner(wire, wire, addr,
                                              peer_name),
                 timeout=self.handshake_timeout)
         except BaseException:
-            writer.close()
+            wire.close()
             raise
 
     async def _client_handshake_inner(self, reader, writer,
@@ -677,8 +987,7 @@ class Messenger:
             if conn is None or conn.closed:
                 try:
                     conn = await self._client_handshake(addr, peer_name)
-                except (ConnectionError_, ConnectionError, OSError,
-                        asyncio.IncompleteReadError):
+                except (ConnectionError_, ConnectionError, OSError):
                     await asyncio.sleep(0.05 * (attempt + 1))
                     continue
                 self._attach(addr, conn)
