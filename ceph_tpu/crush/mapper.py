@@ -1921,7 +1921,23 @@ class Mapper:
                    _expected: str | None = None):
         """``sweep`` returning ``(counts, bad, path)`` — ``path`` is
         the engine THIS sweep executed on (see map_pgs_path for the
-        per-call discipline and the ``_expected`` retry threading)."""
+        per-call discipline and the ``_expected`` retry threading).
+
+        The call is the section ``crush.sweep`` (tags ``lanes``,
+        ``blocks``, ``width``, an indep rule's ``narrow_width``): its
+        self time is the prelude and the counters; each block's
+        ``jit_call`` is a ``crush.dispatch``, the forced first-block read
+        a ``crush.force``, the tally's read a ``crush.readback``. A
+        kernel-failure retry nests inside the failed call's section."""
+        with tracing.section("crush.sweep", service="crush") as sec:
+            if sec:
+                sec.tag("lanes", int(n))
+            return self._sweep_path(sec, ruleno, start_x, n, result_max,
+                                    device_counts_size, _expected)
+
+    def _sweep_path(self, sec, ruleno: int, start_x: int, n: int,
+                    result_max: int, device_counts_size: int | None,
+                    _expected: str | None):
         nd_ = device_counts_size or self.packed.max_devices
         if self._scalar_reason:    # legacy fallback: host aggregation
             PERF.inc("pgs_mapped", int(n))
@@ -1962,8 +1978,6 @@ class Mapper:
         dm = _devmon()
         nblocks = lanes = width = 0
         forced = None
-        sec = tracing.section("crush.indep_block", service="crush") \
-            if indep else None
         try:
             with jax.enable_x64(True):
                 counts = jnp.zeros(nd + 1, dtype=jnp.int64)
@@ -1976,13 +1990,17 @@ class Mapper:
                     block = self._block_for(kb_kern, n - lanes)
                     step_fn = _compiled_sweep(fn_body, tally, nd, block,
                                               result_max)
-                    counts, bad = dm.jit_call(
-                        "crush_sweep",
-                        self._jit_key(ruleno, result_max, kb_kern,
-                                      (block, nd, firstn)), step_fn,
-                        self.arrays, counts, bad,
-                        jnp.uint32((start_x + lanes) % (1 << 32)),
-                        jnp.int64(n - lanes))
+                    key = self._jit_key(ruleno, result_max, kb_kern,
+                                        (block, nd, firstn))
+                    x0 = jnp.uint32((start_x + lanes) % (1 << 32))
+                    left = jnp.int64(n - lanes)
+                    with tracing.section("crush.dispatch",
+                                         service="crush") as d:
+                        if d:
+                            d.tag("block", nblocks)
+                        counts, bad = dm.jit_call(
+                            "crush_sweep", key, step_fn, self.arrays,
+                            counts, bad, x0, left)
                     nblocks += 1
                     lanes += block
                     width = max(width, block)
@@ -1995,7 +2013,9 @@ class Mapper:
                         # reveal a compile/launch fault, and the rest
                         # still pipeline (a narrower program runs last,
                         # where the caller's read-back follows anyway).
-                        np.asarray(counts[0])
+                        with tracing.section("crush.force",
+                                             service="crush"):
+                            np.asarray(counts[0])
                         forced = block
         except Exception as e:
             if kb is None:
@@ -2006,14 +2026,16 @@ class Mapper:
                                    _expected=_expected)
         if tally:
             # the tally comes back with the counts' read-back: one read
-            counts, bad = jax.device_get((counts, bad))
+            with tracing.section("crush.readback", service="crush"):
+                counts, bad = jax.device_get((counts, bad))
             for name, v in zip(tally, bad[1:]):
                 PERF.inc(name, int(v))
             bad = bad[0]
-        if indep:
-            sec.tag("lanes", int(n)).tag("width", width) \
-                .tag("narrow_width", next(iter(narrow_widths(width)), 0))
-            sec.finish()
+        if sec:
+            sec.tag("blocks", nblocks).tag("width", width)
+            if indep:
+                sec.tag("narrow_width",
+                        next(iter(narrow_widths(width)), 0))
         path = self.mapping_path(ruleno, result_max)
         PERF.inc("pgs_mapped", int(n))       # success only (no double
         PERF.inc("sweep_blocks", nblocks)    # count via the retry)
@@ -2030,9 +2052,10 @@ class Mapper:
             counts, bad = _ss.sharded_sweep(self.mesh, self, ruleno,
                                             start_x, n, result_max)
             if kb is not None:
-                with jax.enable_x64(True):      # x64: counts is int64 and
-                    np.asarray(counts[0])    # the getitem traces; force
-                # execution (see sweep)
+                with jax.enable_x64(True), \
+                        tracing.section("crush.force", service="crush"):
+                    # x64: counts is int64 and the getitem traces;
+                    np.asarray(counts[0])    # force execution (see sweep)
         except Exception as e:
             if kb is None:
                 raise                        # XLA path: a real error

@@ -56,6 +56,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ceph_tpu.utils import tracing
+from ceph_tpu.utils.devmon import devmon as _devmon
+
 
 # Below this many lanes the per-shard dispatch overhead outweighs the
 # parallelism (the crossover is not re-measured on a local chip);
@@ -168,7 +171,6 @@ def sharded_map_pgs(mesh, mapper, ruleno: int, xs,
         fn_body, used_kernel = _fn_body(mapper, ruleno, result_max)
         fn = _shard_fn(mapper, used_kernel, _compiled_sharded_map,
                        fn_body, mesh, block, local_n, result_max)
-        from ceph_tpu.utils.devmon import devmon as _devmon
         out = _devmon().jit_call(
             "crush_sharded_map",
             mapper._jit_key(ruleno, result_max, used_kernel,
@@ -232,26 +234,34 @@ def sharded_sweep(mesh, mapper, ruleno: int, start_x: int, n: int,
     Any ``n`` is accepted (tail lanes mask out of the accumulation);
     the range wraps modulo 2^32 as ``Mapper.sweep``'s does.
     Returns (counts (max_devices,), bad) replicated on every device,
-    equal to the single-device sweep's."""
+    equal to the single-device sweep's, unread: the caller's read-back
+    is the sweep's one sync. The call is the section ``crush.sweep``
+    (tags ``lanes``, ``blocks`` a shard, ``width``), the program's call
+    a ``crush.dispatch`` (as ``Mapper.sweep_path``'s)."""
     if getattr(mapper, "_scalar_reason", None):
         raise ValueError(
             f"map uses legacy tunables ({mapper._scalar_reason}); the "
             f"scalar fallback cannot shard — use Mapper.sweep")
-    ndev = mesh.devices.size
-    nd = mapper.packed.max_devices
-    local_n, block = _shard_widths(mapper, ruleno, result_max,
-                                   max(1, -(-n // ndev)))
-    fn_body, used_kernel = _fn_body(mapper, ruleno, result_max)
-    fn = _shard_fn(mapper, used_kernel, _compiled_sharded_sweep,
-                   fn_body, nd, mesh, block, local_n, result_max)
-    from ceph_tpu.utils.devmon import devmon as _devmon
-    with jax.enable_x64(True):
-        out = _devmon().jit_call(
-            "crush_sharded_sweep",
-            mapper._jit_key(ruleno, result_max, used_kernel,
-                            ("sharded", local_n, block, nd)),
-            fn, mapper.arrays, jnp.uint32(start_x % (1 << 32)),
-            jnp.int64(n))
-    mapper.last_map_path = \
-        mapper.mapping_path(ruleno, result_max) + "+sharded"
-    return out
+    with tracing.section("crush.sweep", service="crush") as sec:
+        ndev = mesh.devices.size
+        nd = mapper.packed.max_devices
+        local_n, block = _shard_widths(mapper, ruleno, result_max,
+                                       max(1, -(-n // ndev)))
+        if sec:
+            sec.tag("lanes", int(n)).tag("blocks", -(-local_n // block)) \
+                .tag("width", block)
+        fn_body, used_kernel = _fn_body(mapper, ruleno, result_max)
+        fn = _shard_fn(mapper, used_kernel, _compiled_sharded_sweep,
+                       fn_body, nd, mesh, block, local_n, result_max)
+        key = mapper._jit_key(ruleno, result_max, used_kernel,
+                              ("sharded", local_n, block, nd))
+        with jax.enable_x64(True):
+            x0, total = jnp.uint32(start_x % (1 << 32)), jnp.int64(n)
+            with tracing.section("crush.dispatch", service="crush") as d:
+                if d:
+                    d.tag("block", 0)
+                out = _devmon().jit_call("crush_sharded_sweep", key, fn,
+                                         mapper.arrays, x0, total)
+        mapper.last_map_path = \
+            mapper.mapping_path(ruleno, result_max) + "+sharded"
+        return out

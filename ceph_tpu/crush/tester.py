@@ -15,6 +15,7 @@ import numpy as np
 
 from ceph_tpu.crush.mapper import Mapper
 from ceph_tpu.crush.types import CrushMap, ITEM_NONE
+from ceph_tpu.utils import tracing
 from ceph_tpu.utils.logging import get_logger
 
 log = get_logger("crush")
@@ -100,35 +101,44 @@ class CrushTester:
         fewer than num_rep entries (a short firstn result) or any
         CRUSH_ITEM_NONE (an indep rule's hole), so ``crushtool --test
         --show-bad-mappings`` says of an EC rule what it says upstream.
+
+        The call is the section ``crush.test``, whose self time is the
+        tester's own (counters, the result, the log line); the reads
+        that bring the counts and the bad count home are its
+        ``crush.readback``.
         """
-        n = max_x - min_x + 1
-        t0 = time.perf_counter()
-        if keep_mappings:
-            kept, path = self.mapper.map_pgs_path(
-                rule, np.arange(min_x, max_x + 1, dtype=np.uint32), num_rep)
-            kept = np.asarray(kept)
-            counts = np.bincount(kept[kept != ITEM_NONE],
-                                 minlength=self.map.max_devices)
-            bad = int(_bad_rows(kept).sum())
-        else:
-            counts_dev, bad_dev, path = self.mapper.sweep_path(
-                rule, min_x, n, num_rep)
-            counts = np.asarray(counts_dev)     # readback = execution anchor
-            bad = int(bad_dev)
-            kept = None
-        seconds = time.perf_counter() - t0
-        self.perf.inc("mappings", n)
-        self.perf.inc("bad_mappings", bad)
-        self.perf.tinc("map_seconds", seconds)
-        res = TestResult(
-            rule=rule, num_rep=num_rep, total_x=n,
-            device_counts=counts, bad_mappings=bad, seconds=seconds,
-            mappings=kept, path=path, min_x=min_x,
-            indep=not self.mapper.rule_is_firstn(rule),
-            choose_args=self.choose_args_key)
-        log.dout(5, "test done", rule=rule, num_rep=num_rep, n=n,
-                 secs=round(seconds, 3))
-        return res
+        with tracing.section("crush.test", service="crush"):
+            n = max_x - min_x + 1
+            t0 = time.perf_counter()
+            if keep_mappings:
+                kept, path = self.mapper.map_pgs_path(
+                    rule, np.arange(min_x, max_x + 1, dtype=np.uint32),
+                    num_rep)
+                with tracing.section("crush.readback", service="crush"):
+                    kept = np.asarray(kept)
+                counts = np.bincount(kept[kept != ITEM_NONE],
+                                     minlength=self.map.max_devices)
+                bad = int(_bad_rows(kept).sum())
+            else:
+                counts_dev, bad_dev, path = self.mapper.sweep_path(
+                    rule, min_x, n, num_rep)
+                with tracing.section("crush.readback", service="crush"):
+                    counts = np.asarray(counts_dev)  # the execution anchor
+                    bad = int(bad_dev)
+                kept = None
+            seconds = time.perf_counter() - t0
+            self.perf.inc("mappings", n)
+            self.perf.inc("bad_mappings", bad)
+            self.perf.tinc("map_seconds", seconds)
+            res = TestResult(
+                rule=rule, num_rep=num_rep, total_x=n,
+                device_counts=counts, bad_mappings=bad, seconds=seconds,
+                mappings=kept, path=path, min_x=min_x,
+                indep=not self.mapper.rule_is_firstn(rule),
+                choose_args=self.choose_args_key)
+            log.dout(5, "test done", rule=rule, num_rep=num_rep, n=n,
+                     secs=round(seconds, 3))
+            return res
 
     def bad_mapping_lines(self, res: TestResult) -> list[str]:
         """``--show-bad-mappings``' line for every bad mapping of

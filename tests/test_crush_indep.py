@@ -337,24 +337,27 @@ def test_a_firstn_sweep_moves_no_indep_counter():
 def test_the_indep_sweep_is_a_tracing_section(monkeypatch):
     from ceph_tpu.utils import tracing
     m, rid, w, _rm, _steps = _maps("in")
-    seen, made = [], []
+    made = []
 
     def section(name, ctx=None, tracer=None, service=""):
         # a Span as a profiler session would have it: its tags are kept
-        seen.append(name)
         made.append(tracing.Span(tracer, name, 0, 0, None, tracing.SECTION,
                                  service))
         return made[-1]
     monkeypatch.setattr(mapper_mod.tracing, "section", section)
+
+    def sweep_tags():
+        found = [s.tags for s in made if s.name == "crush.sweep"]
+        made.clear()
+        return found
     CrushTester(m, w, batch=64).test(rid, 6, 0, 63)
-    assert seen == ["crush.indep_block"]
-    assert made[0].tags == {"lanes": 64, "width": 64, "narrow_width": 0}
+    assert sweep_tags() == [{"lanes": 64, "blocks": 1, "width": 64,
+                             "narrow_width": 0}]
     assert mapper_mod.narrow_widths(1 << 20) == (1 << 17, 1 << 13)
-    seen.clear()
     root = m.rules[rid].steps[2].arg1
     firstn = builder.add_simple_rule(m, root, builder.TYPE_HOST)
     CrushTester(m, w, batch=64).test(firstn, 3, 0, 63)
-    assert seen == []
+    assert sweep_tags() == [{"lanes": 64, "blocks": 1, "width": 64}]
 
 
 def _block_program(m, w, rid, width, n):
