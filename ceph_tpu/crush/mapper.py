@@ -38,36 +38,36 @@ of O(N) ever crosses the host boundary.
 Performance techniques (each cross-checked bit-exact vs mapper_ref):
 - uniform-weight exact draw shortcut (round 3, the big one — 17x):
   element gathers cost ~7-9 ns/element on this platform, so the 64K
-  negln lookup dominated everything; for buckets whose items share one
-  weight w <= the minimum positive crush_ln gap (~2^28.5 — every
-  real-world bucket), draw ties are provably exactly the ln-equality
-  hash pairs (ln_table.ln_gap_info), so the winner is argmax of the raw
-  16-bit hashes with an adjacent-pair tie repair — no ln table, no
-  divide, no int64 (see _straw2_uniform_choose);
+  ln-table gather the general draw then made dominated everything; for
+  buckets whose items share one weight w <= the minimum positive
+  crush_ln gap (~2^28.5 — every real-world bucket), draw ties are
+  provably exactly the ln-equality hash pairs (ln_table.ln_gap_info),
+  so the winner is argmax of the raw 16-bit hashes with an
+  adjacent-pair tie repair — no ln table, no divide, no int64 (see
+  _straw2_uniform_choose);
 - the ln-equality predicate and other tiny-table lookups run as one-hot
   matmuls on the MXU instead of gathers (_zg_pair);
 - per-bucket scalars ride ONE packed (B,1) meta word (size|alg|btype)
   row-gathered once per descent level and carried to the next;
 - is_out compiles to False when every device weight is full
   (cfg["skip_is_out"], part of the jit key);
-- general path (mixed weights / choose_args): precomputed 64K-entry
-  negated-ln table, magic-multiply exact division (no 64-bit divider on
-  TPU), speculative parallel tries replacing most while_loop retry
-  iterations, and static descent-depth unrolling.
+- general path (mixed weights / choose_args): crush_ln by the kernel's
+  exact fixed-point ladder (129- and 256-entry tables fetched by one-hot
+  matmuls, no element gather; see _straw2_choose), magic-multiply exact
+  division (no 64-bit divider on TPU), speculative parallel tries
+  replacing most while_loop retry iterations, and static descent-depth
+  unrolling.
 
 Mapping engine layers (round 6, mesh layer round 10): this module is
 the bottom of the serving stack —
 - **Mapper** (here): batched device mapping. The fused Pallas kernel
   (``pallas_mapper``) now serves arbitrary continuous per-item weights
-  and single-position choose_args weight-sets: the 64K-entry negln
-  fixed-point lookup decomposes into two 256-wide one-hot matmuls
-  (hi/lo byte split, same MXU trick as ``_zg_pair``), so a
+  and single-position choose_args weight-sets: crush_ln runs as an
+  exact fixed-point ladder whose RH/LH and LL tables are fetched by
+  one-hot matmuls (same MXU trick as ``_zg_pair``), so a
   balancer-style weight-set no longer falls off the kernel onto the
-  XLA gather path (how much slower that path is on a v5e is not
-  measured; on the kernel the 10,240-OSD map with a compat weight-set
-  sweeps at 10.6 M mappings/s against 23.7 M without one, 2.2 times
-  slower, half of a sweep the flagged-lane recompute: PERF.md §5-§6,
-  PR 35). Since
+  XLA path; the lanes the kernel flags are recomputed on the XLA
+  general path by the same ladder (PERF.md §5-§6). Since
   round 15 its descent is level-major with the replica-candidate axis
   folded into the lane axis — one fused fetch+choose per level for
   ALL candidates, O(l_total) MXU ops independent of numrep
@@ -107,7 +107,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ceph_tpu.crush import hash as h
-from ceph_tpu.crush.ln_table import crush_ln
+from ceph_tpu.crush import pallas_mapper as _pm
 from ceph_tpu.crush.tensors import PackedMap, pack_map
 from ceph_tpu.crush.types import (
     ALG_LIST, ALG_STRAW, ALG_STRAW2, ALG_TREE, ALG_UNIFORM,
@@ -180,8 +180,8 @@ PERF = (_PCB("crush_mapper")
                          "of flagged lanes ran (any lane flagged)")
         .add_u64_counter("kernel_fallback_overflows",
                          "sweep blocks whose flagged lanes exceeded "
-                         "the fallback buffer and were recomputed at "
-                         "full width on the XLA general path")
+                         "the fallback buffer, which then took more "
+                         "than one pass of it")
         .add_u64_counter("indep_blocks",
                          "choose_indep blocks a sweep ran on the rule VM")
         .add_u64_counter("indep_rounds",
@@ -210,33 +210,23 @@ PERF = (_PCB("crush_mapper")
         .create_perf_counters())
 
 
-@functools.lru_cache(maxsize=None)
-def _negln_table() -> np.ndarray:
-    """negln[u] = 2^48 - crush_ln(u) for u in [0, 0xffff]: the negated
-    straw2 draw numerator, precomputed once (crush_ln is pure and its
-    domain is 16 bits — the whole function becomes one gather)."""
-    t = (np.int64(1) << 48) - np.asarray(
-        crush_ln(np.arange(0x10000, dtype=np.int64)), dtype=np.int64)
-    t.flags.writeable = False
-    return t
-
-
 def _u32(v):
     return v.astype(jnp.uint32)
 
 
 @functools.lru_cache(maxsize=1)
 def _staged_const_tables():
-    """The map-INDEPENDENT device tables — negln (64K-entry straw2
-    numerator) and the zg ln-equality factorization — staged once per
-    process. Every Mapper used to re-ship both (~0.8 MiB) on
-    construction; each transfer pays a fixed latency, and the balancer
-    rebuilds a Mapper per map mutation, so the constants were a
-    standing tax on pack_seconds."""
+    """The map-INDEPENDENT device tables — crush_ln's RH/LH and LL byte
+    planes (the straw2 draw's ladder, ``pallas_mapper._ln_plane_tables``)
+    and the zg ln-equality factorization — staged once per process.
+    Every Mapper used to re-ship them on construction; each transfer
+    pays a fixed latency, and the balancer rebuilds a Mapper per map
+    mutation, so the constants were a standing tax on pack_seconds."""
     with jax.enable_x64(True):
         from ceph_tpu.crush.ln_table import ln_gap_info
         _, zg = ln_gap_info()
-        return (jnp.asarray(_negln_table(), dtype=jnp.int64),
+        rhlh, ll = _pm._ln_plane_tables()
+        return (jnp.asarray(rhlh), jnp.asarray(ll),
                 jnp.asarray(zg.reshape(256, 256), dtype=jnp.float32))
 
 
@@ -287,13 +277,41 @@ def _straw2_uniform_choose(arrs, rows, x, r, u, posmask, items):
     return jnp.sum(jnp.where(first, items, 0), axis=1, dtype=jnp.int32)
 
 
+# hashes of a draw's (N, S) plane that the ln ladder takes at once: its
+# one-hot fetches leave 20 int32 planes an element in HBM (80 bytes), so
+# a wider plane (the rule VM's blocks: 2^17 lanes x 6 tries x 32 slots
+# by its own sizing) goes through in rows of this many, 21 MB a row
+_LN_ROW = 1 << 18
+
+
+def _straw2_neg(arrs, u):
+    """(N, S) int32 hashes in [0, 0xffff] -> (N, S) uint64
+    2^48 - crush_ln(u), bit-exact, by the kernel's ladder over the
+    plane flattened to rows of at most ``_LN_ROW``."""
+    def row(v):
+        nh, nl = _pm._crush_ln_neg(arrs["ln_rhlh"], arrs["ln_ll"], v[None])
+        return (nh[0].astype(jnp.uint64) << jnp.uint64(24)) \
+            | nl[0].astype(jnp.uint64)
+
+    flat = u.reshape(-1)
+    n = flat.shape[0]
+    if n <= _LN_ROW:
+        return row(flat).reshape(u.shape)
+    rows = jnp.pad(flat, (0, -n % _LN_ROW)).reshape(-1, _LN_ROW)
+    return lax.map(row, rows).reshape(-1)[:n].reshape(u.shape)
+
+
 def _straw2_choose(arrs, rows, x, r, pos=None, cfg=None, size=None):
     """(N,) lanes: straw2 argmax draw (ref: mapper.c bucket_straw2_choose).
 
-    The 48-bit fixed-point ln is ONE gather from the precomputed 64K-entry
-    ``negln`` table (negln[u] = 2^48 - crush_ln(u), the negated draw
-    numerator) — measured ~5x cheaper on TPU than evaluating crush_ln's
-    normalize/multiply chain in emulated int64 per item.
+    The negated draw numerator neg = 2^48 - crush_ln(u) of every (lane,
+    slot) comes from the kernel's own exact ladder
+    (``pallas_mapper._crush_ln_neg``) run over the flattened (N, S)
+    plane: a bit-length normalize, RH/LH and LL fetched by 129- and
+    256-entry one-hot matmuls (XLA fuses the compare into the dot, so
+    no one-hot reaches HBM) and int32 limb arithmetic. It replaced a
+    gather from a 64K-entry table, which on a v5e cost 7.1 ns an element
+    and half of a weight-set sweep's device time (PERF.md §5).
 
     pos: (N,) replica positions, consulted only when a choose_args
     weight-set is packed (arrs["cw"]): position p draws with
@@ -331,7 +349,7 @@ def _straw2_choose(arrs, rows, x, r, pos=None, cfg=None, size=None):
         sh = arrs["wsh"][rows]
     u = (h.hash32_3(_u32(x)[:, None], _u32(hash_ids), _u32(r)[:, None],
                     xp=jnp) & jnp.uint32(0xFFFF)).astype(jnp.int32)
-    neg = arrs["negln"][u].astype(jnp.uint64)   # (N, S), <= 2^48
+    neg = _straw2_neg(arrs, u)                  # (N, S), <= 2^48
     # draw = trunc((ln - 2^48)/w) = -(neg // w); maximize draw = minimize q.
     # neg // w via the per-slot magic multiply (exact; see PackedMap.wm1)
     # — TPUs have no 64-bit divider and XLA's emulation is ~6.5x slower.
@@ -1100,7 +1118,7 @@ class Mapper:
             # process-wide cache, the six (B, S) tables share ONE int64
             # shuttle (uint64 rides as bits, items as widened int32),
             # and the per-bucket scalar columns share one int32 array.
-            negln_dev, zg2d_dev = _staged_const_tables()
+            rhlh_dev, ll_dev, zg2d_dev = _staged_const_tables()
             big64 = jnp.asarray(np.stack([
                 p.items.astype(np.int64), p.weights, p.cumw,
                 p.wm1.view(np.int64), p.wm0.view(np.int64),
@@ -1129,7 +1147,9 @@ class Mapper:
                 "btype": meta_dev[:, 2],
                 "bid": meta_dev[:, 3],
                 "device_weights": devw_c[:, 0],
-                "negln": negln_dev,
+                # crush_ln's byte planes for the general draw's ladder
+                "ln_rhlh": rhlh_dev,
+                "ln_ll": ll_dev,
                 # (B,1)/(D,1) copies: element gathers cost ~7ns/element
                 # on this platform; row gathers are ~10x cheaper
                 "size_c": meta_dev[:, 0:1],
@@ -1248,7 +1268,7 @@ class Mapper:
         # device-runtime accounting (round 14): the pack's H2D staging
         # footprint — what actually crossed the host boundary (the
         # int64 shuttle, the meta columns, device weights, optionals);
-        # the process-cached const tables (negln/zg2d) ship once per
+        # the process-cached const tables (ln planes, zg2d) ship once per
         # process and are excluded. big64 itself is the one transfer
         # its six views share.
         staged = int(big64.nbytes) + int(meta_dev.nbytes) + \
@@ -1455,7 +1475,6 @@ class Mapper:
 
     def _kernel_plan(self, ruleno: int):
         if ruleno not in self._kernel_plans:
-            from ceph_tpu.crush import pallas_mapper as _pm
             self._kernel_plans[ruleno] = _pm.build_plan(
                 self.map, self.packed, ruleno,
                 np.asarray(self.arrays["device_weights"]),
@@ -1486,7 +1505,6 @@ class Mapper:
         key = (ruleno, result_max, tally)
         if key in self._kernel_bodies:
             return self._kernel_bodies[key]
-        from ceph_tpu.crush import pallas_mapper as _pm
         plan = self._kernel_plan(ruleno)
         body = None
         if plan is not None:
@@ -1499,7 +1517,6 @@ class Mapper:
 
     def _make_kernel_body(self, plan, ruleno: int, result_max: int,
                           numrep: int, tally: bool):
-        from ceph_tpu.crush import pallas_mapper as _pm
         interpret = self._kernel_mode == "interpret"
         rule = self.map.rules[ruleno]
         root = next(s.arg1 for s in rule.steps if s.op == OP_TAKE)
@@ -1543,34 +1560,39 @@ class Mapper:
             # and the fallback must not cost O(block): gather the
             # flagged lanes into a small buffer (``fallback_lanes``: a
             # 256th of the block, 1.94 times that rate), recompute only
-            # those, scatter back. Fill slots recompute lane xs_[0] and
-            # scatter its (identical, because recomputation is exact)
-            # value — no masking needed. The full-width masked
-            # recompute survives only as the >FB overflow guard.
+            # those, scatter back. Fill slots recompute lanes that were
+            # not flagged and scatter their (identical, because
+            # recomputation is exact) values — no masking needed. A
+            # block with more flags than the buffer holds takes as many
+            # passes of it as it needs (counted:
+            # ``kernel_fallback_overflows``), not a recompute of the
+            # whole block: at 2^21 lanes that held half of the sweep
+            # step's ln ladders and nearly all of its 4 GB of
+            # temporaries.
             FB = fallback_lanes(n)
             # Where the plan draws inside a margin the recompute draws
-            # by the general path's ln-table gathers, 11.2 ms a round of
-            # 8,192 lanes on a v5e, and at one width a slot's loop goes
-            # round for its unluckiest lane (a flagged lane's third
-            # replica collides on its first three tries by what flagged
-            # it, then one time in ten): 14.5 rounds a block, and a
-            # sweep's time follows its ids (six seeded runs spread by
-            # 0.66%; PERF.md, PR 35). So the buffer's later rounds run
-            # in narrower blocks (``_choose_one_firstn``), 7 of them at
-            # full width. An all-uniform plan's recompute draws by hash
-            # alone (14 ms of a window's 3.5 s) and its program stays
-            # the text it was.
+            # on the general path (2 ms a round of 8,192 lanes on a v5e,
+            # 11.2 while its ln was a table gather), and at one width a
+            # slot's loop goes round for its unluckiest lane (a flagged
+            # lane's third replica collides on its first three tries by
+            # what flagged it, then one time in ten): 14.5 rounds a
+            # block, and a sweep's time follows its ids (six seeded runs
+            # spread by 0.66%; PERF.md §6). So the buffer's later rounds
+            # run in narrower blocks (``_choose_one_firstn``), 7 of them
+            # at full width. An all-uniform plan's recompute draws by
+            # hash alone (14 ms of a window's 3.5 s) at one width.
             narrow = narrow_widths(FB) if plan.rhlh is not None else ()
 
-            def _recompute(arrs_, xs_, active, narrow=()):
+            def _recompute(xs_):
                 nn = xs_.shape[0]
                 rows = jnp.full(nn, root_row, dtype=jnp.int32)
+                active = jnp.ones(nn, dtype=bool)
                 fb = jnp.full((nn, numrep), ITEM_NONE, dtype=jnp.int32)
                 fb_lv = jnp.full((nn, numrep), ITEM_NONE,
                                  dtype=jnp.int32)
                 for rep in range(numrep):
                     item, leaf, ok = _choose_one_firstn(
-                        arrs_, cfg, rows, active, xs_, rep,
+                        arrs, cfg, rows, active, xs_, rep,
                         fb[:, :rep], fb_lv[:, :rep], plan.target_type,
                         plan.recurse, tries, recurse_tries,
                         plan.vary_r, narrow=narrow)
@@ -1580,31 +1602,20 @@ class Mapper:
                         jnp.where(ok, leaf, ITEM_NONE))
                 return _compact(fb_lv if plan.recurse else fb)
 
-            def _run_fallback(op):
-                def _few(op2):
-                    arrs2, bad2, xs2, leaves2 = op2
-                    # top_k, not jnp.nonzero: nonzero's lowering inside
-                    # a lax.cond crashes this platform's TPU compile
-                    # helper outright (minimal repro: any nonzero under
-                    # cond). top_k is stable, so the FB indices are the
-                    # flagged lanes first, then arbitrary fill lanes —
-                    # whose recomputed (identical) values scatter
-                    # harmlessly.
-                    _, idx = jax.lax.top_k(bad2.astype(jnp.int32), FB)
-                    sub = _recompute(arrs2, xs2[idx],
-                                     jnp.ones(FB, dtype=bool), narrow)
-                    return leaves2.at[idx].set(sub)
+            def _pass(c):
+                # top_k, not jnp.nonzero: nonzero's lowering inside a
+                # lax.cond crashed this platform's TPU compile helper
+                # outright. top_k is stable, so the FB indices are the
+                # flagged lanes left first, then fill lanes — whose
+                # recomputed (identical) values scatter harmlessly.
+                left, w = c
+                _, idx = jax.lax.top_k(left.astype(jnp.int32), FB)
+                return (left.at[idx].set(False),
+                        w.at[idx].set(_recompute(xs[idx])))
 
-                def _all(op2):
-                    arrs2, bad2, xs2, leaves2 = op2
-                    out = _recompute(arrs2, xs2, bad2)
-                    return jnp.where(bad2[:, None], out, leaves2)
-
-                return jax.lax.cond(jnp.sum(op[1]) <= FB, _few, _all,
-                                    op)
-
-            w = jax.lax.cond(jnp.any(bad), _run_fallback,
-                             lambda op: op[3], (arrs, bad, xs, leaves))
+            # one pass on all but a rare block, none without a flag
+            _, w = jax.lax.while_loop(lambda c: jnp.any(c[0]), _pass,
+                                      (bad.astype(bool), leaves))
             if w.shape[1] < result_max:
                 padc = jnp.full((n, result_max - w.shape[1]), ITEM_NONE,
                                 dtype=jnp.int32)
@@ -1612,8 +1623,9 @@ class Mapper:
             w = w[:, :result_max]
             if not tally:
                 return w
-            # KERNEL_TALLY, from the flags alone: which branch the
-            # conds above took is a function of their count
+            # KERNEL_TALLY, from the flags alone: whether the loop
+            # above ran, and for more than one pass, is a function of
+            # their count
             flagged = jnp.sum(bad, dtype=jnp.int32)
             return w, jnp.stack([flagged, (flagged > 0).astype(jnp.int32),
                                  (flagged > FB).astype(jnp.int32)])
@@ -1678,7 +1690,6 @@ class Mapper:
         if self._scalar_reason or \
                 self._kernel_body(ruleno, result_max) is None:
             return None
-        from ceph_tpu.crush import pallas_mapper as _pm
         plan = self._kernel_plan(ruleno)
         n_cand = self._plan_numrep(plan, result_max) + _pm.SPEC_EXTRA
         lanes, fold, groups = _pm.kernel_geometry(plan, n_cand)
@@ -1715,7 +1726,6 @@ class Mapper:
         fresh batched-kernel compile from a stale plan's re-trace —
         the tag bumps whenever the kernel body restructures."""
         if kernel:
-            from ceph_tpu.crush import pallas_mapper as _pm
             return ("kern", _pm.KERNEL_VARIANT, self._devmon_token,
                     ruleno, result_max, extra)
         if self._arrays_sig is None:
@@ -2154,8 +2164,9 @@ KERNEL_TALLY = ("kernel_flagged_lanes", "kernel_fallback_blocks",
 def fallback_lanes(n: int) -> int:
     """Lanes of the buffer a kernel block of ``n`` lanes gathers its
     flagged lanes into for the bit-exact recompute: a 256th of the
-    block, 8,192 at 2^21 lanes; a block that flags more is recomputed
-    at full width and counted (``kernel_fallback_overflows``)."""
+    block, 8,192 at 2^21 lanes; a block that flags more takes as many
+    passes of it as it needs and is counted
+    (``kernel_fallback_overflows``)."""
     return min(n, max(256, n >> 8))
 
 

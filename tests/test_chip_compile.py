@@ -136,3 +136,23 @@ def test_count_placements_compiles_at_kernel_block(one_chip):
     text = compiled.as_text()
     assert "convolution(" in text and "scatter(" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
+
+def test_general_draw_ln_fuses_its_one_hots(one_chip):
+    """The XLA general draw's crush_ln (``mapper._straw2_neg``) over the
+    kernel recompute's buffer, 8,192 lanes x 32 slots: the one-hot
+    compares are made inside the matmuls' fusions, so no (129 or 256,
+    262,144) one-hot reaches HBM, and no element gather is left."""
+    from ceph_tpu.crush import pallas_mapper as pm
+    from ceph_tpu.crush.mapper import _straw2_neg
+
+    rhlh, ll = pm._ln_plane_tables()
+    arrs = {k: jax.ShapeDtypeStruct(t.shape, jnp.float32, sharding=one_chip)
+            for k, t in (("ln_rhlh", rhlh), ("ln_ll", ll))}
+    u = jax.ShapeDtypeStruct((8192, 32), jnp.int32, sharding=one_chip)
+    with jax.enable_x64(True):
+        compiled = jax.jit(_straw2_neg).lower(arrs, u).compile()
+    text = compiled.as_text()
+    assert text.count("convolution(") == 2 and " gather(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20

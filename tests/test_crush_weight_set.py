@@ -288,8 +288,8 @@ def test_a_uniform_map_flags_none(interpret):
 def test_flags_beyond_the_buffer_count_an_overflow_and_map_the_same(
         interpret, monkeypatch):
     """The buffer forced down to 4 lanes: the block's flags overflow it,
-    the block is recomputed at full width, and the mappings are still
-    the scalar spec's."""
+    the buffer takes as many passes as the flags need, and the mappings
+    are still the scalar spec's."""
     monkeypatch.setattr(mapper_mod, "fallback_lanes", lambda n: 4)
     m, rid = _flat(HEAVY)
     n = 4096
@@ -370,3 +370,52 @@ def test_a_zero_weight_slot_beside_one_weight_class_never_wins(
     assert np.array_equal(res.mappings, want)
     dead = (vector or weights).index(0)
     assert res.device_counts[dead] == 0
+
+
+# -- the general path's draw: crush_ln by the kernel's ladder -----------------
+
+LN_PLANES = [((2048, 32), None), ((16384, 4), None), ((2048, 32), 5000)]
+
+
+@pytest.mark.parametrize("shape,row", LN_PLANES,
+                         ids=["32-slot", "4-slot", "in-rows"])
+def test_the_general_draw_ln_is_crush_ln_over_its_whole_domain(
+        monkeypatch, shape, row):
+    """Every 16-bit hash, laid out as the (lanes, slots) plane
+    ``_straw2_choose`` hands the ladder, is 2^48 - crush_ln(u) bit for
+    bit; also where a plane wider than ``_LN_ROW`` goes through in
+    rows (the last one padded)."""
+    import jax
+    import jax.numpy as jnp
+    if row is not None:
+        monkeypatch.setattr(mapper_mod, "_LN_ROW", row)
+    rhlh, ll, _zg = mapper_mod._staged_const_tables()
+    arrs = {"ln_rhlh": rhlh, "ln_ll": ll}
+    u = np.arange(0x10000, dtype=np.int64)
+    with jax.enable_x64(True):
+        got = np.asarray(jax.jit(
+            lambda v: mapper_mod._straw2_neg(arrs, v))(
+                jnp.asarray(u.reshape(shape), dtype=jnp.int32)))
+    assert got.shape == shape and got.dtype == np.uint64
+    want = (1 << 48) - crush_ln(u)
+    assert np.array_equal(got.reshape(-1).astype(np.int64), want)
+
+
+def test_the_general_draw_on_a_weight_set_map_is_the_scalar_specs():
+    """The rule VM draws by ``_straw2_choose`` at all three levels (a
+    rack of four, a host of two, an OSD of four) on a map whose compat
+    weight-set weighs host0's OSDs and osd.17 0: a zero-weight slot at
+    the rack and at the host level. Every position of 2,048 inputs
+    spread over the id space is ``mapper_ref``'s, and none lands on a
+    zero-weight OSD."""
+    m, rid = _map()
+    _install(m, -1, 11)
+    dead = [0, 1, 2, 3, 17]
+    builder.choose_args_set_item_weights(m, -1, {o: [0] for o in dead})
+    mp = Mapper(m, choose_args=-1, block=4096)
+    assert mp.mapping_path(rid, 3) == "xla"
+    xs = ((np.arange(2048, dtype=np.uint64) * 2654435761) % (1 << 32)
+          ).astype(np.uint32)
+    got = np.asarray(mp.map_pgs(rid, xs, 3))
+    assert np.array_equal(got, _ref_rows(m, rid, -1, xs))
+    assert not np.isin(got, dead).any()
