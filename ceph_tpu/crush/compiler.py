@@ -8,6 +8,7 @@ ref: src/crush/CrushCompiler.{h,cc} (compile/decompile). Same grammar as
     type <id> <name>
     <typename> <bucketname> {
         id <negative int>            [# comment]
+        id <negative int> class <c>  [# the bucket's <c> shadow]
         alg uniform|list|tree|straw|straw2
         hash 0
         item <name> [weight <float>] [pos <int>]
@@ -24,7 +25,13 @@ ref: src/crush/CrushCompiler.{h,cc} (compile/decompile). Same grammar as
 
 Device-class ``take X class Y`` is realized the reference way: shadow
 hierarchies filtered per class (ref: CrushWrapper::populate_classes /
-device_class_clone), built at compile time.
+device_class_clone), built at compile time. A shadow's id is part of
+the map: straw2 hashes a bucket's item ids, and a shadow bucket's
+items are shadow ids, so placement follows them. ``crushtool -d``
+writes each as an ``id <n> class <c>`` line of the bucket it shadows,
+``-c`` builds every shadow the text names under the id it states, and
+only a shadow no line names gets the next free id, as the rules that
+take it come.
 """
 
 from __future__ import annotations
@@ -75,6 +82,8 @@ def compile_crushmap(text: str) -> CrushMap:
     name_to_id: dict[str, int] = {}
     class_of_device: dict[int, str] = {}
     rule_lines: list[tuple[str, list[str]]] = []
+    # (bucket name, class) -> the shadow id the text states, text order
+    stated_lines: dict[tuple[str, str], int] = {}
     lines = text.splitlines()
     i = 0
 
@@ -195,7 +204,10 @@ def compile_crushmap(text: str) -> CrushMap:
                 bt = bl.split()
                 if bt[0] == "id":
                     if len(bt) >= 4 and bt[2] == "class":
-                        pass  # shadow ids regenerate at compile
+                        if (bname, bt[3]) in stated_lines:
+                            err(f"bucket {bname!r} states its {bt[3]} "
+                                f"shadow id twice")
+                        stated_lines[(bname, bt[3])] = int(bt[1])
                     else:
                         bucket.id = int(bt[1])
                 elif bt[0] == "alg":
@@ -229,6 +241,16 @@ def compile_crushmap(text: str) -> CrushMap:
         i += 1
 
     m.device_classes = class_of_device
+    stated: dict[tuple[int, str], int] = {}
+    for (bname, klass), sid in stated_lines.items():
+        if sid >= 0 or sid in m.buckets or sid in stated.values():
+            raise CompileError(
+                f"bucket {bname!r}: the {klass} shadow id {sid} is taken "
+                f"by another bucket")
+        stated[(name_to_id[bname], klass)] = sid
+    # every shadow the text names, under its own id, before any rule
+    for bid, klass in stated:
+        class_shadow(m, bid, klass, stated)
     # rules second pass (buckets all known; class takes build shadows)
     for name, body in rule_lines:
         rule = Rule(id=len(m.rules), name=name)
@@ -242,7 +264,7 @@ def compile_crushmap(text: str) -> CrushMap:
                 pass  # legacy mask fields, ignored (removed upstream)
             elif bt[0] == "step":
                 rule.steps.append(
-                    _compile_step(m, name_to_id, bt[1:]))
+                    _compile_step(m, name_to_id, bt[1:], stated))
             else:
                 raise CompileError(f"rule {name!r}: bad line {bl!r}")
         m.rules[rule.id] = rule
@@ -250,14 +272,15 @@ def compile_crushmap(text: str) -> CrushMap:
 
 
 def _compile_step(m: CrushMap, name_to_id: dict[str, int],
-                  tok: list[str]) -> RuleStep:
+                  tok: list[str],
+                  stated: dict[tuple[int, str], int]) -> RuleStep:
     op = tok[0]
     if op == "take":
         if tok[1] not in name_to_id:
             raise CompileError(f"take of unknown bucket {tok[1]!r}")
         target = name_to_id[tok[1]]
         if len(tok) >= 4 and tok[2] == "class":
-            target = class_shadow(m, target, tok[3])
+            target = class_shadow(m, target, tok[3], stated)
         return RuleStep(OP_TAKE, target)
     if op == "emit":
         return RuleStep(OP_EMIT)
@@ -281,10 +304,15 @@ def _compile_step(m: CrushMap, name_to_id: dict[str, int],
     raise CompileError(f"unknown step {op!r}")
 
 
-def class_shadow(m: CrushMap, bucket_id: int, klass: str) -> int:
+def class_shadow(m: CrushMap, bucket_id: int, klass: str,
+                 stated: dict[tuple[int, str], int] | None = None) -> int:
     """Build (or reuse) the per-class filtered copy of a subtree
     (ref: CrushWrapper::device_class_clone). Devices not of `klass` are
-    dropped; empty subtrees pruned; weights re-summed."""
+    dropped; empty subtrees pruned; weights re-summed. ``stated``:
+    (bucket id, class) -> the shadow id the map's text gives it; a
+    shadow it does not name takes the id below every id in use or
+    stated, after its children took theirs."""
+    stated = stated or {}
     name = f"{m.bucket_names.get(bucket_id, bucket_id)}~{klass}"
     for bid, bname in m.bucket_names.items():
         if bname == name:
@@ -298,11 +326,14 @@ def class_shadow(m: CrushMap, bucket_id: int, klass: str) -> int:
                 items.append(item)
                 weights.append(w)
         else:
-            sub = class_shadow(m, item, klass)
+            sub = class_shadow(m, item, klass, stated)
             if m.buckets[sub].items:
                 items.append(sub)
                 weights.append(m.buckets[sub].weight)
-    shadow = Bucket(id=min(m.buckets, default=0) - 1, type=src.type,
+    sid = stated.get((bucket_id, klass))
+    if sid is None:
+        sid = min([*m.buckets, *stated.values()], default=0) - 1
+    shadow = Bucket(id=sid, type=src.type,
                     alg=src.alg, hash=src.hash, items=items,
                     weights=weights)
     m.buckets[shadow.id] = shadow
@@ -333,6 +364,14 @@ def decompile_crushmap(m: CrushMap) -> str:
             return f"osd.{i}"
         return m.bucket_names.get(i, f"bucket{-i}")
 
+    # each bucket's class shadows, written as its `id <n> class <c>`
+    # lines so that -c gives them their ids again
+    shadows: dict[str, list[tuple[str, int]]] = {}
+    for bid, bname in m.bucket_names.items():
+        base, sep, klass = bname.partition("~")
+        if sep:
+            shadows.setdefault(base, []).append((klass, bid))
+
     # children before parents (ref: decompile emits leaves-up)
     emitted: set[int] = set()
 
@@ -346,9 +385,11 @@ def decompile_crushmap(m: CrushMap) -> str:
         emitted.add(bid)
         name = m.bucket_names.get(bid, f"bucket{-bid}")
         if "~" in name:
-            return  # class shadows are regenerated, not serialized
+            return  # a shadow is its bucket's `id <n> class <c>` line
         out.append(f"{m.type_names.get(b.type, b.type)} {name} {{")
         out.append(f"\tid {b.id}")
+        for klass, sid in sorted(shadows.get(name, ())):
+            out.append(f"\tid {sid} class {klass}")
         out.append(f"\t# weight {b.weight / WEIGHT_ONE:.5f}")
         out.append(f"\talg {ALG_IDS[b.alg]}")
         out.append(f"\thash {b.hash}\t# rjenkins1")

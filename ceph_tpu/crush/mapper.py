@@ -207,6 +207,15 @@ PERF = (_PCB("crush_mapper")
                          "with their rounds there")
         .add_u64_counter("indep_holes",
                          "positions an indep sweep emitted as ITEM_NONE")
+        .add_u64_counter("firstn_slots",
+                         "lane-slots the firstn blocks of a rule VM "
+                         "sweep ran: each block's lanes x its slots")
+        .add_u64_counter("firstn_loop_lanes",
+                         "of those, the lane-slots the speculative tries "
+                         "left to the retry loop")
+        .add_u64_counter("firstn_loop_rounds",
+                         "rounds that loop ran at the block's full "
+                         "width, summed over slots")
         .create_perf_counters())
 
 
@@ -618,7 +627,7 @@ def _choose_one_firstn(arrs, cfg, root_rows, root_valid, x, rep,
                        prior_out, prior_leaves, target_type,
                        recurse_to_leaf, tries, recurse_tries, vary_r,
                        ftotal0: int = 0, pos: int = 0,
-                       narrow: tuple[int, ...] = ()):
+                       narrow: tuple[int, ...] = (), tally: bool = False):
     """One replica slot of crush_choose_firstn, all lanes at once.
 
     ftotal0 > 0 resumes after the caller's speculative tries: the while
@@ -632,7 +641,11 @@ def _choose_one_firstn(arrs, cfg, root_rows, root_valid, x, rep,
     kernel's flagged-lane recompute, ``Mapper._make_kernel_body``): the
     lanes are gathered into a block that wide and put back where they
     came from, as ``_choose_indep_block`` does. ``ftotal`` is a lane's
-    own, so its ``r`` is what it was; only its company changes."""
+    own, so its ``r`` is what it was; only its company changes.
+
+    ``tally`` (the rule VM's sweep, which does not narrow): also return
+    the int32 pair (lanes that entered the loop, rounds it ran), the
+    ``FIRSTN_TALLY``; without it the program is the one it was."""
     def rounds(root_rows, root_valid, x, base_r, prior_out, prior_leaves,
                c, stop_at):
         """Rounds at the width of ``x`` while more than ``stop_at`` of
@@ -671,13 +684,16 @@ def _choose_one_firstn(arrs, cfg, root_rows, root_valid, x, rep,
             succeed = active & ok
             ftotal_next = c["ftotal"] + 1
             give_up = active & ~ok & (ftotal_next >= tries)
-            return {
+            out = {
                 "item": jnp.where(succeed, item, c["item"]),
                 "leaf": jnp.where(succeed, leaf, c["leaf"]),
                 "ok": c["ok"] | succeed,
                 "done": c["done"] | succeed | give_up,
                 "ftotal": jnp.where(active & ~ok, ftotal_next, c["ftotal"]),
             }
+            if tally:
+                out["rounds"] = c["rounds"] + 1
+            return out
 
         return lax.while_loop(cond, body, c)
 
@@ -713,8 +729,13 @@ def _choose_one_firstn(arrs, cfg, root_rows, root_valid, x, rep,
         else jnp.ones(n, dtype=bool),
         "ftotal": jnp.full(n, ftotal0, dtype=jnp.int32),
     }
+    if tally:
+        init["rounds"] = jnp.int32(0)
     out = finish(root_rows, root_valid, x, base_r, prior_out, prior_leaves,
                  init, tuple(w for w in narrow if w < n))
+    if tally:
+        return out["item"], out["leaf"], out["ok"], jnp.stack(
+            [(~init["done"]).sum(dtype=jnp.int32), out["rounds"]])
     return out["item"], out["leaf"], out["ok"]
 
 
@@ -740,8 +761,11 @@ def _leaf_once(arrs, cfg, item, item_ok, x, sub_r, pos=None):
 
 def _choose_firstn_block(arrs, cfg, root_rows, root_valid, x, numrep,
                          target_type, recurse_to_leaf, tries, recurse_tries,
-                         vary_r, pos_base: int = 0):
-    """numrep replica slots from one root column -> (N, numrep) x2.
+                         vary_r, pos_base: int = 0, tally: bool = False):
+    """numrep replica slots from one root column -> (N, numrep) x2, and
+    with ``tally`` the block's ``FIRSTN_TALLY`` (int32: lane-slots run,
+    lane-slots the speculative tries left to the loop, the loop's
+    rounds summed over slots).
 
     Structure (round 2): the first SPEC_TRIES tries of EVERY slot descend
     in parallel as extra lanes — the descent for (slot, try) is
@@ -800,6 +824,7 @@ def _choose_firstn_block(arrs, cfg, root_rows, root_valid, x, numrep,
         ok_s = ok_f.reshape(n, numrep, K)
         leaves_s = leaf_f.reshape(n, numrep, K)
 
+    loop = []                            # the tally's (lanes, rounds) a slot
     for rep in range(numrep):
         if speculate:
             K = items_s.shape[2]
@@ -819,22 +844,27 @@ def _choose_firstn_block(arrs, cfg, root_rows, root_valid, x, numrep,
             item = jnp.take_along_axis(it_k, first[:, None], axis=1)[:, 0]
             leaf = jnp.take_along_axis(lf_k, first[:, None], axis=1)[:, 0]
             # fallback continues from ftotal = K for unresolved lanes only
-            item2, leaf2, ok2 = _choose_one_firstn(
+            item2, leaf2, ok2, *slot = _choose_one_firstn(
                 arrs, cfg, root_rows, root_valid & ~any_ok, x, rep,
                 out[:, :rep], leaves[:, :rep], target_type,
                 recurse_to_leaf, tries, recurse_tries, vary_r,
-                ftotal0=K, pos=pos_base + rep)
+                ftotal0=K, pos=pos_base + rep, tally=tally)
             ok = any_ok | ok2
             item = jnp.where(any_ok, item, item2)
             leaf = jnp.where(any_ok, leaf, leaf2)
         else:
-            item, leaf, ok = _choose_one_firstn(
+            item, leaf, ok, *slot = _choose_one_firstn(
                 arrs, cfg, root_rows, root_valid, x, rep,
                 out[:, :rep], leaves[:, :rep], target_type,
                 recurse_to_leaf, tries, recurse_tries, vary_r,
-                pos=pos_base + rep)
+                pos=pos_base + rep, tally=tally)
+        loop += slot
         out = out.at[:, rep].set(jnp.where(ok, item, ITEM_NONE))
         leaves = leaves.at[:, rep].set(jnp.where(ok, leaf, ITEM_NONE))
+    if tally:
+        return out, leaves, jnp.concatenate(
+            [jnp.full(1, n * max(numrep, 0), dtype=jnp.int32),
+             sum(loop, jnp.zeros(2, dtype=jnp.int32))])
     return out, leaves
 
 
@@ -1739,6 +1769,11 @@ class Mapper:
         return not any(s.op in (OP_CHOOSE_INDEP, OP_CHOOSELEAF_INDEP)
                        for s in self.map.rules[ruleno].steps)
 
+    def takes(self, ruleno: int) -> int:
+        """The rule's take/emit blocks: its TAKE steps (``build_plan``
+        puts a rule of one on the kernel, never one of several)."""
+        return sum(s.op == OP_TAKE for s in self.map.rules[ruleno].steps)
+
     def _scalar_map(self, ruleno: int, xs, result_max: int) -> np.ndarray:
         """Legacy-tunable fallback: per-x scalar walk of the executable
         spec (bit-exact by definition; slow — legacy maps only)."""
@@ -1934,14 +1969,15 @@ class Mapper:
         per-call discipline and the ``_expected`` retry threading).
 
         The call is the section ``crush.sweep`` (tags ``lanes``,
-        ``blocks``, ``width``, an indep rule's ``narrow_width``): its
+        ``takes``: the rule's take/emit blocks, ``blocks``, ``width``,
+        an indep rule's ``narrow_width``): its
         self time is the prelude and the counters; each block's
         ``jit_call`` is a ``crush.dispatch``, the forced first-block read
         a ``crush.force``, the tally's read a ``crush.readback``. A
         kernel-failure retry nests inside the failed call's section."""
         with tracing.section("crush.sweep", service="crush") as sec:
             if sec:
-                sec.tag("lanes", int(n))
+                sec.tag("lanes", int(n)).tag("takes", self.takes(ruleno))
             return self._sweep_path(sec, ruleno, start_x, n, result_max,
                                     device_counts_size, _expected)
 
@@ -1969,12 +2005,14 @@ class Mapper:
             return counts, bad, self._record_path(path, _expected)
         kb = self._kernel_body(ruleno, result_max)
         firstn = self.rule_is_firstn(ruleno)
-        # an indep rule on the rule VM tallies what its blocks did, a
+        # a rule on the rule VM tallies what its choose blocks did (an
+        # indep rule its rounds, a firstn rule its slots' loops), a
         # kernel plan with a margin draw what its fallback did
         indep = kb is None and not firstn
-        tally = INDEP_TALLY if indep else ()
+        tally = () if kb is not None else \
+            INDEP_TALLY if indep else FIRSTN_TALLY
         fn_body = kb or _rule_body(*self._rule_key(ruleno, result_max),
-                                   indep_stats=indep)
+                                   tally=tally)
         # a plan that decides draws inside a margin (a class or a
         # continuous level: it carries the crush_ln planes) sweeps with
         # the tally; an all-uniform plan flags candidate exhaustion
@@ -2159,6 +2197,13 @@ INDEP_TALLY = ("indep_blocks", "indep_rounds", "indep_lane_rounds_needed",
 # ran, and whether the flags overflowed its buffer
 KERNEL_TALLY = ("kernel_flagged_lanes", "kernel_fallback_blocks",
                 "kernel_fallback_overflows")
+# what a firstn sweep on the rule VM (a rule with no kernel plan: one
+# of several take/emit blocks, or a shape ``build_plan`` does not take)
+# carries after the bad mappings: of each block, the lane-slots its
+# firstn blocks ran, those the speculative tries left to
+# ``_choose_one_firstn``'s loop, and the rounds that loop ran at full
+# width, summed over slots. A slot's loop costs its unluckiest lane.
+FIRSTN_TALLY = ("firstn_slots", "firstn_loop_lanes", "firstn_loop_rounds")
 
 
 def fallback_lanes(n: int) -> int:
@@ -2190,8 +2235,9 @@ def _compiled_sweep(fn_body, tally, n_devices, block, result_max):
     lanes and is dropped by the caller. With a ``tally`` the body
     returns ``(mappings, stats)`` and ``bad`` is an int64 vector: the
     bad mappings, then the tally's counters. ``INDEP_TALLY``: the body
-    is ``_rule_body(..., indep_stats=True)``, and the step adds the
-    holes. ``KERNEL_TALLY``: ``Mapper._kernel_body(..., tally=True)``. An indep step
+    is ``_rule_body(..., tally=INDEP_TALLY)``, and the step adds the
+    holes. ``KERNEL_TALLY``: ``Mapper._kernel_body(..., tally=True)``.
+    ``FIRSTN_TALLY``: ``_rule_body(..., tally=FIRSTN_TALLY)``. An indep step
     costs its rounds, and a round the width it runs at
     (``_choose_indep_block``): the first the block's, the later ones an
     eighth and then a 128th of it once no more lanes than that are
@@ -2213,7 +2259,7 @@ def _compiled_sweep(fn_body, tally, n_devices, block, result_max):
         short = (live.sum(axis=1) < result_max) & inb
         if not tally:
             return counts, bad + short.sum(dtype=jnp.int64)
-        if tally == KERNEL_TALLY:
+        if tally != INDEP_TALLY:             # KERNEL_TALLY, FIRSTN_TALLY
             return counts, bad + jnp.concatenate([
                 short.sum(dtype=jnp.int64)[None], stats.astype(jnp.int64)])
         # ``bad`` is the indep tally: bad mappings, then INDEP_TALLY
@@ -2239,12 +2285,15 @@ def _depth_between(type_depth, from_type, to_type):
 
 @functools.lru_cache(maxsize=256)
 def _rule_body(steps, result_max, tkey, max_depth, present, type_depth=(),
-               tree_depth=0, flags=(False, False), indep_stats=False):
+               tree_depth=0, flags=(False, False), tally=()):
     """The rule VM: ``run(arrs, xs) -> (n, result_max)`` mappings.
-    With ``indep_stats`` it returns ``(mappings, stats)``, stats the
-    int32 vector (choose_indep blocks run, then the sum of their
-    ``_choose_indep_block`` tallies: rounds, needed lane-rounds,
-    lane-rounds run, blocks narrowed) that the sweep step tallies."""
+    With a ``tally`` it returns ``(mappings, stats)``, stats the int32
+    vector the sweep step tallies under those names. ``INDEP_TALLY``:
+    choose_indep blocks run, then the sum of their
+    ``_choose_indep_block`` tallies (rounds, needed lane-rounds,
+    lane-rounds run, blocks narrowed). ``FIRSTN_TALLY``: the sum of the
+    firstn blocks' ``_choose_firstn_block`` tallies."""
+    firstn_stats = tally == FIRSTN_TALLY
     total_tries, descend_once, vary_r, stable = tkey
     base_cfg = {"max_depth": max_depth, "present": present,
                 "tree_depth": tree_depth,
@@ -2260,7 +2309,8 @@ def _rule_body(steps, result_max, tkey, max_depth, present, type_depth=(),
         w_cols: list = []
         emitted: list = []
         any_firstn = False
-        stats = jnp.zeros(len(INDEP_TALLY) - 1, dtype=jnp.int32)
+        stats = jnp.zeros(len(FIRSTN_TALLY) if firstn_stats
+                          else len(INDEP_TALLY) - 1, dtype=jnp.int32)
         cur_type = None   # static type of the current columns' items
         for step in steps:
             op, arg1, arg2 = step[0], step[1], step[2]
@@ -2311,17 +2361,20 @@ def _rule_body(steps, result_max, tkey, max_depth, present, type_depth=(),
                     root_rows = jnp.clip(-1 - col, 0, B - 1)
                     if firstn:
                         blk = min(numrep, result_max - osize)
-                        out, leaves = _choose_firstn_block(
+                        out, leaves, *block_stats = _choose_firstn_block(
                             arrs, cfg, root_rows, root_valid, xs, blk,
-                            arg2, recurse, choose_tries, recurse_tries, vr)
+                            arg2, recurse, choose_tries, recurse_tries, vr,
+                            tally=firstn_stats)
+                        if firstn_stats:
+                            stats = stats + block_stats[0]
                     else:
                         blk = min(numrep, result_max - osize)
-                        out, leaves, tally = _choose_indep_block(
+                        out, leaves, block_stats = _choose_indep_block(
                             arrs, cfg, root_rows, root_valid, xs, blk,
                             numrep, arg2, recurse, choose_tries,
                             recurse_tries)
                         stats = stats + jnp.concatenate(
-                            [jnp.ones(1, dtype=jnp.int32), tally])
+                            [jnp.ones(1, dtype=jnp.int32), block_stats])
                     chosen = leaves if recurse else out
                     # Device roots with matching type pass through.
                     if arg2 == 0:
@@ -2353,6 +2406,6 @@ def _rule_body(steps, result_max, tkey, max_depth, present, type_depth=(),
                            dtype=jnp.int32)
             w = jnp.concatenate([w, pad], axis=1)
         w = w[:, :result_max]
-        return (w, stats) if indep_stats else w
+        return (w, stats) if tally else w
 
     return run

@@ -457,7 +457,9 @@ def do_rule(map_: CrushMap, ruleno: int, x: int, result_max: int,
                     osize += out_size
             w = c[:osize] if recurse_to_leaf else o[:osize]
         elif op == OP_EMIT:
-            result.extend(w)
+            # what fits: a later take/emit block cannot push the result
+            # past result_max (mapper.c: `result_len < result_max`)
+            result.extend(w[:result_max - len(result)])
             w = []
         else:
             raise ValueError(f"unknown rule op {op}")

@@ -236,7 +236,8 @@ def sharded_sweep(mesh, mapper, ruleno: int, start_x: int, n: int,
     Returns (counts (max_devices,), bad) replicated on every device,
     equal to the single-device sweep's, unread: the caller's read-back
     is the sweep's one sync. The call is the section ``crush.sweep``
-    (tags ``lanes``, ``blocks`` a shard, ``width``), the program's call
+    (tags ``lanes``, ``takes``, ``blocks`` a shard, ``width``), the
+    program's call
     a ``crush.dispatch`` (as ``Mapper.sweep_path``'s)."""
     if getattr(mapper, "_scalar_reason", None):
         raise ValueError(
@@ -248,8 +249,8 @@ def sharded_sweep(mesh, mapper, ruleno: int, start_x: int, n: int,
         local_n, block = _shard_widths(mapper, ruleno, result_max,
                                        max(1, -(-n // ndev)))
         if sec:
-            sec.tag("lanes", int(n)).tag("blocks", -(-local_n // block)) \
-                .tag("width", block)
+            sec.tag("lanes", int(n)).tag("takes", mapper.takes(ruleno)) \
+                .tag("blocks", -(-local_n // block)).tag("width", block)
         fn_body, used_kernel = _fn_body(mapper, ruleno, result_max)
         fn = _shard_fn(mapper, used_kernel, _compiled_sharded_sweep,
                        fn_body, nd, mesh, block, local_n, result_max)

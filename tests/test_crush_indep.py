@@ -351,13 +351,14 @@ def test_the_indep_sweep_is_a_tracing_section(monkeypatch):
         made.clear()
         return found
     CrushTester(m, w, batch=64).test(rid, 6, 0, 63)
-    assert sweep_tags() == [{"lanes": 64, "blocks": 1, "width": 64,
-                             "narrow_width": 0}]
+    assert sweep_tags() == [{"lanes": 64, "takes": 1, "blocks": 1,
+                             "width": 64, "narrow_width": 0}]
     assert mapper_mod.narrow_widths(1 << 20) == (1 << 17, 1 << 13)
     root = m.rules[rid].steps[2].arg1
     firstn = builder.add_simple_rule(m, root, builder.TYPE_HOST)
     CrushTester(m, w, batch=64).test(firstn, 3, 0, 63)
-    assert sweep_tags() == [{"lanes": 64, "blocks": 1, "width": 64}]
+    assert sweep_tags() == [{"lanes": 64, "takes": 1, "blocks": 1,
+                             "width": 64}]
 
 
 def _block_program(m, w, rid, width, n):
@@ -365,7 +366,8 @@ def _block_program(m, w, rid, width, n):
     ``(jitted run(arrays, xs) -> (mappings, stats), arrays)``."""
     mp = Mapper(m, w, block=n)
     return jax.jit(mapper_mod._rule_body(*mp._rule_key(rid, width),
-                                         indep_stats=True)), mp.arrays
+                                         tally=mapper_mod.INDEP_TALLY)), \
+        mp.arrays
 
 
 # case, width, the widths of a 128-lane block's rounds (it narrows to 16

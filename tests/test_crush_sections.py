@@ -57,13 +57,16 @@ def test_a_firstn_sweep_records_test_sweep_dispatch_readback(firstn, roots):
     m, rid = firstn
     res = CrushTester(m, batch=64).test(rid, 3, 0, 99)
     assert res.device_counts.sum() == 300
-    # the XLA path forces no block: the tester's read is the one sync
+    # the XLA path forces no block: its firstn tally's read is the
+    # sweep's one sync, and the tester's reads find the counts home
     assert shape(roots) == [("crush.test", [
-        ("crush.sweep", [("crush.dispatch", []), ("crush.dispatch", [])]),
+        ("crush.sweep", [("crush.dispatch", []), ("crush.dispatch", []),
+                         ("crush.readback", [])]),
         ("crush.readback", [])])]
     sweep = roots[0].kids[0]
-    assert sweep.tags == {"lanes": 100, "blocks": 2, "width": 64}
-    assert [d.tags for d in sweep.kids] == [{"block": 0}, {"block": 1}]
+    assert sweep.tags == {"lanes": 100, "takes": 1, "blocks": 2,
+                          "width": 64}
+    assert [d.tags for d in sweep.kids] == [{"block": 0}, {"block": 1}, {}]
     assert sweep.service == "crush"
 
 
@@ -74,7 +77,7 @@ def test_an_indep_sweep_reads_its_tally_inside_the_sweep(roots):
     assert shape(roots) == [("crush.test", [
         ("crush.sweep", [("crush.dispatch", []), ("crush.readback", [])]),
         ("crush.readback", [])])]
-    assert roots[0].kids[0].tags == {"lanes": 64, "blocks": 1,
+    assert roots[0].kids[0].tags == {"lanes": 64, "takes": 1, "blocks": 1,
                                      "width": 64, "narrow_width": 0}
 
 
@@ -124,9 +127,10 @@ def test_a_kernel_failure_retries_inside_the_failed_sweep(firstn, roots,
     # of its own inside the failed one
     assert shape(roots) == [("crush.sweep", [
         ("crush.dispatch", []),
-        ("crush.sweep", [("crush.dispatch", [])])])]
+        ("crush.sweep", [("crush.dispatch", []), ("crush.readback", [])])])]
     assert "blocks" not in roots[0].tags
-    assert roots[0].kids[1].tags == {"lanes": 64, "blocks": 1, "width": 64}
+    assert roots[0].kids[1].tags == {"lanes": 64, "takes": 1, "blocks": 1,
+                                     "width": 64}
 
 
 def test_the_sharded_sweep_on_the_virtual_mesh(firstn, roots):
@@ -137,7 +141,8 @@ def test_the_sharded_sweep_on_the_virtual_mesh(firstn, roots):
     counts, bad = sharded_sweep(mesh, mp, rid, 0, n, 3)
     assert int(np.asarray(counts).sum()) == 3 * n
     assert shape(roots) == [("crush.sweep", [("crush.dispatch", [])])]
-    assert roots[0].tags == {"lanes": n, "blocks": 1, "width": 64}
+    assert roots[0].tags == {"lanes": n, "takes": 1, "blocks": 1,
+                             "width": 64}
     assert roots[0].kids[0].tags == {"block": 0}
     roots.clear()
     # through the Mapper: its sweep holds the module's
@@ -145,7 +150,7 @@ def test_the_sharded_sweep_on_the_virtual_mesh(firstn, roots):
     assert path == "xla+sharded"
     assert shape(roots) == [("crush.sweep", [
         ("crush.sweep", [("crush.dispatch", [])])])]
-    assert roots[0].tags == {"lanes": n}
+    assert roots[0].tags == {"lanes": n, "takes": 1}
 
 
 def test_with_no_session_a_section_is_the_shared_off_and_tags_nothing(
