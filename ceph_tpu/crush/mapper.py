@@ -167,6 +167,12 @@ PERF = (_PCB("crush_mapper")
                          "lanes dispatched by sweep: the sum of its "
                          "blocks' widths (pgs_mapped over it is the "
                          "fill share)")
+        .add_u64_counter("kernel_take_plans",
+                         "kernel plans run, one a take/emit block of "
+                         "the rule, summed over the blocks of lanes "
+                         "dispatched (a rule of one block counts one a "
+                         "block, the docs' SSD-primary rule two); "
+                         "counted on the host")
         .add_u64_counter("kernel_flagged_lanes",
                          "lanes the fused kernel flagged to the "
                          "bit-exact recompute, summed over a sweep's "
@@ -1051,6 +1057,29 @@ def _compact(w):
     return jnp.take_along_axis(w, order, axis=1)
 
 
+def _emit_blocks(blocks, result_max):
+    """firstn EMIT of several take/emit blocks: each lane keeps its
+    first ``result_max`` items that are not ITEM_NONE, in block then
+    slot order, ITEM_NONE after them -> (N, result_max). ``blocks`` are
+    the blocks' (N, numrep) results; their columns are laid side by
+    side lane-dense, (cols, N) as the kernel writes them, and placed by
+    a running count of the items before them and one select a column
+    and output slot, where ``_compact``'s argsort of an (N, cols) array
+    cost the rule VM 45 ms a 2^20-lane sweep on a v5e (PERF.md)."""
+    cols = jnp.concatenate([b.T for b in blocks], axis=0)
+    n = cols.shape[1]
+    out = [jnp.full(n, ITEM_NONE, dtype=jnp.int32)] * result_max
+    before = jnp.zeros(n, dtype=jnp.int32)
+    for c in range(cols.shape[0]):
+        item = cols[c]
+        live = item != ITEM_NONE
+        # column c can land at most in output slot c
+        for j in range(min(c + 1, result_max)):
+            out[j] = jnp.where(live & (before == j), item, out[j])
+        before = before + live
+    return jnp.stack(out, axis=1)
+
+
 # ---------------------------------------------------------------------------
 # Rule execution
 # ---------------------------------------------------------------------------
@@ -1523,33 +1552,79 @@ class Mapper:
             else plan.numrep_arg + result_max
         return min(numrep, result_max)
 
+    def _take_plans(self, ruleno: int) -> tuple:
+        """The kernel plans of the rule's take/emit blocks in the rule's
+        order (one for a rule of one block), or () where no plan
+        serves."""
+        plan = self._kernel_plan(ruleno)
+        if plan is None:
+            return ()
+        return plan if isinstance(plan, tuple) else (plan,)
+
     def _kernel_body(self, ruleno: int, result_max: int,
                      tally: bool = False):
         """fn_body(arrs, xs) -> (N, result_max), backed by the fused
         kernel with a masked XLA fallback for flagged lanes, or None
-        when this rule is ineligible (the XLA path stands). With
-        ``tally`` the body returns ``(mappings, stats)``, stats the
-        block's ``KERNEL_TALLY``."""
+        when this rule is ineligible (the XLA path stands). A rule of
+        several take/emit blocks runs one kernel plan a block, each
+        with its own fallback, in the one body, and keeps a lane's
+        first ``result_max`` items in block then slot order, as firstn
+        EMIT does (``_emit_blocks``). With ``tally`` the body returns
+        ``(mappings, stats)``, stats the block's ``KERNEL_TALLY``
+        summed over its take plans."""
         if self._kernel_mode is None:
             return None
         key = (ruleno, result_max, tally)
         if key in self._kernel_bodies:
             return self._kernel_bodies[key]
-        plan = self._kernel_plan(ruleno)
+        plans = self._take_plans(ruleno)
+        numreps = [self._plan_numrep(p, result_max) for p in plans]
         body = None
-        if plan is not None:
-            numrep = self._plan_numrep(plan, result_max)
-            if numrep >= 1:
-                body = self._make_kernel_body(plan, ruleno, result_max,
-                                              numrep, tally)
+        if plans and min(numreps) >= 1:
+            body = self._make_kernel_body(plans, ruleno, result_max,
+                                          numreps, tally)
         self._kernel_bodies[key] = body
         return body
 
-    def _make_kernel_body(self, plan, ruleno: int, result_max: int,
-                          numrep: int, tally: bool):
+    def _make_kernel_body(self, plans, ruleno: int, result_max: int,
+                          numreps, tally: bool):
+        roots = [s.arg1 for s in self.map.rules[ruleno].steps
+                 if s.op == OP_TAKE]
+        blocks = [self._take_block(plan, root, numrep)
+                  for plan, root, numrep in zip(plans, roots, numreps)]
+
+        def fn_body(arrs, xs):
+            outs = [run(arrs, xs) for run in blocks]
+            if len(outs) > 1:
+                w = _emit_blocks([o[0] for o in outs], result_max)
+            else:       # a rule of one block: its program as it was
+                w = outs[0][0]
+                if w.shape[1] < result_max:
+                    padc = jnp.full((w.shape[0], result_max - w.shape[1]),
+                                    ITEM_NONE, dtype=jnp.int32)
+                    w = jnp.concatenate([w, padc], axis=1)
+                w = w[:, :result_max]
+            if not tally:
+                return w
+            # KERNEL_TALLY, from the flags alone: whether a block's
+            # recompute ran, and for more than one pass, is a function
+            # of their count
+            stats = None
+            for _, bad, FB in outs:
+                flagged = jnp.sum(bad, dtype=jnp.int32)
+                s = jnp.stack([flagged, (flagged > 0).astype(jnp.int32),
+                               (flagged > FB).astype(jnp.int32)])
+                stats = s if stats is None else stats + s
+            return w, stats
+
+        return fn_body
+
+    def _take_block(self, plan, root: int, numrep: int):
+        """run(arrs, xs) -> (w, bad, FB) of one take/emit block on the
+        kernel: ``w`` its (N, numrep) items with the lanes the kernel
+        flagged (``bad``) recomputed bit-exactly on the XLA path from
+        ``root``, FB lanes a pass."""
         interpret = self._kernel_mode == "interpret"
-        rule = self.map.rules[ruleno]
-        root = next(s.arg1 for s in rule.steps if s.op == OP_TAKE)
         root_type = self.map.buckets[root].type
         t = self.map.tunables
         tries = t.choose_total_tries
@@ -1566,7 +1641,7 @@ class Mapper:
         # width is plan.lanes // fold, not plan.lanes
         lanes = _pm.kernel_geometry(plan, numrep + _pm.SPEC_EXTRA)[0]
 
-        def fn_body(arrs, xs):
+        def run(arrs, xs):
             n = xs.shape[0]
             pad = -n % lanes
             xs_k = jnp.pad(xs, (0, pad)) if pad else xs
@@ -1646,21 +1721,9 @@ class Mapper:
             # one pass on all but a rare block, none without a flag
             _, w = jax.lax.while_loop(lambda c: jnp.any(c[0]), _pass,
                                       (bad.astype(bool), leaves))
-            if w.shape[1] < result_max:
-                padc = jnp.full((n, result_max - w.shape[1]), ITEM_NONE,
-                                dtype=jnp.int32)
-                w = jnp.concatenate([w, padc], axis=1)
-            w = w[:, :result_max]
-            if not tally:
-                return w
-            # KERNEL_TALLY, from the flags alone: whether the loop
-            # above ran, and for more than one pass, is a function of
-            # their count
-            flagged = jnp.sum(bad, dtype=jnp.int32)
-            return w, jnp.stack([flagged, (flagged > 0).astype(jnp.int32),
-                                 (flagged > FB).astype(jnp.int32)])
+            return w, bad, FB
 
-        return fn_body
+        return run
 
     def _rule_key(self, ruleno: int, result_max: int):
         rule = self.map.rules[ruleno]
@@ -1684,7 +1747,10 @@ class Mapper:
     def mapping_path(self, ruleno: int, result_max: int) -> str:
         """Which engine serves this (rule, width): 'pallas' (fused
         kernel on TPU), 'pallas-interpret' (tests), 'xla' (vectorized
-        general path), or 'scalar' (legacy-tunable spec walk). Bench
+        general path), or 'scalar' (legacy-tunable spec walk). A rule
+        of several take/emit blocks is 'pallas' when ``build_plan``
+        takes every block (a plan a block, merged as EMIT keeps the
+        first ``result_max``) and 'xla' when it refuses one. Bench
         rows record this so a variant silently sliding off the kernel
         is a visible diff, not a mystery slowdown."""
         if self._scalar_reason:
@@ -1716,21 +1782,29 @@ class Mapper:
           level pass (fold > 1);
         - ``kernel_lanes`` / ``candidate_fold``: the per-cell PG
           width and fold the geometry search chose for this map.
+
+        A rule of several take/emit blocks gives each fact as a list,
+        one entry a block's plan, and ``take_plans``, their count.
         """
         if self._scalar_reason or \
                 self._kernel_body(ruleno, result_max) is None:
             return None
-        plan = self._kernel_plan(ruleno)
-        n_cand = self._plan_numrep(plan, result_max) + _pm.SPEC_EXTRA
-        lanes, fold, groups = _pm.kernel_geometry(plan, n_cand)
-        return {
-            "fetches_per_sweep": groups * (plan.l_main + plan.l_leaf),
-            "fetch_amortization": round(
-                n_cand * lanes / (groups * plan.lanes), 3),
-            "candidate_batched": fold > 1,
-            "kernel_lanes": lanes,
-            "candidate_fold": fold,
-        }
+        infos = []
+        for plan in self._take_plans(ruleno):
+            n_cand = self._plan_numrep(plan, result_max) + _pm.SPEC_EXTRA
+            lanes, fold, groups = _pm.kernel_geometry(plan, n_cand)
+            infos.append({
+                "fetches_per_sweep": groups * (plan.l_main + plan.l_leaf),
+                "fetch_amortization": round(
+                    n_cand * lanes / (groups * plan.lanes), 3),
+                "candidate_batched": fold > 1,
+                "kernel_lanes": lanes,
+                "candidate_fold": fold,
+            })
+        if len(infos) == 1:
+            return infos[0]
+        return {"take_plans": len(infos),
+                **{k: [i[k] for i in infos] for k in infos[0]}}
 
     def expected_path(self, ruleno: int, result_max: int) -> str:
         """The engine this Mapper is EXPECTED to serve (rule, width)
@@ -1771,7 +1845,7 @@ class Mapper:
 
     def takes(self, ruleno: int) -> int:
         """The rule's take/emit blocks: its TAKE steps (``build_plan``
-        puts a rule of one on the kernel, never one of several)."""
+        gives a rule of several one kernel plan a block)."""
         return sum(s.op == OP_TAKE for s in self.map.rules[ruleno].steps)
 
     def _scalar_map(self, ruleno: int, xs, result_max: int) -> np.ndarray:
@@ -1912,8 +1986,9 @@ class Mapper:
                                      _expected=_expected)
         path = self.mapping_path(ruleno, result_max)
         PERF.inc("pgs_mapped", int(n))       # success only: the failed
-        return out, self._record_path(path, _expected)  # attempt must
-        # not double-count
+        if kb_kern:                          # attempt must not double-count
+            self._count_take_plans(ruleno, -(-int(n) // block))
+        return out, self._record_path(path, _expected)
 
     def _sharded_map_pgs(self, ruleno: int, xs, result_max: int):
         """map_pgs over the attached mesh (crush.sharded_sweep), with
@@ -1934,6 +2009,8 @@ class Mapper:
             return self._sharded_map_pgs(ruleno, xs, result_max)
         # (last_map_path is set by sharded_map_pgs itself — one site)
         PERF.inc("pgs_mapped", len(xs))
+        if kb is not None and len(xs):
+            self._count_sharded_take_plans(ruleno, result_max, len(xs))
         return out
 
     def sweep(self, ruleno: int, start_x: int, n: int, result_max: int,
@@ -2017,8 +2094,8 @@ class Mapper:
         # continuous level: it carries the crush_ln planes) sweeps with
         # the tally; an all-uniform plan flags candidate exhaustion
         # only, and its sweep program stays the one it was
-        if kb is not None and getattr(self._kernel_plan(ruleno), "rhlh",
-                                      None) is not None:
+        if kb is not None and any(p.rhlh is not None
+                                  for p in self._take_plans(ruleno)):
             tally = KERNEL_TALLY
             fn_body = self._kernel_body(ruleno, result_max, tally=True)
         nd = device_counts_size or self.packed.max_devices
@@ -2088,6 +2165,8 @@ class Mapper:
         PERF.inc("pgs_mapped", int(n))       # success only (no double
         PERF.inc("sweep_blocks", nblocks)    # count via the retry)
         PERF.inc("sweep_lanes", lanes)
+        if kb_kern:
+            self._count_take_plans(ruleno, nblocks)
         return counts[:nd], bad, self._record_path(path, _expected)
 
     def _sharded_sweep(self, ruleno: int, start_x: int, n: int,
@@ -2111,7 +2190,24 @@ class Mapper:
             return self._sharded_sweep(ruleno, start_x, n, result_max)
         # (last_map_path is set by sharded_sweep itself — one site)
         PERF.inc("pgs_mapped", int(n))
+        if kb is not None:
+            self._count_sharded_take_plans(ruleno, result_max, max(1, n))
         return counts, bad
+
+    def _count_take_plans(self, ruleno: int, blocks: int) -> None:
+        """``kernel_take_plans``: ``blocks`` blocks of lanes ran the
+        kernel body, one plan a take/emit block of the rule each."""
+        PERF.inc("kernel_take_plans", blocks * len(self._take_plans(ruleno)))
+
+    def _count_sharded_take_plans(self, ruleno: int, result_max: int,
+                                  n: int) -> None:
+        """The same for a batch of ``n`` lanes over the mesh: every
+        shard's tiles (``sharded_sweep._shard_widths``)."""
+        from ceph_tpu.crush.sharded_sweep import _shard_widths
+        ndev = self.mesh.devices.size
+        local_n, block = _shard_widths(self, ruleno, result_max,
+                                       -(-n // ndev))
+        self._count_take_plans(ruleno, ndev * -(-local_n // block))
 
 
 def _tunables_key(t):
@@ -2197,8 +2293,8 @@ INDEP_TALLY = ("indep_blocks", "indep_rounds", "indep_lane_rounds_needed",
 # ran, and whether the flags overflowed its buffer
 KERNEL_TALLY = ("kernel_flagged_lanes", "kernel_fallback_blocks",
                 "kernel_fallback_overflows")
-# what a firstn sweep on the rule VM (a rule with no kernel plan: one
-# of several take/emit blocks, or a shape ``build_plan`` does not take)
+# what a firstn sweep on the rule VM (a rule with no kernel plan: a
+# shape ``build_plan`` does not take, or any rule off the TPU)
 # carries after the bad mappings: of each block, the lane-slots its
 # firstn blocks ran, those the speculative tries left to
 # ``_choose_one_firstn``'s loop, and the rounds that loop ran at full

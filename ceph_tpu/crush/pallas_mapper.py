@@ -134,7 +134,8 @@ bench map.
 Eligibility (build_plan returns None otherwise; the caller keeps the
 XLA path):
 - modern tunables (chooseleaf_stable=1, no legacy local retries),
-- rule shape TAKE root / CHOOSE[LEAF]_FIRSTN / EMIT,
+- rule shape TAKE root / CHOOSE[LEAF]_FIRSTN / EMIT, or several such
+  take/emit blocks, each its own plan (``build_plan``),
 - every bucket reachable from the root is straw2 and non-empty with at
   least one positive weight; weights above the class budget or the
   ln-gap license take the per-slot continuous draw (weights must fit
@@ -430,8 +431,17 @@ class KernelPlan:                               # hash -> usable as a
 
 def build_plan(m: CrushMap, packed, ruleno: int,
                device_weights: np.ndarray | None = None,
-               choose_args_key=None) -> KernelPlan | None:
-    """Stratify the map for one rule, or None if ineligible."""
+               choose_args_key=None
+               ) -> KernelPlan | tuple[KernelPlan, ...] | None:
+    """Stratify the map for one rule: the ``KernelPlan`` of a rule of
+    one take/emit block; for a rule of several (the docs' SSD-primary
+    ``mixed_replicated_rule``), a tuple of plans, one a block in the
+    rule's order, which the caller runs one after another and merges as
+    firstn EMIT does, keeping a lane's first ``result_max`` items in
+    block then slot order (no collision scan crosses blocks: each
+    chooses into its own working vector, ref: mapper.c crush_do_rule).
+    None if any block is ineligible: the whole rule keeps the XLA
+    path."""
     t = m.tunables
     if t.chooseleaf_stable != 1 or t.choose_local_tries or \
             t.choose_local_fallback_tries:
@@ -454,24 +464,40 @@ def build_plan(m: CrushMap, packed, ruleno: int,
     if rule is None:
         return None
     steps = [s for s in rule.steps if s.op != OP_NOOP]
-    if len(steps) != 3 or steps[0].op != OP_TAKE or \
-            steps[2].op != OP_EMIT:
+    # the rule's take/emit blocks: the steps cut after each EMIT, every
+    # block TAKE / CHOOSE[LEAF]_FIRSTN / EMIT
+    blocks = [steps[i:i + 3] for i in range(0, len(steps), 3)]
+    if not blocks or any(
+            len(b) != 3 or b[0].op != OP_TAKE or b[2].op != OP_EMIT
+            or b[1].op not in (OP_CHOOSELEAF_FIRSTN, OP_CHOOSE_FIRSTN)
+            for b in blocks):
         return None
-    choose = steps[1]
-    if choose.op not in (OP_CHOOSELEAF_FIRSTN, OP_CHOOSE_FIRSTN):
-        return None
+    from ceph_tpu.crush.ln_table import ln_gap_info
+    G, zg = ln_gap_info()
+    plans = []
+    for take, choose, _emit in blocks:
+        plan = _block_plan(m, take.arg1, choose, device_weights, ca_map,
+                           G, zg)
+        if plan is None:
+            return None
+        plans.append(plan)
+    return plans[0] if len(plans) == 1 else tuple(plans)
+
+
+def _block_plan(m: CrushMap, root: int, choose, device_weights, ca_map,
+                G, zg) -> KernelPlan | None:
+    """The plan of one take/emit block: ``take root`` then ``choose``,
+    or None if the kernel cannot run it."""
+    t = m.tunables
     recurse = choose.op == OP_CHOOSELEAF_FIRSTN
     target_type = choose.arg2
     if recurse and target_type == 0:
         return None
-    root = steps[0].arg1
     if root >= 0 or root not in m.buckets:
         return None
     # BFS strata: level l = all buckets at depth l from the root; the
     # kernel requires every level to be "pure" (all buckets, or all
     # devices at the end) and the target type to sit at one depth.
-    from ceph_tpu.crush.ln_table import ln_gap_info
-    G, zg = ln_gap_info()
     bucket_cls: dict[int, tuple] = {}       # bid -> (cls per slot, cws)
     strata: list[list[int]] = [[root]]
     l_main = None

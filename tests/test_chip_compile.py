@@ -118,6 +118,49 @@ def test_crush_kernel_compiles_at_10k_osds(one_chip, variant):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+HYBRID_RULE = """rule mixed_replicated_rule {
+\tid 1
+\ttype replicated
+\tstep take root class ssd
+\tstep chooseleaf firstn 1 type host
+\tstep emit
+\tstep take root class hdd
+\tstep chooseleaf firstn 0 type host
+\tstep emit
+}
+"""
+
+
+def test_crush_kernel_compiles_a_plan_a_take_block(one_chip):
+    """The docs' SSD-primary rule on the 10,240-OSD map with 12 hdd and
+    4 ssd OSDs a host: one plan a take/emit block, the ssd one for one
+    replica over hosts of 4, the hdd one for three over hosts of 12,
+    each compiled at the replica count the sweep launches it with."""
+    from ceph_tpu.bench import crushtool
+    from ceph_tpu.crush import pallas_mapper as pm
+    from ceph_tpu.crush.compiler import compile_crushmap, decompile_crushmap
+    from ceph_tpu.crush.tensors import pack_map
+
+    built = crushtool.build_map(crushtool.parse_args(
+        ["--build", "--num-osds", "10240", "--hosts", "640", "--racks",
+         "20", "--alg", "straw2"]))
+    lines = [ln + (" class " + ("hdd" if int(ln.split()[1]) % 16 < 12
+                                 else "ssd")
+                   if ln.startswith("device ") else "")
+             for ln in decompile_crushmap(built).splitlines()]
+    m = compile_crushmap("\n".join(lines) + "\n" + HYBRID_RULE)
+    plans = pm.build_plan(m, pack_map(m), 1)
+    assert isinstance(plans, tuple) and len(plans) == 2
+    for plan, numrep in zip(plans, (1, 3)):
+        lanes, _fold, _groups = pm.kernel_geometry(plan,
+                                                   numrep + pm.SPEC_EXTRA)
+        xs = jax.ShapeDtypeStruct((4 * lanes,), jnp.int32,
+                                  sharding=one_chip)
+        with jax.enable_x64(True):
+            compiled = pm._run_kernel.lower(plan, xs, numrep).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_count_placements_compiles_at_kernel_block(one_chip):
     """The sweep step's histogram at the kernel path's block (2^21
     lanes x 3, the 10,240-OSD map's 10,241 bins), fed as the kernel
